@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import tuning
+from repro.kernels.backend import interpret_mode
 from repro.kernels.detect.ops import detect_hosts
 from repro.kernels.fused.fused import fused_rca_pallas
 from repro.kernels.fused.ops import fused_rca
@@ -45,8 +46,7 @@ def kernel_microbench(B: int = 256, M: int = 16, N: int = 512,
     us_ref = _time(lambda a, b: lagged_xcorr(a, b, K, use_kernel=False), L, Mx)
     rows.append((f"kernel/xcorr_ref_jnp/{B}x{M}x{N}", us_ref,
                  f"{2 * B * M * (2 * K + 1) * N / 1e6:.1f}MFLOP"))
-    us_k = _time(lambda a, b: lagged_xcorr(a, b, K, use_kernel=True,
-                                           interpret=True), L, Mx)
+    us_k = _time(lambda a, b: lagged_xcorr(a, b, K, use_kernel=True), L, Mx)
     rows.append((f"kernel/xcorr_pallas_interp/{B}x{M}x{N}", us_k,
                  "interpret-mode (CPU correctness path)"))
     W = jnp.asarray(rng.standard_normal((B, M, N)), jnp.float32)
@@ -66,8 +66,7 @@ def kernel_microbench(B: int = 256, M: int = 16, N: int = 512,
     rows.append((f"kernel/fused_vs_separate/{B}x{M}x{N}", us_sep / us_fused,
                  "separate spike+xcorr dispatches / fused"))
     rows.append((f"kernel/fused_pallas_interp/{B}x{M}x{N}", _time(
-        lambda a, b, c: fused_rca(a, b, c, K, use_kernel=True,
-                                  interpret=True), L, Mx, Bs),
+        lambda a, b, c: fused_rca(a, b, c, K, use_kernel=True), L, Mx, Bs),
         "interpret-mode (CPU correctness path)"))
     # streaming detect: score + persistence gate + onset, one dispatch over
     # the (hosts, wn) slab (vs spike dispatch + f64 detect_rows replay)
@@ -83,13 +82,14 @@ def kernel_microbench(B: int = 256, M: int = 16, N: int = 512,
     return rows
 
 
-def tile_sweep_rows(interpret: bool = True) -> List[Tuple[str, float, str]]:
-    """Interpret-mode block_m sweep for the fused kernel (the TPU-tuning
-    hook): candidate tile sizes from kernels.tuning, one row each, so a
-    hardware run (interpret=False) starts from a measured grid.  CPU
-    interpret-mode walls rank dispatch/trace overhead only — trends, not
-    absolutes.
+def tile_sweep_rows() -> List[Tuple[str, float, str]]:
+    """block_m sweep for the fused kernel (the TPU-tuning hook):
+    candidate tile sizes from kernels.tuning, one row each, so a hardware
+    run starts from a measured grid.  On the CPU the kernel runs in
+    interpret mode and the walls rank dispatch/trace overhead only —
+    trends, not absolutes.
     """
+    interpret = interpret_mode()
     rng = np.random.default_rng(1)
     B, M, N, Nb, K = 8, 16, 512, 512, 20
     L = jnp.asarray(rng.standard_normal((B, N)), jnp.float32)

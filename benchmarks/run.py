@@ -74,6 +74,8 @@ def main() -> None:
                     help="tiny-size run of all perf families, no JSON")
     args = ap.parse_args()
     want = [s for s in args.only.split(",") if s]
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     def on(name: str) -> bool:
         return not want or any(name.startswith(w) for w in want)

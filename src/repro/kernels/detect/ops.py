@@ -31,7 +31,7 @@ __all__ = ["detect_hosts", "detect_hosts_slab", "persistence_count"]
 def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
                  patch_base: np.ndarray, wn: int, bn: int,
                  threshold: float, persistence: float,
-                 use_kernel: bool, interpret: bool, exact: bool,
+                 use_kernel: bool, exact: bool,
                  device=None, moments=None,
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Single-tick sweep over the (H, bn + wn) trailing slab.
@@ -71,7 +71,7 @@ def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
     fire, score, onset, marg = sweep_ops.sweep_rows(
         disp, wn, bn_d, ticks, threshold, persistence,
         moments=(mu[:, None], sd[:, None]), argmax_fallback=True,
-        use_kernel=use_kernel, interpret=interpret, device=device)
+        use_kernel=use_kernel, device=device)
     fire, score, onset, marg = (fire[:, 0], score[:, 0], onset[:, 0],
                                 marg[:, 0])
     if exact and marg.any():
@@ -88,7 +88,7 @@ def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
 
 def detect_hosts(windows, baselines, threshold: float = 3.0,
                  persistence: float = 0.0, use_kernel: bool = True,
-                 interpret: bool = True, exact: bool = True,
+                 exact: bool = True,
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Batched Layer-2 decision per host row, one dispatch.
 
@@ -111,13 +111,13 @@ def detect_hosts(windows, baselines, threshold: float = 3.0,
                              np.asarray(windows, np.float32)], axis=1)
     fire, score, onset = _detect_tail(
         tail32, windows, baselines, wn, bn, float(threshold),
-        float(persistence), bool(use_kernel), bool(interpret), bool(exact))
+        float(persistence), bool(use_kernel), bool(exact))
     return fire.astype(bool), score, onset.astype(np.intp)
 
 
 def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
                       persistence: float = 0.0, use_kernel: bool = True,
-                      interpret: bool = True, exact: bool = True,
+                      exact: bool = True,
                       valid: Optional[np.ndarray] = None,
                       force_oracle: bool = False, device=None,
                       moments=None,
@@ -182,5 +182,5 @@ def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
     fire, score, onset = _detect_tail(
         tail32, patch[:, bn:], patch[:, :bn], int(wn), int(bn),
         float(threshold), float(persistence), bool(use_kernel),
-        bool(interpret), bool(exact), device=device, moments=moments)
+        bool(exact), device=device, moments=moments)
     return fire.astype(bool), score, onset.astype(np.intp)

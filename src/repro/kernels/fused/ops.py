@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.fused.fused import fused_rca_masked_pallas, fused_rca_pallas
 from repro.kernels.fused.ref import fused_rca_masked_ref, fused_rca_ref
 
@@ -33,17 +34,16 @@ def _pad128(x: jax.Array, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel"))
 def fused_rca(latency: jax.Array, metrics: jax.Array, baselines: jax.Array,
               max_lag: int = 20, use_kernel: bool = True,
-              interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+              ) -> tuple[jax.Array, jax.Array]:
     """(scores (B, M), rho (B, M, 2K+1)) for latency (B, N), metrics
     (B, M, N), baselines (B, M, Nb).
 
-    ``use_kernel=True`` dispatches the fused Pallas kernel (interpret mode
-    executes the body on CPU for validation); False composes the pure-jnp
-    references — also the AD-friendly path.
+    ``use_kernel=True`` dispatches the fused Pallas kernel (compiled on a
+    TPU, interpreted on the CPU — see ``kernels.backend``); False composes
+    the pure-jnp references — also the AD-friendly path.
     """
     if latency.ndim != 2 or metrics.ndim != 3 or baselines.ndim != 3:
         raise ValueError(f"latency {latency.shape}, metrics {metrics.shape}, "
@@ -55,34 +55,30 @@ def fused_rca(latency: jax.Array, metrics: jax.Array, baselines: jax.Array,
     met = _pad128(metrics.astype(jnp.float32), 2)
     base = _pad128(baselines.astype(jnp.float32), 2)
     return fused_rca_pallas(lat, met, base, max_lag, n_valid=n, nb_valid=nb,
-                            interpret=interpret)
+                            interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel",
-                                             "interpret"))
-def _fused_rca_max_jit(latency, metrics, baselines, max_lag,
-                       use_kernel, interpret):
-    scores, rho = fused_rca(latency, metrics, baselines, max_lag,
-                            use_kernel, interpret)
+@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel"))
+def _fused_rca_max_jit(latency, metrics, baselines, max_lag, use_kernel):
+    scores, rho = fused_rca(latency, metrics, baselines, max_lag, use_kernel)
     idx = jnp.argmax(jnp.abs(rho), axis=-1)
     c = jnp.take_along_axis(jnp.abs(rho), idx[..., None], axis=-1)[..., 0]
     return scores, c, idx - max_lag
 
 
 def fused_rca_max(latency, metrics, baselines, max_lag: int = 20,
-                  use_kernel: bool = True, interpret: bool = True):
+                  use_kernel: bool = True):
     """(scores, c, lag) per (B, M): spike scores plus max |rho| over lags
     and its arg-max lag — the exact inputs of confidence.rank_causes."""
     global DISPATCH_COUNT
     DISPATCH_COUNT += 1
     return _fused_rca_max_jit(latency, metrics, baselines, int(max_lag),
-                              bool(use_kernel), bool(interpret))
+                              bool(use_kernel))
 
 
-@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_lag", "use_kernel"))
 def _fused_rca_max_ragged_jit(latency, metrics, baselines, n_valid, nb_valid,
-                              max_lag, use_kernel, interpret):
+                              max_lag, use_kernel):
     # zero the tails inside the jit (XLA fuses it) so the masked math sees
     # exact zeros regardless of caller padding garbage
     N, Nb = metrics.shape[-1], baselines.shape[-1]
@@ -97,7 +93,7 @@ def _fused_rca_max_ragged_jit(latency, metrics, baselines, n_valid, nb_valid,
         base = _pad128(baselines.astype(jnp.float32), 2)
         scores, rho = fused_rca_masked_pallas(lat, met, base, n_valid,
                                               nb_valid, max_lag,
-                                              interpret=interpret)
+                                              interpret=interpret_mode())
     else:
         scores, rho = fused_rca_masked_ref(latency, metrics, baselines,
                                            n_valid, nb_valid, max_lag)
@@ -107,8 +103,7 @@ def _fused_rca_max_ragged_jit(latency, metrics, baselines, n_valid, nb_valid,
 
 
 def fused_rca_max_ragged(latency, metrics, baselines, n_valid, nb_valid,
-                         max_lag: int = 20, use_kernel: bool = False,
-                         interpret: bool = True):
+                         max_lag: int = 20, use_kernel: bool = False):
     """Ragged-row :func:`fused_rca_max`: rows (events or hosts) carry their
     own valid window/baseline lengths.
 
@@ -117,7 +112,7 @@ def fused_rca_max_ragged(latency, metrics, baselines, n_valid, nb_valid,
     ``nb_valid[b]``.  One dispatch for the whole stack — the event-batched
     Layer-3 path of ``run_eval``.  ``use_kernel=False`` (default) runs the
     masked XLA reference, the CPU timing path; True dispatches the masked
-    Pallas kernel (interpret mode validates on CPU).
+    Pallas kernel (interpreted on the CPU, compiled on a TPU).
     """
     global DISPATCH_COUNT
     DISPATCH_COUNT += 1
@@ -130,5 +125,4 @@ def fused_rca_max_ragged(latency, metrics, baselines, n_valid, nb_valid,
     return _fused_rca_max_ragged_jit(latency, metrics, baselines,
                                      jnp.asarray(n_valid, jnp.int32),
                                      jnp.asarray(nb_valid, jnp.int32),
-                                     int(max_lag), bool(use_kernel),
-                                     bool(interpret))
+                                     int(max_lag), bool(use_kernel))

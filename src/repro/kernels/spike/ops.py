@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.spike.ref import spike_scores_ref
 from repro.kernels.spike.spike import spike_scores_pallas
 
@@ -19,10 +20,9 @@ def _pad128(x: jax.Array, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def spike_scores(windows: jax.Array, baselines: jax.Array,
-                 use_kernel: bool = True, interpret: bool = True,
-                 ) -> jax.Array:
+                 use_kernel: bool = True) -> jax.Array:
     """Batched spike scores (B, M) for (B, M, Nw) windows vs (B, M, Nb)."""
     if not use_kernel:
         return spike_scores_ref(windows, baselines)
@@ -30,4 +30,4 @@ def spike_scores(windows: jax.Array, baselines: jax.Array,
     w = _pad128(windows.astype(jnp.float32), 2)
     b = _pad128(baselines.astype(jnp.float32), 2)
     return spike_scores_pallas(w, b, nw_valid=nw, nb_valid=nb,
-                               interpret=interpret)
+                               interpret=interpret_mode())

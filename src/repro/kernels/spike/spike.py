@@ -21,36 +21,34 @@ NEG = -3.4e38
 
 
 def _spike_kernel(nw_valid: int, nb_valid: int, win_ref, base_ref, out_ref):
-    """win_ref (1, bm, Nw), base_ref (1, bm, Nb), out_ref (1, bm)."""
+    """win_ref (bm, Nw), base_ref (bm, Nb), out_ref (bm, 1)."""
     Nw = win_ref.shape[-1]
     Nb = base_ref.shape[-1]
-    bm = win_ref.shape[1]
-    wmask = (jax.lax.iota(jnp.int32, Nw) < nw_valid)
-    bmask = (jax.lax.iota(jnp.int32, Nb) < nb_valid).astype(jnp.float32)
+    wmask = jax.lax.broadcasted_iota(jnp.int32, (1, Nw), 1) < nw_valid
+    bmask = (jax.lax.broadcasted_iota(jnp.int32, (1, Nb), 1)
+             < nb_valid).astype(jnp.float32)
     nb = jnp.float32(nb_valid)
 
-    b = base_ref[0] * bmask[None, :]
-    mu = jnp.sum(b, axis=1) / nb                                  # (bm,)
-    var = jnp.sum((b - mu[:, None]) * bmask[None, :] * (b - mu[:, None]),
-                  axis=1) / nb
+    b = base_ref[...] * bmask
+    mu = jnp.sum(b, axis=1, keepdims=True) / nb                   # (bm, 1)
+    var = jnp.sum((b - mu) * bmask * (b - mu), axis=1, keepdims=True) / nb
     sd = jnp.sqrt(jnp.maximum(var, 0.0))
     floor = jnp.maximum(SIGMA_FLOOR_ABS, SIGMA_FLOOR_REL * jnp.abs(mu))
     sd = jnp.maximum(sd, floor)
 
-    w = win_ref[0]
-    z = (w - mu[:, None]) / sd[:, None]
-    z = jnp.where(wmask[None, :], z, NEG)
-    out_ref[0] = jnp.max(z, axis=1)
+    z = jnp.where(wmask, (win_ref[...] - mu) / sd, NEG)
+    out_ref[...] = jnp.max(z, axis=1, keepdims=True)
 
 
 def spike_scores_pallas(windows: jax.Array, baselines: jax.Array,
                         nw_valid: int | None = None,
                         nb_valid: int | None = None,
-                        block_m: int | None = None, interpret: bool = True,
+                        block_m: int | None = None, *, interpret: bool,
                         ) -> jax.Array:
     """windows (B, M, Nw), baselines (B, M, Nb) -> (B, M) f32.
 
-    ``block_m`` defaults to the env-overridable tile config."""
+    ``block_m`` defaults to the env-overridable tile config; ``interpret``
+    runs the body in the Pallas interpreter (the CPU path)."""
     B, M, Nw = windows.shape
     Nb = baselines.shape[-1]
     if Nw % 128 or Nb % 128:
@@ -68,11 +66,11 @@ def spike_scores_pallas(windows: jax.Array, baselines: jax.Array,
         functools.partial(_spike_kernel, nw_valid, nb_valid),
         grid=(B, Mp // block_m),
         in_specs=[
-            pl.BlockSpec((1, block_m, Nw), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_m, Nb), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((None, block_m, Nw), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((None, block_m, Nb), lambda b, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_m), lambda b, j: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((B, Mp), jnp.float32),
+        out_specs=pl.BlockSpec((None, block_m, 1), lambda b, j: (b, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Mp, 1), jnp.float32),
         interpret=interpret,
     )(windows.astype(jnp.float32), baselines.astype(jnp.float32))
-    return out[:, :M]
+    return out[:, :M, 0]

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import spike as spike_mod
 from repro.kernels import tuning
+from repro.kernels.backend import interpret_mode
 from repro.kernels.sweep.ref import sweep_rows_ref
 from repro.kernels.sweep.sweep import sweep_rows_pallas
 
@@ -267,7 +268,7 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                valid_n: Optional[np.ndarray] = None,
                moments: Optional[Tuple[np.ndarray, np.ndarray]] = None,
                argmax_fallback: bool = False, eps: float = SWEEP_GUARD_EPS,
-               use_kernel: bool = False, interpret: bool = True,
+               use_kernel: bool = False,
                block_t: Optional[int] = None,
                valid: Optional[np.ndarray] = None,
                device=None,
@@ -277,9 +278,10 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
     ``lat`` (rows, T) — any dtype, staged to f32 for the dispatch; every
     row is evaluated at the shared ``ticks`` (each in ``[wn + bn, T]``)
     against its own rolling baseline, in ONE jit dispatch (masked-XLA ref
-    by default; ``use_kernel=True`` for the Pallas kernel, interpret mode
-    on CPU).  Returns ``(fire, score, onset, marginal)`` numpy arrays of
-    shape (rows, #ticks):
+    by default; ``use_kernel=True`` for the Pallas kernel, compiled on a
+    TPU and interpreted on the CPU — see ``kernels.backend``).  Returns
+    ``(fire, score, onset, marginal)`` numpy arrays of shape (rows,
+    #ticks):
 
       fire      bool, the full scalar detect rule per (row, tick);
       score     f32 max-z (0 where the tick is masked);
@@ -363,7 +365,8 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
             jnp.asarray(np.asarray(sd, np.float32)),
             jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32),
             wn, float(threshold), int(min_hot), float(eps),
-            bool(argmax_fallback), bool(use_kernel), bool(interpret),
+            bool(argmax_fallback), bool(use_kernel),
+            bool(use_kernel) and interpret_mode(device),
             tuning.sweep_block_t(block_t))
     if device is None:
         fire, score, onset, marg = _dispatch()

@@ -10,9 +10,9 @@ the generation, so both are overridable without code edits:
     REPRO_BLOCK_M=16 REPRO_LAG_PAD=128 python -m benchmarks.run --only kernel
     REPRO_SWEEP_BLOCK_T=64 REPRO_SWEEP_BLOCK_R=4 ... # sweep kernel tile
 
-``benchmarks/kernelbench.py`` sweeps the ``block_m`` candidates in interpret
-mode (`kernel/tile_sweep/*` rows) so a hardware run has a starting grid; the
-ROADMAP's TPU-tuning item consumes those rows.
+``benchmarks/kernelbench.py`` sweeps the ``block_m`` candidates
+(`kernel/tile_sweep/*` rows) so a hardware run has a starting grid; on the
+CPU those rows time the Pallas interpreter, not the kernel.
 """
 from __future__ import annotations
 
@@ -28,7 +28,7 @@ DEFAULT_SHARD_TOPK = 16       # evidence candidates shipped per shard/rack
 DEFAULT_REANCHOR_ROUNDS = 32  # rounds between exact-f64 moment re-anchors
 DEFAULT_MOMENT_BLOCK = 64     # ticks per cached incremental-moment block
 
-#: candidates the interpret-mode microbench sweeps (hardware starting grid)
+#: candidates the kernel microbench sweeps (hardware starting grid)
 BLOCK_M_CANDIDATES = (4, 8, 16)
 
 

@@ -6,12 +6,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.welford.ref import welford_ref
 from repro.kernels.welford.welford import welford_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def welford(x: jax.Array, use_kernel: bool = True, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
+def welford(x: jax.Array, use_kernel: bool = True):
     """(mean, var) over the last axis of (B, M, N)."""
     if not use_kernel:
         return welford_ref(x)
@@ -20,4 +21,4 @@ def welford(x: jax.Array, use_kernel: bool = True, interpret: bool = True):
     if pad:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
     return welford_pallas(x.astype(jnp.float32), n_valid=n,
-                          interpret=interpret)
+                          interpret=interpret_mode())

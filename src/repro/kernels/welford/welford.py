@@ -22,23 +22,23 @@ CHUNK = 128
 
 
 def _welford_kernel(n_valid: int, x_ref, mean_ref, var_ref):
-    """x_ref (1, bm, N); mean/var (1, bm)."""
+    """x_ref (bm, N); mean/var (bm, 1)."""
     N = x_ref.shape[-1]
-    bm = x_ref.shape[1]
+    bm = x_ref.shape[0]
     n_chunks = N // CHUNK
 
     def body(c, carry):
-        cnt, mean, m2 = carry                         # (bm,) each
-        lo = c * CHUNK
-        idx = lo + jax.lax.iota(jnp.int32, CHUNK)
-        valid = (idx < n_valid).astype(jnp.float32)   # (CHUNK,)
-        xc = jax.lax.dynamic_slice(x_ref[0], (0, lo), (bm, CHUNK))
-        n_c = jnp.sum(valid)
+        cnt, mean, m2 = carry                         # (bm, 1) each
+        lo = pl.multiple_of(c * CHUNK, CHUNK)
+        idx = lo + jax.lax.broadcasted_iota(jnp.int32, (1, CHUNK), 1)
+        valid = (idx < n_valid).astype(jnp.float32)   # (1, CHUNK)
+        xc = x_ref[:, pl.ds(lo, CHUNK)]
+        n_c = jnp.sum(valid, axis=1, keepdims=True)   # (1, 1)
         # chunk stats (masked)
         safe = jnp.maximum(n_c, 1.0)
-        mean_c = jnp.sum(xc * valid[None, :], axis=1) / safe
-        d = (xc - mean_c[:, None]) * valid[None, :]
-        m2_c = jnp.sum(d * d, axis=1)
+        mean_c = jnp.sum(xc * valid, axis=1, keepdims=True) / safe
+        d = (xc - mean_c) * valid
+        m2_c = jnp.sum(d * d, axis=1, keepdims=True)
         # Chan merge
         tot = cnt + n_c
         tot_safe = jnp.maximum(tot, 1.0)
@@ -51,16 +51,16 @@ def _welford_kernel(n_valid: int, x_ref, mean_ref, var_ref):
         cnt_new = jnp.where(n_c > 0, tot, cnt)
         return cnt_new, mean_new, m2_new
 
-    cnt0 = jnp.zeros((bm,), jnp.float32)
-    init = (cnt0, jnp.zeros((bm,), jnp.float32), jnp.zeros((bm,), jnp.float32))
-    cnt, mean, m2 = jax.lax.fori_loop(0, n_chunks, body, init)
-    mean_ref[0] = mean
-    var_ref[0] = m2 / jnp.maximum(cnt, 1.0)
+    z = jnp.zeros((bm, 1), jnp.float32)
+    cnt, mean, m2 = jax.lax.fori_loop(0, n_chunks, body, (z, z, z))
+    mean_ref[...] = mean
+    var_ref[...] = m2 / jnp.maximum(cnt, 1.0)
 
 
 def welford_pallas(x: jax.Array, n_valid: int | None = None,
-                   block_m: int = 8, interpret: bool = True):
-    """x (B, M, N) -> (mean, var) each (B, M) f32.  N % 128 == 0."""
+                   block_m: int = 8, *, interpret: bool):
+    """x (B, M, N) -> (mean, var) each (B, M) f32.  N % 128 == 0;
+    ``interpret`` runs the body in the Pallas interpreter (the CPU path)."""
     B, M, N = x.shape
     if N % 128 != 0:
         raise ValueError("N must be lane-aligned")
@@ -69,14 +69,14 @@ def welford_pallas(x: jax.Array, n_valid: int | None = None,
     if pad_m:
         x = jnp.pad(x, ((0, 0), (0, pad_m), (0, 0)))
     Mp = M + pad_m
+    out_spec = pl.BlockSpec((None, block_m, 1), lambda b, j: (b, j, 0))
     mean, var = pl.pallas_call(
         functools.partial(_welford_kernel, n_valid),
         grid=(B, Mp // block_m),
-        in_specs=[pl.BlockSpec((1, block_m, N), lambda b, j: (b, j, 0))],
-        out_specs=[pl.BlockSpec((1, block_m), lambda b, j: (b, j)),
-                   pl.BlockSpec((1, block_m), lambda b, j: (b, j))],
-        out_shape=[jax.ShapeDtypeStruct((B, Mp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Mp), jnp.float32)],
+        in_specs=[pl.BlockSpec((None, block_m, N), lambda b, j: (b, j, 0))],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, Mp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Mp, 1), jnp.float32)],
         interpret=interpret,
     )(x.astype(jnp.float32))
-    return mean[:, :M], var[:, :M]
+    return mean[:, :M, 0], var[:, :M, 0]

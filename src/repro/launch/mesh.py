@@ -7,6 +7,14 @@ tests run on one device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the model code places tensors
+    through ``with_sharding_constraint`` and sharding-in-types gathers,
+    which refuse the ``Explicit`` axes ``make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +22,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     DP "pod" axis over DCN: 2 x 16 x 16 = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small host-device mesh for tests (same axis names)."""
     if n_pod:
-        return jax.make_mesh((n_pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

@@ -161,7 +161,7 @@ def test_sweep_rows_masked_matches_oracle(use_kernel):
     want = _oracle_rows(staged, valid, ticks, wn, bn)
     fire, score, onset, _ = sweep_ops.sweep_rows(
         staged, wn, bn, ticks, persistence=0.2, valid=valid,
-        use_kernel=use_kernel, interpret=True)
+        use_kernel=use_kernel)
     np.testing.assert_array_equal(fire, want)
     assert fire[2].any()                            # clean spike still fires
     assert not fire[4].any()                        # poisoned spike quiet

@@ -40,7 +40,7 @@ _SUBPROC = textwrap.dedent("""
     import sys
     sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P, NamedSharding
+    from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
     from repro.configs import get_config
     from repro.launch.inputs import make_real_batch
     from repro.models.registry import build_model
@@ -61,7 +61,8 @@ _SUBPROC = textwrap.dedent("""
     loss_ref = float(m_ref["loss"])
 
     # sharded run on a 4x2 mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(cfg, mesh)
     with mesh_context(mesh, rules):
         pspecs = param_pspecs(model.param_logical, rules)
@@ -96,12 +97,14 @@ def test_dryrun_smoke_small_devices(tmp_path):
     script = textwrap.dedent("""
         import repro.launch.dryrun as dr
         import jax
+        from jax.sharding import AxisType
         # shrink the production mesh to the debug size for this probe
         import repro.launch.mesh as mesh_mod
         mesh_mod.make_production_mesh = \
             lambda multi_pod=False: jax.make_mesh(
                 (2, 2, 2) if multi_pod else (4, 2),
-                ("pod", "data", "model") if multi_pod else ("data", "model"))
+                ("pod", "data", "model") if multi_pod else ("data", "model"),
+                axis_types=(AxisType.Auto,) * (3 if multi_pod else 2))
         rec = dr.run_cell("whisper-base", "train_4k", False, save=False)
         assert rec["status"] == "ok", rec
         rec2 = dr.run_cell("whisper-base", "train_4k", True, save=False)
