@@ -1,0 +1,8 @@
+"""Device idle share of the traced window of whole rounds, in %."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0 or t.n_devices == 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)

@@ -1,0 +1,11 @@
+"""Tests of the benchmark itself, on the CPU at tiny sizes:
+
+    JAX_PLATFORMS=cpu python -m pytest bench/tests -q
+"""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
