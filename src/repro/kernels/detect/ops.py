@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from repro.core import spike as spike_mod
+from repro.core.spans import span
 from repro.kernels.sweep import ops as sweep_ops
 from repro.kernels.sweep.ops import persistence_count  # re-export (tests/API)
 
@@ -63,7 +64,9 @@ def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
         # copy and the kernel's slab stay O(wn) instead of O(wn + bn)
         # (onsets are window-relative either way; verified equivalent
         # for both kernel and reference dispatch)
-        disp, bn_d = np.ascontiguousarray(tail32[:, bn:]), 0
+        with span("detect.stage") as sp:
+            disp, bn_d = np.ascontiguousarray(tail32[:, bn:]), 0
+            sp.set_metadata(bytes=disp.nbytes)
         ticks = np.array([wn], np.int64)
     else:
         disp, bn_d = tail32, bn
@@ -78,11 +81,12 @@ def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
         # guard band hit: re-decide those hosts through the f64 oracle so
         # the fast path cannot split from detect_rows at the threshold
         rows = np.flatnonzero(marg)
-        f2, s2, o2 = spike_mod.detect_rows(
-            np.asarray(patch_win[rows], np.float64),
-            np.asarray(patch_base[rows], np.float64),
-            threshold, persistence)
-        fire[rows], score[rows], onset[rows] = f2, s2, o2
+        with span("detect.redecide", rows=rows.size):
+            f2, s2, o2 = spike_mod.detect_rows(
+                np.asarray(patch_win[rows], np.float64),
+                np.asarray(patch_base[rows], np.float64),
+                threshold, persistence)
+            fire[rows], score[rows], onset[rows] = f2, s2, o2
     return fire, score, onset
 
 
@@ -175,7 +179,9 @@ def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
             t64[:, bn:], t64[:, :bn], v[:, bn:], v[:, :bn],
             float(threshold), float(persistence))
         return fire.astype(bool), score, onset.astype(np.intp)
-    tail32 = np.ascontiguousarray(tail, np.float32)
+    with span("detect.stage") as sp:
+        tail32 = np.ascontiguousarray(tail, np.float32)
+        sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
     # the exact re-decision must see the caller's values, not the f32
     # staging — only a genuinely-f32 tail may reuse the staged copy
     patch = tail32 if tail.dtype == np.float32 else tail

@@ -27,6 +27,7 @@ byte-exact by luck.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional, Tuple
 
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import spike as spike_mod
+from repro.core.spans import span
 from repro.kernels import tuning
 from repro.kernels.backend import interpret_mode
 from repro.kernels.sweep.ref import sweep_rows_ref
@@ -358,25 +360,28 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
     lat32 = np.ascontiguousarray(lat, np.float32)
     if vmask is not None:
         lat32 = np.where(vmask, lat32, np.float32(spike_mod.MASK_NEG))
-    def _dispatch():
-        return _sweep_jit(
-            jnp.asarray(lat32),
-            jnp.asarray(np.asarray(mu, np.float32)),
-            jnp.asarray(np.asarray(sd, np.float32)),
-            jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32),
-            wn, float(threshold), int(min_hot), float(eps),
-            bool(argmax_fallback), bool(use_kernel),
-            bool(use_kernel) and interpret_mode(device),
-            tuning.sweep_block_t(block_t))
-    if device is None:
-        fire, score, onset, marg = _dispatch()
-    else:
-        with jax.default_device(device):
-            fire, score, onset, marg = _dispatch()
-    fire = np.asarray(fire).astype(bool)
-    score = np.array(score, np.float64)
-    onset = np.asarray(onset).astype(np.intp)
-    marg = np.asarray(marg).astype(bool)
+    # one span from the host->device put to the last pull; the put, the
+    # (asynchronous) dispatch and the four pulls are its children
+    h2d = lat32.nbytes + 4 * (np.size(mu) + np.size(sd) + nt + R)
+    with span("detect.sweep", rows=R, h2d_bytes=h2d), \
+            (contextlib.nullcontext() if device is None
+             else jax.default_device(device)):
+        with span("sweep.put"):
+            args = (jnp.asarray(lat32),
+                    jnp.asarray(np.asarray(mu, np.float32)),
+                    jnp.asarray(np.asarray(sd, np.float32)),
+                    jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32))
+        with span("sweep.dispatch"):
+            fire, score, onset, marg = _sweep_jit(
+                *args, wn, float(threshold), int(min_hot), float(eps),
+                bool(argmax_fallback), bool(use_kernel),
+                bool(use_kernel) and interpret_mode(device),
+                tuning.sweep_block_t(block_t))
+        with span("sweep.pull"):
+            fire = np.asarray(fire).astype(bool)
+            score = np.array(score, np.float64)
+            onset = np.asarray(onset).astype(np.intp)
+            marg = np.asarray(marg).astype(bool)
     if vmask is not None:
         # host-side validity gate: a baseline you cannot estimate (or a
         # window with zero valid cells) may never fire, whatever the

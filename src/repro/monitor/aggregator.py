@@ -33,6 +33,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.monitor.fleet import FleetDiagnosis, FleetMonitor
 from repro.telemetry.agent import TelemetryAgent
 
@@ -52,6 +53,10 @@ class AggregatorStats:
     unchanged_skips: int = 0    # rows reused untouched (seqlock watermark)
     delta_reads: int = 0        # rows advanced by a delta read, not T ticks
     full_restages: int = 0      # live rows that took the full T-tick copy
+    #: bytes written into the staging buffers (slab, timestamps, validity
+    #: and their scratch rows), summed at each write: left-shifts, ring
+    #: reads, validity masks, and the zeroing of dead and short rows
+    staged_bytes: int = 0
 
 
 @dataclasses.dataclass
@@ -71,6 +76,13 @@ class FleetSnapshot:
     #: ``diagnose`` for that round (NOT flagged-eligible; an operator must
     #: not read their zero spike score as "monitored and healthy")
     masked: List[int] = dataclasses.field(default_factory=list)
+
+
+
+def _write(dst: np.ndarray, src) -> int:
+    """``dst[...] = src``; returns the bytes written."""
+    dst[...] = src
+    return dst.nbytes
 
 
 class FleetAggregator:
@@ -221,27 +233,29 @@ class FleetAggregator:
         restage they replace (ring history is append-only, so the
         overlapping columns could not have changed).  Any gap, torn
         read, or off-grid timestamp voids the attempt — the caller falls
-        back to the full restage.  Returns ``(staged, retries)``.
+        back to the full restage.  Returns ``(staged, retries, nbytes)``,
+        ``nbytes`` the bytes written into the staging buffers.
         """
         T = self.window_n
         if not self._staged_full[h] or skip != 0 or count < T:
-            return False, 0
+            return False, 0, 0
         if seq >= 0 and seq == self._staged_seq[h] \
                 and abs(self._staged_last[h] - t_common) <= 0.5 * period:
             self.stats.unchanged_skips += 1
-            return True, 0
+            return True, 0, 0
         gap = t_common - self._staged_last[h]
         di = int(round(gap / period))
         if not (0 < di < T and abs(gap - di * period) <= 0.25 * period):
-            return False, 0
+            return False, 0, 0
         row, tsr, vrow = self._slab[h], self._ts_rows[h], self._valid[h]
         # overlapping left-shift: numpy buffers overlapping assignments,
         # so this is the memmove it looks like
-        row[:, :T - di] = row[:, di:]
-        tsr[:T - di] = tsr[di:]
-        vrow[:, :T - di] = vrow[:, di:]
-        ts_n, _, r = agent.ring.read_window(di, out_ts=tsr[T - di:],
-                                            out=row[:, T - di:])
+        nbytes = (_write(row[:, :T - di], row[:, di:])
+                  + _write(tsr[:T - di], tsr[di:])
+                  + _write(vrow[:, :T - di], vrow[:, di:]))
+        ts_n, d_n, r = agent.ring.read_window(di, out_ts=tsr[T - di:],
+                                              out=row[:, T - di:])
+        nbytes += ts_n.nbytes + d_n.nbytes
         if (ts_n.size != di
                 or abs(float(ts_n[0]) - (self._staged_last[h] + period))
                 > 0.25 * period
@@ -249,12 +263,12 @@ class FleetAggregator:
             # writer raced past the watermark or ticks were dropped: the
             # shifted row no longer lines up — void it and restage fully
             self._staged_full[h] = False
-            return False, r
-        np.isfinite(row[:, T - di:], out=vrow[:, T - di:])
+            return False, r, nbytes
+        nbytes += np.isfinite(row[:, T - di:], out=vrow[:, T - di:]).nbytes
         self._staged_seq[h] = seq
         self._staged_last[h] = float(tsr[-1])
         self.stats.delta_reads += 1
-        return True, r
+        return True, r, nbytes
 
     def assemble(self) -> FleetSnapshot:
         """Stage every host's trailing window into the (hosts, C, T) slab.
@@ -265,30 +279,62 @@ class FleetAggregator:
         next ``assemble`` call.
         """
         H, T = len(self.agents), self.window_n
-        period = 1.0 / self.rate_hz
-        retries = 0
-        giveups0 = sum(a.ring.torn_giveups for a in self.agents)
+        with span("aggregator.assemble"):
+            period = 1.0 / self.rate_hz
+            giveups0 = sum(a.ring.torn_giveups for a in self.agents)
 
-        # phase 1: consistent (seq, count, newest-ts) probe per host to
-        # pick the common right edge of the fleet window; the seqlock
-        # sequence doubles as the delta-staging change detector
-        counts = np.zeros(H, np.int64)
-        lasts = np.full(H, -np.inf)
-        seqs = np.full(H, -1, np.int64)
-        for h, a in enumerate(self.agents):
-            seqs[h], counts[h], lasts[h] = a.ring.watermark()
-        have = counts >= max(self.min_samples, 1)
-        if not have.any():
-            snap = FleetSnapshot(ts=np.zeros(0), slab=self._slab[:0],
-                                 valid=np.zeros(H, np.int64),
-                                 skipped=list(range(H)), retries=0)
+            # phase 1: consistent (seq, count, newest-ts) probe per host to
+            # pick the common right edge of the fleet window; the seqlock
+            # sequence doubles as the delta-staging change detector
+            counts = np.zeros(H, np.int64)
+            lasts = np.full(H, -np.inf)
+            seqs = np.full(H, -1, np.int64)
+            with span("assemble.probe", hosts=H):
+                for h, a in enumerate(self.agents):
+                    seqs[h], counts[h], lasts[h] = a.ring.watermark()
+            have = counts >= max(self.min_samples, 1)
+            if not have.any():
+                snap = FleetSnapshot(ts=np.zeros(0), slab=self._slab[:0],
+                                     valid=np.zeros(H, np.int64),
+                                     skipped=list(range(H)), retries=0)
+                self.last_snapshot = snap
+                return snap
+            t_latest = float(lasts[have].max())
+            alive = have & (lasts >= t_latest - self.dead_after_s)
+            t_common = float(lasts[alive].min())
+
+            # phase 2: one bounded copy per live host, right-aligned at
+            # t_common
+            st = self.stats
+            n0 = (st.delta_reads, st.full_restages, st.unchanged_skips)
+            with span("assemble.copy") as sp:
+                valid, skipped, ref_host, retries, nbytes = self._stage_rows(
+                    alive, have, counts, lasts, seqs, t_common, period)
+                delta, full, unchanged = (
+                    st.delta_reads - n0[0], st.full_restages - n0[1],
+                    st.unchanged_skips - n0[2])
+                sp.set_metadata(delta_reads=delta, full_restages=full,
+                                unchanged=unchanged, bytes=nbytes)
+            st.staged_bytes += nbytes
+            st.assemblies += 1
+            st.torn_retries += retries
+            st.torn_giveups += (
+                sum(a.ring.torn_giveups for a in self.agents) - giveups0)
+            snap = FleetSnapshot(ts=self._ts_rows[ref_host], slab=self._slab,
+                                 valid=valid, skipped=skipped,
+                                 retries=retries, valid_mask=self._valid)
             self.last_snapshot = snap
             return snap
-        t_latest = float(lasts[have].max())
-        alive = have & (lasts >= t_latest - self.dead_after_s)
-        t_common = float(lasts[alive].min())
 
-        # phase 2: one bounded copy per live host, right-aligned at t_common
+    def _stage_rows(self, alive: np.ndarray, have: np.ndarray,
+                    counts: np.ndarray, lasts: np.ndarray, seqs: np.ndarray,
+                    t_common: float, period: float) -> tuple:
+        """Phase 2 of :meth:`assemble`: one bounded copy per live host,
+        right-aligned at ``t_common``.  Returns ``(valid, skipped,
+        ref_host, retries, nbytes)``, ``nbytes`` the bytes written into
+        the staging buffers."""
+        H, T = len(self.agents), self.window_n
+        retries = nbytes = 0
         valid = np.zeros(H, np.int64)
         skipped: List[int] = []
         ref_host = -1
@@ -299,6 +345,8 @@ class FleetAggregator:
                 self._slab[h] = 0.0
                 self._ts_rows[h] = 0.0
                 self._valid[h] = True
+                nbytes += (self._slab[h].nbytes + self._ts_rows[h].nbytes
+                           + self._valid[h].nbytes)
                 self._staged_full[h] = False
                 skipped.append(h)
                 self.stats.dead_hosts += int(have[h])
@@ -309,9 +357,11 @@ class FleetAggregator:
             # unchanged) or left-shifted + topped up with only the new
             # ticks — byte-identical to the full restage it replaces,
             # falling back to it on any raggedness, race, or gap
-            staged, r0 = self._stage_delta(h, a, skip, int(counts[h]),
-                                           int(seqs[h]), t_common, period)
+            staged, r0, b0 = self._stage_delta(h, a, skip, int(counts[h]),
+                                               int(seqs[h]), t_common,
+                                               period)
             retries += r0
+            nbytes += b0
             if staged:
                 valid[h] = T
                 if ref_host < 0 or T > valid[ref_host]:
@@ -326,6 +376,7 @@ class FleetAggregator:
             ts_h, d_h, r = a.ring.read_window(T, out_ts=out_ts, out=out_d,
                                               skip_newest=skip)
             retries += r
+            nbytes += ts_h.nbytes + d_h.nbytes
             # a live writer may have pushed between peek() and the read,
             # making the stale `skip` land past t_common — re-derive the
             # common-edge trim from the timestamps actually returned
@@ -335,6 +386,7 @@ class FleetAggregator:
             if k < self.min_samples:
                 self._slab[h] = 0.0
                 self._valid[h] = True
+                nbytes += self._slab[h].nbytes + self._valid[h].nbytes
                 self._staged_full[h] = False
                 skipped.append(h)
                 continue
@@ -343,23 +395,24 @@ class FleetAggregator:
                 if direct:
                     # short/trimmed read landed left-aligned in the slab
                     # row itself: move it through scratch to right-align
-                    self._scratch[:, :k] = d_h
-                    self._ts_scratch[:k] = ts_h
+                    nbytes += (_write(self._scratch[:, :k], d_h)
+                               + _write(self._ts_scratch[:k], ts_h))
                     d_h = self._scratch[:, :k]
                     ts_h = self._ts_scratch[:k]
-                row[:, T - k:] = d_h
-                self._ts_rows[h, T - k:] = ts_h
+                nbytes += (_write(row[:, T - k:], d_h)
+                           + _write(self._ts_rows[h, T - k:], ts_h))
             if k < T:
                 # late joiner: backfill the missing head with its oldest
                 # sample — a flat stretch that reads as a quiet baseline
-                row[:, :T - k] = d_h[:, :1]
-                self._ts_rows[h, :T - k] = (
-                    ts_h[0] - period * np.arange(T - k, 0, -1))
+                nbytes += (_write(row[:, :T - k], d_h[:, :1])
+                           + _write(self._ts_rows[h, :T - k],
+                                    ts_h[0] - period
+                                    * np.arange(T - k, 0, -1)))
                 self.stats.ragged_hosts += 1
             valid[h] = k
             # per-cell validity: the agent marks failed/backoff-skipped
             # collectors' channels NaN, so finiteness IS the delivery mask
-            np.isfinite(row, out=self._valid[h])
+            nbytes += np.isfinite(row, out=self._valid[h]).nbytes
             # only a full clean direct window seeds the next round's
             # delta path — trimmed/backfilled rows must restage
             full = bool(direct and k == T)
@@ -370,16 +423,7 @@ class FleetAggregator:
             self.stats.full_restages += 1
             if ref_host < 0 or k > valid[ref_host]:
                 ref_host = h
-
-        self.stats.assemblies += 1
-        self.stats.torn_retries += retries
-        self.stats.torn_giveups += (
-            sum(a.ring.torn_giveups for a in self.agents) - giveups0)
-        snap = FleetSnapshot(ts=self._ts_rows[ref_host], slab=self._slab,
-                             valid=valid, skipped=skipped, retries=retries,
-                             valid_mask=self._valid)
-        self.last_snapshot = snap
-        return snap
+        return valid, skipped, ref_host, retries, nbytes
 
     # ------------------------------------------------------------- sharding
     def shard_plan(self, shard_hosts: Optional[int] = None,
@@ -418,33 +462,35 @@ class FleetAggregator:
         baseline nor collapse the span into ``diagnose_fleet``'s
         short-baseline quiet verdict (which would wipe a real straggler's
         strike history fleet-wide while the newcomer refills)."""
-        # agent-restart wiring: a host whose probe was restarted/replaced
-        # since the last round gets its monitor-side strike/quarantine
-        # history re-based BEFORE this diagnosis — delivered exactly once
-        for h in sorted(self._pending_resets):
-            monitor.reset_host(h)
-            self.stats.host_resets += 1
-        self._pending_resets.clear()
-        snap = self.assemble()
-        if snap.slab.shape[0] == 0 or not snap.valid.size:
-            return None
-        k = int(snap.valid.max())
-        if k < max(int(min_valid_s * self.rate_hz), 1):
-            return None
-        for h in np.flatnonzero((snap.valid > 0) & (snap.valid < k)):
-            snap.slab[h] = 0.0      # cannot fill the span: quiet this round
-            if snap.valid_mask is not None:
-                snap.valid_mask[h] = True   # zeros are deliberate quiet
-            snap.masked.append(int(h))
-            # the staged row was just overwritten in place — it can no
-            # longer seed a delta read; force a full restage next round
-            self._staged_full[h] = False
-        self.stats.masked_hosts += len(snap.masked)
-        T = self.window_n
-        vm = snap.valid_mask
-        if k < T:
-            return monitor.diagnose_fleet(
-                snap.ts[T - k:], snap.slab[:, :, T - k:], self.channels,
-                valid=None if vm is None else vm[:, :, T - k:])
-        return monitor.diagnose_fleet(snap.ts, snap.slab, self.channels,
-                                      valid=vm)
+        with span("aggregator.diagnose", hosts=len(self.agents)) as sp:
+            # agent-restart wiring: a host whose probe was restarted/replaced
+            # since the last round gets its monitor-side strike/quarantine
+            # history re-based BEFORE this diagnosis — delivered exactly once
+            for h in sorted(self._pending_resets):
+                monitor.reset_host(h)
+                self.stats.host_resets += 1
+            self._pending_resets.clear()
+            snap = self.assemble()
+            if snap.slab.shape[0] == 0 or not snap.valid.size:
+                return None
+            k = int(snap.valid.max())
+            if k < max(int(min_valid_s * self.rate_hz), 1):
+                return None
+            for h in np.flatnonzero((snap.valid > 0) & (snap.valid < k)):
+                snap.slab[h] = 0.0  # cannot fill the span: quiet this round
+                if snap.valid_mask is not None:
+                    snap.valid_mask[h] = True   # zeros are deliberate quiet
+                snap.masked.append(int(h))
+                # the staged row was just overwritten in place — it can no
+                # longer seed a delta read; force a full restage next round
+                self._staged_full[h] = False
+            self.stats.masked_hosts += len(snap.masked)
+            sp.set_metadata(masked=len(snap.masked))
+            T = self.window_n
+            vm = snap.valid_mask
+            if k < T:
+                return monitor.diagnose_fleet(
+                    snap.ts[T - k:], snap.slab[:, :, T - k:], self.channels,
+                    valid=None if vm is None else vm[:, :, T - k:])
+            return monitor.diagnose_fleet(snap.ts, snap.slab, self.channels,
+                                          valid=vm)

@@ -30,13 +30,15 @@ oracle; the persistence gate compares an integer count), asserted by
 tests and recorded in BENCH_fleet.json.
 
 ``stage_seconds`` reports *disjoint* pipeline stages (detect / gather /
-kernel / rank / assemble) so benchmark attribution sums to the wall total.
+kernel / rank / assemble); they cover most of a round but not all of it
+(flag ordering and the strike lifecycle are in none).  Each stage is also
+a program span (:mod:`repro.core.spans`), and finer spans split it on the
+profiler's clock: ``docs/OPERATIONS.md``, "Tracing a round".
 """
 from __future__ import annotations
 
 import dataclasses
 import enum
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -49,6 +51,8 @@ from repro.core.engine import (
     orient_about_baseline, pick_baseline_slice,
 )
 from repro.core.reconcile import CO_GAP, symptom_table
+from repro.core.spans import span
+from repro.core.spans import stage as stage_span
 from repro.core.spike import detect_rows
 from repro.core.taxonomy import CauseClass, Diagnosis, SpikeEvent
 from repro.kernels.detect import ops as detect_ops
@@ -102,7 +106,9 @@ class FleetDiagnosis:
     #: host).  With a single hypothesis every list is just the primary.
     causes: Dict[int, List[CauseClass]] = dataclasses.field(default_factory=dict)
     #: wall seconds per pipeline stage, disjoint (detect / gather / kernel /
-    #: rank / assemble) — they sum to the diagnose_fleet wall total
+    #: rank / assemble, plus reduce on a sharded round) — they cover most
+    #: of the round's wall time, not all: ordering, the strike lifecycle
+    #: and the budget update fall in no stage
     stage_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     #: hosts whose telemetry is quarantined this round (persistently-bad
     #: validity) — fire suppressed, score zeroed, mitigation
@@ -401,32 +407,37 @@ class FleetMonitor:
         exact fleet-level verdict logic, keeping the two byte-identical
         by construction."""
         hosts, C, T = host_data.shape
-        li = list(channels).index(self.cfg.latency_metric)
-        vfull = None
-        if valid is not None:
-            v = np.asarray(valid, bool)
-            if v.shape != host_data.shape:
-                raise ValueError(f"valid {v.shape} vs data {host_data.shape}")
-            if not v.all():
-                vfull = v
-        wn, bn = self.cfg.window_n, self.cfg.baseline_n
-        wn = min(wn, T // 2)
-        bn = min(bn, T - wn)
-        if bn < MIN_BASELINE_N:
-            return self._quiet_round(hosts, extra_cost_s)
-        tick_end = self._tick_end(ts, T)
-        t_detect = time.perf_counter()
-        scores, cand, onset_rel, qhosts = self._detect_round(
-            host_data, vfull, li, T, wn, bn, tick_end=tick_end)
-        stage = {"detect": time.perf_counter() - t_detect}
+        with span("monitor.round", hosts=hosts):
+            li = list(channels).index(self.cfg.latency_metric)
+            vfull = None
+            if valid is not None:
+                with span("monitor.validity", cells=host_data.size):
+                    v = np.asarray(valid, bool)
+                    if v.shape != host_data.shape:
+                        raise ValueError(
+                            f"valid {v.shape} vs data {host_data.shape}")
+                    if not v.all():
+                        vfull = v
+            wn, bn = self.cfg.window_n, self.cfg.baseline_n
+            wn = min(wn, T // 2)
+            bn = min(bn, T - wn)
+            if bn < MIN_BASELINE_N:
+                return self._quiet_round(hosts, extra_cost_s)
+            tick_end = self._tick_end(ts, T)
+            stage: Dict[str, float] = {}
+            with stage_span(stage, "detect", "monitor.detect", rows=hosts):
+                scores, cand, onset_rel, qhosts = self._detect_round(
+                    host_data, vfull, li, T, wn, bn, tick_end=tick_end)
 
-        def evidence_for(geom: "EvidenceGeometry", rca_hosts: np.ndarray,
-                         ) -> np.ndarray:
-            return self._gather_evidence(host_data, rca_hosts, geom, vfull)
+            def evidence_for(geom: "EvidenceGeometry",
+                             rca_hosts: np.ndarray) -> np.ndarray:
+                return self._gather_evidence(host_data, rca_hosts, geom,
+                                             vfull)
 
-        return self._finish_round(ts, channels, li, T, wn, bn, scores,
-                                  cand, onset_rel, qhosts, stage,
-                                  extra_cost_s, evidence_for)
+            with span("monitor.finish", flagged=cand.size):
+                return self._finish_round(ts, channels, li, T, wn, bn,
+                                          scores, cand, onset_rel, qhosts,
+                                          stage, extra_cost_s, evidence_for)
 
     def _quiet_round(self, hosts: int, extra_cost_s: float) -> FleetDiagnosis:
         """Short-snapshot quiet verdict (baseline too thin to trust).
@@ -525,7 +536,8 @@ class FleetMonitor:
         bad_frac = (np.zeros(hosts) if lvt is None
                     else 1.0 - lvt.mean(axis=1))
         if quar is None:
-            quar = self._update_quarantine(bad_frac, base=base)
+            with span("detect.quarantine", hosts=hosts):
+                quar = self._update_quarantine(bad_frac, base=base)
         qhosts = np.flatnonzero(quar)
         # persistence gate, the scalar spike.detect rule batched over hosts:
         # a host is a straggler only if `persistence` of its window sits
@@ -541,8 +553,13 @@ class FleetMonitor:
             moments = None
             if self._inc is not None:
                 if lvt is None and not force_oracle and tick_end is not None:
-                    moments = self._inc.moments(
-                        lat[:, T - wn - bn:T], tick_end, wn, bn, base=base)
+                    with span("detect.moments", rows=hosts) as sp:
+                        moments = self._inc.moments(
+                            lat[:, T - wn - bn:T], tick_end, wn, bn,
+                            base=base)
+                        sp.set_metadata(
+                            blocks_computed=self._inc.last_round_computed,
+                            rebuilt_rows=self._inc.last_round_rebuilt_rows)
                 else:
                     self._inc.invalidate(np.arange(base, base + hosts))
             fire, scores, onset_all = detect_ops.detect_hosts_slab(
@@ -629,13 +646,6 @@ class FleetMonitor:
         diagnoses: Dict[int, Diagnosis] = {}
         causes: Dict[int, List[CauseClass]] = {}
         mitigations: Dict[int, Mitigation] = {}
-        # strike lifecycle: a host that recovered (not flagged THIS round)
-        # loses its strike history immediately, even while other hosts stay
-        # flagged — otherwise churn leaves stale counts behind forever and
-        # the dict grows unbounded with fleet size
-        flagged_set = {int(h) for h in flagged}
-        for h in [h for h in self._strikes if h not in flagged_set]:
-            del self._strikes[h]
         degraded = self._degraded
         deferred: List[int] = []
         if flagged.size:
@@ -645,13 +655,23 @@ class FleetMonitor:
             if rca_hosts.size:
                 geom = self._evidence_geometry(channels, li, T, wn, bn)
                 if geom is not None:
-                    t_gather = time.perf_counter()
-                    X = evidence_for(geom, rca_hosts)
-                    stage["gather"] = (stage.get("gather", 0.0)
-                                       + time.perf_counter() - t_gather)
+                    with stage_span(stage, "gather", "monitor.gather",
+                                    hosts=rca_hosts.size) as sp:
+                        X = evidence_for(geom, rca_hosts)
+                        sp.set_metadata(bytes=X.nbytes)
                     diagnoses, causes = self._rca_from_evidence(
                         ts, X, geom, rca_hosts, (T - wn) + rca_onsets,
                         scores, stage)
+        with span("finish.lifecycle", flagged=flagged.size):
+            # strike lifecycle: a host that recovered (not flagged THIS
+            # round) loses its strike history immediately, even while
+            # other hosts stay flagged — otherwise churn leaves stale
+            # counts behind forever and the dict grows unbounded with
+            # fleet size.  The RCA selection above reads the strikes of
+            # flagged hosts only, which this purge never touches.
+            flagged_set = {int(h) for h in flagged}
+            for h in [h for h in self._strikes if h not in flagged_set]:
+                del self._strikes[h]
             deferred_set = set(deferred)
             for h in flagged:
                 h = int(h)
@@ -667,12 +687,12 @@ class FleetMonitor:
                     mitigations[h] = Mitigation.NONE
                 else:
                     mitigations[h] = VERDICT_TO_MITIGATION[d.top_cause]
-        # quarantined hosts carry the telemetry-fault verdict: fire was
-        # suppressed and score zeroed above, so they can neither lead the
-        # flagged list nor accrue strikes — the only actionable output is
-        # "restart that host's telemetry agent"
-        for h in qhosts:
-            mitigations[int(h)] = Mitigation.RESTART_TELEMETRY
+            # quarantined hosts carry the telemetry-fault verdict: fire was
+            # suppressed and score zeroed above, so they can neither lead
+            # the flagged list nor accrue strikes — the only actionable
+            # output is "restart that host's telemetry agent"
+            for h in qhosts:
+                mitigations[int(h)] = Mitigation.RESTART_TELEMETRY
         # the worst *persistent* host; bare arg-max only as the quiet-fleet
         # readout (a transient max-z glitch must not name a straggler)
         straggler = int(flagged[0]) if flagged.size else int(np.argmax(scores))
@@ -769,98 +789,96 @@ class FleetMonitor:
         RCA'd hosts) and therefore always runs at fleet level, on the
         gathered candidates — never per shard."""
         cfg = self.cfg
-        t_gather = time.perf_counter()
         rate = cfg.rate_hz
         nb, rn = geom.nb, geom.rn
         names = geom.names
         names_pos = {n: m for m, n in enumerate(names)}
         T = int(geom.cols[-1]) + 1
-        L_win = X[:, 0, nb:]                                    # (H, rn)
-        Xm = X[:, 1:, :]                                        # (H, M, nb+rn)
-
-        # orientation about the baseline-region mean, batched over hosts —
-        # same slice/orientation policy as engine._diagnose (shared helpers)
-        head = int(np.min(onset_idx) - (T - rn))
-        b_sl = pick_baseline_slice(nb, head, nb + rn)
-        XO = orient_about_baseline(Xm, geom.orient, b_sl)
-        W = XO[:, :, nb:]                                       # (H, M, rn)
-        Bm = XO[:, :, b_sl]                                     # (H, M, nb')
-        # multi-hypothesis co-cause corroboration over the SAME gathered
-        # slab: per cause, does some symptom channel show a two-sided raw-z
-        # deviation at/above its floor (reconcile's corroboration test,
-        # vectorized over hosts)?  Computed in f64 on the raw (unoriented,
-        # forward-filled) evidence so the fast f32 gather and the f64
-        # oracle agree on every verdict-cause list.
-        sym_ok: Dict[CauseClass, np.ndarray] = {}
-        if cfg.max_hypotheses > 1:
-            for cause, chans in symptom_table().items():
-                ok = np.zeros(flagged.size, bool)
-                for name, floor in chans:
-                    m = names_pos.get(name)
-                    if m is None:
-                        continue
-                    seg = np.asarray(Xm[:, m, :], np.float64)
-                    B, Wr = seg[:, b_sl], seg[:, nb:]
-                    if B.shape[1] == 0 or Wr.shape[1] == 0:
-                        continue
-                    mb = B.mean(axis=1)
-                    sd = np.maximum(B.std(axis=1),
-                                    np.maximum(1e-3 * np.abs(mb), 1e-9))
-                    ok |= np.abs(Wr.mean(axis=1) - mb) / sd >= floor
-                sym_ok[cause] = ok
-        stage["gather"] = (stage.get("gather", 0.0)
-                           + time.perf_counter() - t_gather)
+        with stage_span(stage, "gather", "rca.orient"):
+            L_win = X[:, 0, nb:]                            # (H, rn)
+            Xm = X[:, 1:, :]                                # (H, M, nb+rn)
+            # orientation about the baseline-region mean, batched over
+            # hosts — same slice/orientation policy as engine._diagnose
+            # (shared helpers)
+            head = int(np.min(onset_idx) - (T - rn))
+            b_sl = pick_baseline_slice(nb, head, nb + rn)
+            XO = orient_about_baseline(Xm, geom.orient, b_sl)
+            W = XO[:, :, nb:]                               # (H, M, rn)
+            Bm = XO[:, :, b_sl]                             # (H, M, nb')
+            # multi-hypothesis co-cause corroboration over the SAME
+            # gathered slab: per cause, does some symptom channel show a
+            # two-sided raw-z deviation at/above its floor (reconcile's
+            # corroboration test, vectorized over hosts)?  Computed in f64
+            # on the raw (unoriented, forward-filled) evidence so the fast
+            # f32 gather and the f64 oracle agree on every verdict-cause
+            # list.
+            sym_ok: Dict[CauseClass, np.ndarray] = {}
+            if cfg.max_hypotheses > 1:
+                for cause, chans in symptom_table().items():
+                    ok = np.zeros(flagged.size, bool)
+                    for name, floor in chans:
+                        m = names_pos.get(name)
+                        if m is None:
+                            continue
+                        seg = np.asarray(Xm[:, m, :], np.float64)
+                        B, Wr = seg[:, b_sl], seg[:, nb:]
+                        if B.shape[1] == 0 or Wr.shape[1] == 0:
+                            continue
+                        mb = B.mean(axis=1)
+                        sd = np.maximum(B.std(axis=1),
+                                        np.maximum(1e-3 * np.abs(mb), 1e-9))
+                        ok |= np.abs(Wr.mean(axis=1) - mb) / sd >= floor
+                    sym_ok[cause] = ok
 
         # one fused dispatch: spike scores + max-|rho| + arg-max lag
-        t_kernel = time.perf_counter()
-        s, c, lags = fused_ops.fused_rca_max(
-            np.asarray(L_win, np.float32), np.asarray(W, np.float32),
-            np.asarray(Bm, np.float32), max_lag=cfg.max_lag,
-            use_kernel=self.use_kernels)
-        s, c, lags = np.asarray(s), np.asarray(c), np.asarray(lags)
-        stage["kernel"] = time.perf_counter() - t_kernel
+        with stage_span(stage, "kernel", "rca.kernel", batch=flagged.size):
+            s, c, lags = fused_ops.fused_rca_max(
+                np.asarray(L_win, np.float32), np.asarray(W, np.float32),
+                np.asarray(Bm, np.float32), max_lag=cfg.max_lag,
+                use_kernel=self.use_kernels)
+            s, c, lags = np.asarray(s), np.asarray(c), np.asarray(lags)
 
-        t_rank = time.perf_counter()
-        ranked_all = conf_mod.rank_causes_batch(
-            names, s, c, lags / rate, cfg.alpha, details=False)
-        # operators drill into the worst host (flagged[0]): full per-metric
-        # detail for it only, via the same ranker
-        ranked_all[0] = conf_mod.rank_causes_batch(
-            names, s[:1], c[:1], lags[:1] / rate, cfg.alpha, details=True)[0]
-        t_assemble = time.perf_counter()
-        # disjoint stages: "rank" is the confidence fusion only; the
-        # Diagnosis-object assembly below is its own stage, so benchmark
-        # attribution sums to the wall total with no double counting
-        stage["rank"] = t_assemble - t_rank
+        # "rank" is the confidence fusion only; the Diagnosis-object
+        # assembly below is its own disjoint stage
+        with stage_span(stage, "rank", "rca.rank"):
+            ranked_all = conf_mod.rank_causes_batch(
+                names, s, c, lags / rate, cfg.alpha, details=False)
+            # operators drill into the worst host (flagged[0]): full
+            # per-metric detail for it only, via the same ranker
+            ranked_all[0] = conf_mod.rank_causes_batch(
+                names, s[:1], c[:1], lags[:1] / rate, cfg.alpha,
+                details=True)[0]
         out: Dict[int, Diagnosis] = {}
         causes: Dict[int, List[CauseClass]] = {}
-        now = float(ts[T - 1])
-        # Layer-3/4 compute cost, shared by the whole batch (paper's
-        # Time-to-RCA includes analysis compute)
-        analysis = t_assemble - t_kernel
-        for j, h in enumerate(flagged):
-            h = int(h)
-            ranked, per_metric = ranked_all[j]
-            ev = SpikeEvent(t_onset=float(ts[int(onset_idx[j])]),
-                            t_detect=now, score=float(scores[h]),
-                            metric=cfg.latency_metric)
-            out[h] = Diagnosis(event=ev, ranked=ranked,
-                               per_metric=per_metric, t_rca=now + analysis,
-                               analysis_seconds=analysis, t_ready=now)
-            cl = [ranked[0].cause] if ranked else []
-            if ranked and cfg.max_hypotheses > 1:
-                # co-causes: corroborated runners within their per-cause
-                # confidence gap of the primary, rank order preserved
-                top = ranked[0].confidence
-                for rc in ranked[1:]:
-                    ok = sym_ok.get(rc.cause)
-                    if ok is None or not bool(ok[j]):
-                        continue
-                    if top - rc.confidence > CO_GAP.get(rc.cause, 0.0):
-                        continue
-                    cl.append(rc.cause)
-            causes[h] = cl
-        stage["assemble"] = time.perf_counter() - t_assemble
+        with stage_span(stage, "assemble", "rca.assemble"):
+            now = float(ts[T - 1])
+            # Layer-3/4 compute cost, shared by the whole batch (paper's
+            # Time-to-RCA includes analysis compute)
+            analysis = stage["kernel"] + stage["rank"]
+            for j, h in enumerate(flagged):
+                h = int(h)
+                ranked, per_metric = ranked_all[j]
+                ev = SpikeEvent(t_onset=float(ts[int(onset_idx[j])]),
+                                t_detect=now, score=float(scores[h]),
+                                metric=cfg.latency_metric)
+                out[h] = Diagnosis(event=ev, ranked=ranked,
+                                   per_metric=per_metric,
+                                   t_rca=now + analysis,
+                                   analysis_seconds=analysis, t_ready=now)
+                cl = [ranked[0].cause] if ranked else []
+                if ranked and cfg.max_hypotheses > 1:
+                    # co-causes: corroborated runners within their
+                    # per-cause confidence gap of the primary, rank order
+                    # preserved
+                    top = ranked[0].confidence
+                    for rc in ranked[1:]:
+                        ok = sym_ok.get(rc.cause)
+                        if ok is None or not bool(ok[j]):
+                            continue
+                        if top - rc.confidence > CO_GAP.get(rc.cause, 0.0):
+                            continue
+                        cl.append(rc.cause)
+                causes[h] = cl
         return out, causes
 
 

@@ -51,12 +51,13 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.engine import MIN_BASELINE_N
+from repro.core.spans import span
+from repro.core.spans import stage as stage_span
 from repro.kernels import tuning
 from repro.monitor.fleet import FleetDiagnosis, FleetMonitor
 
@@ -346,32 +347,35 @@ class ShardedFleetMonitor(FleetMonitor):
         views into it (no copy).  Knowing the whole mask upfront lets the
         round pick the oracle/fast path once instead of re-visiting
         shards (see :meth:`diagnose_sharded`)."""
-        host_data = np.asarray(host_data)
-        if host_data.shape[0] != self.plan.hosts:
-            raise ValueError(f"host_data covers {host_data.shape[0]} hosts,"
-                             f" plan covers {self.plan.hosts}")
-        vfull = None
-        if valid is not None:
-            v = np.asarray(valid, bool)
-            if v.shape != host_data.shape:
-                raise ValueError(f"valid {v.shape} vs data "
-                                 f"{host_data.shape}")
-            if not v.all():
-                vfull = v
-        li = list(channels).index(self.cfg.latency_metric)
-        T = host_data.shape[2]
-        wn = min(self.cfg.window_n, T // 2)
-        bn = min(self.cfg.baseline_n, T - wn)
-        any_invalid = (vfull is not None
-                       and not vfull[:, li, T - wn - bn:T].all())
+        with span("monitor.round", hosts=self.plan.hosts):
+            host_data = np.asarray(host_data)
+            if host_data.shape[0] != self.plan.hosts:
+                raise ValueError(
+                    f"host_data covers {host_data.shape[0]} hosts,"
+                    f" plan covers {self.plan.hosts}")
+            vfull = None
+            if valid is not None:
+                v = np.asarray(valid, bool)
+                if v.shape != host_data.shape:
+                    raise ValueError(f"valid {v.shape} vs data "
+                                     f"{host_data.shape}")
+                if not v.all():
+                    vfull = v
+            li = list(channels).index(self.cfg.latency_metric)
+            T = host_data.shape[2]
+            wn = min(self.cfg.window_n, T // 2)
+            bn = min(self.cfg.baseline_n, T - wn)
+            any_invalid = (vfull is not None
+                           and not vfull[:, li, T - wn - bn:T].all())
 
-        def provider(s: int) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-            a, b = self.plan.bounds[s]
-            return (host_data[a:b],
-                    None if vfull is None else vfull[a:b])
+            def provider(s: int) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+                a, b = self.plan.bounds[s]
+                return (host_data[a:b],
+                        None if vfull is None else vfull[a:b])
 
-        return self._diagnose_shards(ts, provider, channels, extra_cost_s,
-                                     any_invalid=any_invalid)
+            return self._diagnose_shards(ts, provider, channels,
+                                         extra_cost_s,
+                                         any_invalid=any_invalid)
 
     def diagnose_sharded(self, ts: np.ndarray, provider: ShardProvider,
                          channels: Sequence[str],
@@ -387,8 +391,9 @@ class ShardedFleetMonitor(FleetMonitor):
         the oracle for *every* host), which calls the provider a second
         time for those shards.  Clean rounds visit each shard exactly
         once."""
-        return self._diagnose_shards(ts, provider, channels, extra_cost_s,
-                                     any_invalid=None)
+        with span("monitor.round", hosts=self.plan.hosts):
+            return self._diagnose_shards(ts, provider, channels,
+                                         extra_cost_s, any_invalid=None)
 
     def _diagnose_shards(self, ts: np.ndarray, provider: ShardProvider,
                          channels: Sequence[str], extra_cost_s: float,
@@ -413,67 +418,68 @@ class ShardedFleetMonitor(FleetMonitor):
 
         def visit(s: int, force_oracle: bool) -> None:
             nonlocal geom, dims, tick_end
-            a, b = plan.bounds[s]
-            slab, val = provider(s)
-            slab = np.asarray(slab)
-            if slab.ndim != 3 or slab.shape[0] != b - a:
-                raise ValueError(f"shard {s} slab {slab.shape} vs bounds "
-                                 f"({a}, {b})")
-            if dims is None:
-                T = slab.shape[2]
-                wn = min(self.cfg.window_n, T // 2)
-                bn = min(self.cfg.baseline_n, T - wn)
-                if bn < MIN_BASELINE_N:
-                    raise _ShortBaseline
-                dims = (T, wn, bn)
-                geom = self._evidence_geometry(channels, li, T, wn, bn)
-                tick_end = self._tick_end(ts, T)
-            T, wn, bn = dims
-            if slab.shape[2] != T:
-                raise ValueError(f"shard {s} T={slab.shape[2]} vs {T}")
-            vfull = None
-            if val is not None:
-                v = np.asarray(val, bool)
-                if v.shape != slab.shape:
-                    raise ValueError(f"shard {s} valid {v.shape} vs slab "
-                                     f"{slab.shape}")
-                if not v.all():
-                    vfull = v
-            saw_invalid[s] = (
-                vfull is not None
-                and not vfull[:, li, T - wn - bn:T].all())
-            t0 = time.perf_counter()
-            # base=a keys the incremental moment rows (and quarantine
-            # state) by absolute host id; a forced-oracle re-visit
-            # invalidates rather than advances them, so a shard visited
-            # twice in one round cannot double-advance the moment state
-            scores, cand, onset_rel, qloc = self._detect_round(
-                slab, vfull, li, T, wn, bn,
-                force_oracle=force_oracle, device=self.devices[s],
-                base=a, quar=quar_saved[s], tick_end=tick_end)
-            stage["detect"] += time.perf_counter() - t0
-            if quar_saved[s] is None:
-                qmask = np.zeros(b - a, bool)
-                qmask[qloc] = True
-                quar_saved[s] = qmask
-            ran_oracle[s] = force_oracle or saw_invalid[s]
-            # local RCA selection mirrors the fleet's (same total order,
-            # same degraded/top-K policy) so every evidence block the
-            # fleet level will need is shipped — see _rca_selection
-            order = np.argsort(-scores[cand], kind="stable")
-            sel, _, _ = self._rca_selection(
-                cand[order] + a, onset_rel[order])
-            evidence: Dict[int, np.ndarray] = {}
-            if geom is not None and sel.size:
-                t1 = time.perf_counter()
-                X = self._gather_evidence(slab, sel - a, geom, vfull)
-                stage["gather"] = (stage.get("gather", 0.0)
-                                   + time.perf_counter() - t1)
-                evidence = {int(h): X[k] for k, h in enumerate(sel)}
-            per_shard[s] = ShardCandidates(
-                idx=cand + a, score=scores[cand], onset=onset_rel,
-                qhosts=qloc + a, evidence=evidence)
-            shard_scores[s] = scores
+            with span("shard.visit", shard=s, oracle=force_oracle):
+                a, b = plan.bounds[s]
+                with span("shard.provider", shard=s):
+                    slab, val = provider(s)
+                slab = np.asarray(slab)
+                if slab.ndim != 3 or slab.shape[0] != b - a:
+                    raise ValueError(f"shard {s} slab {slab.shape} vs bounds "
+                                     f"({a}, {b})")
+                if dims is None:
+                    T = slab.shape[2]
+                    wn = min(self.cfg.window_n, T // 2)
+                    bn = min(self.cfg.baseline_n, T - wn)
+                    if bn < MIN_BASELINE_N:
+                        raise _ShortBaseline
+                    dims = (T, wn, bn)
+                    geom = self._evidence_geometry(channels, li, T, wn, bn)
+                    tick_end = self._tick_end(ts, T)
+                T, wn, bn = dims
+                if slab.shape[2] != T:
+                    raise ValueError(f"shard {s} T={slab.shape[2]} vs {T}")
+                vfull = None
+                if val is not None:
+                    v = np.asarray(val, bool)
+                    if v.shape != slab.shape:
+                        raise ValueError(f"shard {s} valid {v.shape} vs slab "
+                                         f"{slab.shape}")
+                    if not v.all():
+                        vfull = v
+                saw_invalid[s] = (
+                    vfull is not None
+                    and not vfull[:, li, T - wn - bn:T].all())
+                # base=a keys the incremental moment rows (and quarantine
+                # state) by absolute host id; a forced-oracle re-visit
+                # invalidates rather than advances them, so a shard visited
+                # twice in one round cannot double-advance the moment state
+                with stage_span(stage, "detect", "monitor.detect", rows=b - a):
+                    scores, cand, onset_rel, qloc = self._detect_round(
+                        slab, vfull, li, T, wn, bn,
+                        force_oracle=force_oracle, device=self.devices[s],
+                        base=a, quar=quar_saved[s], tick_end=tick_end)
+                if quar_saved[s] is None:
+                    qmask = np.zeros(b - a, bool)
+                    qmask[qloc] = True
+                    quar_saved[s] = qmask
+                ran_oracle[s] = force_oracle or saw_invalid[s]
+                # local RCA selection mirrors the fleet's (same total order,
+                # same degraded/top-K policy) so every evidence block the
+                # fleet level will need is shipped — see _rca_selection
+                order = np.argsort(-scores[cand], kind="stable")
+                sel, _, _ = self._rca_selection(
+                    cand[order] + a, onset_rel[order])
+                evidence: Dict[int, np.ndarray] = {}
+                if geom is not None and sel.size:
+                    with stage_span(stage, "gather", "monitor.gather",
+                                    hosts=sel.size) as sp:
+                        X = self._gather_evidence(slab, sel - a, geom, vfull)
+                        sp.set_metadata(bytes=X.nbytes)
+                    evidence = {int(h): X[k] for k, h in enumerate(sel)}
+                per_shard[s] = ShardCandidates(
+                    idx=cand + a, score=scores[cand], onset=onset_rel,
+                    qhosts=qloc + a, evidence=evidence)
+                shard_scores[s] = scores
 
         force_all = bool(any_invalid)
         try:
@@ -495,44 +501,45 @@ class ShardedFleetMonitor(FleetMonitor):
 
         # rack-level reduce: merge member candidate lists, prune evidence
         # to the rack's own RCA selection
-        t2 = time.perf_counter()
-        rack_cands: List[ShardCandidates] = []
-        for rack in plan.racks:
-            members = [per_shard[s] for s in rack]
-            for m in members:
-                traffic.shard_scalar_bytes += m.scalar_bytes
-                traffic.shard_evidence_bytes += m.evidence_bytes
-                traffic.n_candidates += int(m.idx.size)
-            idx = np.concatenate([m.idx for m in members])
-            score = np.concatenate([m.score for m in members])
-            onset = np.concatenate([m.onset for m in members])
-            qh = np.concatenate([m.qhosts for m in members])
-            order = np.argsort(-score, kind="stable")
-            sel, _, _ = self._rca_selection(idx[order], onset[order])
-            merged_ev: Dict[int, np.ndarray] = {}
-            for m in members:
-                merged_ev.update(m.evidence)
-            rc = ShardCandidates(
-                idx=idx, score=score, onset=onset, qhosts=qh,
-                evidence={int(h): merged_ev[int(h)] for h in sel
-                          if int(h) in merged_ev})
-            traffic.rack_scalar_bytes += rc.scalar_bytes
-            traffic.rack_evidence_bytes += rc.evidence_bytes
-            traffic.n_evidence += len(rc.evidence)
-            rack_cands.append(rc)
+        with stage_span(stage, "reduce", "shard.reduce") as sp:
+            rack_cands: List[ShardCandidates] = []
+            for rack in plan.racks:
+                members = [per_shard[s] for s in rack]
+                for m in members:
+                    traffic.shard_scalar_bytes += m.scalar_bytes
+                    traffic.shard_evidence_bytes += m.evidence_bytes
+                    traffic.n_candidates += int(m.idx.size)
+                idx = np.concatenate([m.idx for m in members])
+                score = np.concatenate([m.score for m in members])
+                onset = np.concatenate([m.onset for m in members])
+                qh = np.concatenate([m.qhosts for m in members])
+                order = np.argsort(-score, kind="stable")
+                sel, _, _ = self._rca_selection(idx[order], onset[order])
+                merged_ev: Dict[int, np.ndarray] = {}
+                for m in members:
+                    merged_ev.update(m.evidence)
+                rc = ShardCandidates(
+                    idx=idx, score=score, onset=onset, qhosts=qh,
+                    evidence={int(h): merged_ev[int(h)] for h in sel
+                              if int(h) in merged_ev})
+                traffic.rack_scalar_bytes += rc.scalar_bytes
+                traffic.rack_evidence_bytes += rc.evidence_bytes
+                traffic.n_evidence += len(rc.evidence)
+                rack_cands.append(rc)
 
-        # fleet level: concatenate rack candidates (shard order keeps
-        # absolute ids ascending) and hand the merged round to the
-        # unchanged fleet verdict logic
-        scores = np.concatenate([shard_scores[s]
-                                 for s in range(plan.n_shards)])
-        cand = np.concatenate([rc.idx for rc in rack_cands])
-        onset_rel = np.concatenate([rc.onset for rc in rack_cands])
-        qhosts = np.concatenate([rc.qhosts for rc in rack_cands])
-        blocks: Dict[int, np.ndarray] = {}
-        for rc in rack_cands:
-            blocks.update(rc.evidence)
-        stage["reduce"] = time.perf_counter() - t2
+            # fleet level: concatenate rack candidates (shard order keeps
+            # absolute ids ascending) and hand the merged round to the
+            # unchanged fleet verdict logic
+            scores = np.concatenate([shard_scores[s]
+                                     for s in range(plan.n_shards)])
+            cand = np.concatenate([rc.idx for rc in rack_cands])
+            onset_rel = np.concatenate([rc.onset for rc in rack_cands])
+            qhosts = np.concatenate([rc.qhosts for rc in rack_cands])
+            blocks: Dict[int, np.ndarray] = {}
+            for rc in rack_cands:
+                blocks.update(rc.evidence)
+            sp.set_metadata(candidates=traffic.n_candidates,
+                            evidence=traffic.n_evidence)
         traffic.score_bytes = int(scores.size) * 8
         # counterfactual: what shipping every raw f32 shard slab would cost
         T, wn, bn = dims
@@ -548,9 +555,10 @@ class ShardedFleetMonitor(FleetMonitor):
                     "RCA set (top-K superset invariant violated)")
             return np.stack([blocks[int(h)] for h in rca_hosts])
 
-        return self._finish_round(ts, channels, li, T, wn, bn, scores,
-                                  cand, onset_rel, qhosts, stage,
-                                  extra_cost_s, evidence_for)
+        with span("monitor.finish", flagged=cand.size):
+            return self._finish_round(ts, channels, li, T, wn, bn, scores,
+                                      cand, onset_rel, qhosts, stage,
+                                      extra_cost_s, evidence_for)
 
     # ------------------------------------------------------------ checkpoint
     def state_dict(self) -> Dict[str, object]:

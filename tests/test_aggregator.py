@@ -272,6 +272,35 @@ def test_delta_restage_bitwise_equals_full_restage():
     assert b.stats.full_restages == len(schedule) * 4
 
 
+def test_staged_bytes_counts_each_write():
+    """``staged_bytes`` sums the bytes each staging write moves: a full
+    restage and a delta read (left-shift plus new ticks) each rewrite one
+    whole row of slab, timestamps and validity; an unchanged row moves
+    nothing; a late joiner's right-align and backfill move more."""
+    _, agents = _fleet(3, bad_host=0, history_s=30.0)
+    agg = FleetAggregator(agents, window_s=20.0)
+    C, T = len(agg.channels), agg.window_n
+    row = C * T * 4 + T * 8 + C * T
+    agg.run_virtual(0.0, 25.0)
+    agg.assemble()
+    assert agg.stats.full_restages == 3
+    assert agg.stats.staged_bytes == 3 * row
+    agg.run_virtual(25.0, 25.5)
+    agg.assemble()
+    assert agg.stats.delta_reads == 3
+    assert agg.stats.staged_bytes == 6 * row
+    agg.assemble()                      # nothing pushed: rows reused
+    assert agg.stats.unchanged_skips == 3
+    assert agg.stats.staged_bytes == 6 * row
+
+    _, young = _fleet(2, bad_host=0, history_s=30.0)
+    agg = FleetAggregator(young, window_s=20.0)
+    agg.run_virtual(0.0, 10.0)          # half a window: backfilled rows
+    agg.assemble()
+    assert agg.stats.ragged_hosts == 2
+    assert agg.stats.staged_bytes > 2 * row
+
+
 def test_restart_agent_voids_staged_row():
     _, agents = _fleet(3, bad_host=0, history_s=30.0)
     agg = FleetAggregator(agents, window_s=20.0)

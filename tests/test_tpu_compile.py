@@ -9,14 +9,17 @@ topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import roofline, trace_reduce
 from repro.core.engine import EngineConfig
 from repro.kernels import tuning
+from repro.kernels.fused import ops as fused_ops
 from repro.kernels.fused.fused import fused_rca_masked_pallas, fused_rca_pallas
 from repro.kernels.spike.spike import spike_scores_pallas
 from repro.kernels.sweep import ops as sweep_ops
@@ -141,3 +144,38 @@ def test_single_purpose_kernels_compile(one_chip, rca_geometry, kernel):
                                interpret=False)
         args = (((B, M, _pad128(cfg.baseline_n)), F32),)
     _compile(fn, _shapes(one_chip, *args))
+
+
+_CUSTOM_CALL = re.compile(
+    r'^\s*(?:ROOT\s+)?%(\S+) = .*custom-call\(.*'
+    r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def test_kernel_names_match_the_roofline_readers(one_chip, rca_geometry):
+    """The device trace names each kernel by the HLO instruction of its
+    custom call, which takes the name of the jitted function around the
+    ``pallas_call``.  ``bench/roofline.py`` finds the sweep and fused
+    kernels by those names: a rename fails here instead of silently
+    leaving ``sweep_roofline`` and ``fused_roofline`` without a reading."""
+    cfg = EngineConfig()
+    wn, R = cfg.window_n, tuning.shard_hosts()
+    sweep = sweep_ops._sweep_jit.lower(
+        *_shapes(one_chip, ((R, wn), F32), ((R, 1), F32), ((R, 1), F32),
+                 ((1,), I32), ((R,), I32)),
+        wn=wn, threshold=cfg.threshold,
+        min_hot=sweep_ops.persistence_count(wn, cfg.persistence),
+        eps=sweep_ops.SWEEP_GUARD_EPS, argmax_fallback=True, use_kernel=True,
+        interpret=False, block_t=tuning.sweep_block_t(None)).compile()
+    B, M, rn, nb = rca_geometry
+    # the fused op asks the dispatch's device whether to interpret
+    with jax.default_device(next(iter(one_chip.device_set))):
+        fused = fused_ops._fused_rca_max_jit.lower(
+            *_shapes(one_chip, ((B, rn), F32), ((B, M, rn), F32),
+                     ((B, M, nb), F32)),
+            max_lag=cfg.max_lag, use_kernel=True).compile()
+    names = [[trace_reduce.op_name(n)
+              for n in _CUSTOM_CALL.findall(c.as_text())]
+             for c in (sweep, fused)]
+    assert names == [[roofline.SWEEP_OP], [roofline.FUSED_OP]]
+    assert roofline.is_sweep_op(names[0][0])
+    assert roofline.is_fused_op(names[1][0])
