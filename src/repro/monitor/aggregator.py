@@ -8,11 +8,17 @@ must read *while they write*.  :class:`FleetAggregator` owns the agents
 and assembles the monitor's (hosts, C, T) f32 slab from each host's ring
 via the seqlock reader (:meth:`MultiChannelRing.read_window`):
 
-  * **one bounded copy per host** — each host's trailing window lands
-    straight from the ring's zero-copy views into a row of a preallocated
-    f32 staging slab (no per-assembly allocation); a wrapped span costs
-    the same copy split in two, and only a torn read (writer collided
-    mid-copy) repeats it,
+  * **a mirrored window frame** — the staging buffers are (hosts, C, 2T):
+    tick ``j`` of the fleet's tick grid lives at column ``j mod T`` and
+    again at ``j mod T + T``, so the round's window is always the
+    contiguous column range ``[s, s + T)`` and the snapshot hands out
+    views of it.  A steady-state round reads each host's new ticks out
+    of its ring straight into the frame's moving edge; the validity of
+    those columns and their mirror copy are then written once for the
+    whole fleet.  No row is ever shifted.  A row that does not fit the
+    frame (ragged, torn, restarted, off the grid) takes one bounded
+    full-window copy instead; only a torn read (writer collided
+    mid-copy) repeats a copy,
   * **clock alignment** — hosts are right-aligned on the newest timestamp
     every live host has reached (``t_common``); hosts that have sampled
     past it contribute their window *ending at* ``t_common``,
@@ -24,7 +30,9 @@ via the seqlock reader (:meth:`MultiChannelRing.read_window`):
 
 ``diagnose`` feeds the staged slab directly to a
 :class:`~repro.monitor.fleet.FleetMonitor` — the training loop's
-per-diagnosis defensive full-window copy is gone.
+per-diagnosis defensive full-window copy is gone — and, since the stager
+keeps each row's count of invalid cells, passes no validity mask at all
+when the window holds none.
 """
 from __future__ import annotations
 
@@ -54,8 +62,8 @@ class AggregatorStats:
     delta_reads: int = 0        # rows advanced by a delta read, not T ticks
     full_restages: int = 0      # live rows that took the full T-tick copy
     #: bytes written into the staging buffers (slab, timestamps, validity
-    #: and their scratch rows), summed at each write: left-shifts, ring
-    #: reads, validity masks, and the zeroing of dead and short rows
+    #: and their scratch rows), summed at each write: ring reads, validity
+    #: masks, mirror copies, and the zeroing of dead and short rows
     staged_bytes: int = 0
 
 
@@ -63,7 +71,7 @@ class AggregatorStats:
 class FleetSnapshot:
     """One staged (hosts, C, T) assembly: slab, clock, validity, skips."""
     ts: np.ndarray              # (T,) reference clock, newest at T-1
-    slab: np.ndarray            # (hosts, C, T) f32 — the staging buffer
+    slab: np.ndarray            # (hosts, C, T) f32 — view of the frame
     valid: np.ndarray           # (hosts,) true sample count per row
     skipped: List[int]          # dead/stale hosts (rows zeroed)
     retries: int                # torn-read retries during this assembly
@@ -76,6 +84,9 @@ class FleetSnapshot:
     #: ``diagnose`` for that round (NOT flagged-eligible; an operator must
     #: not read their zero spike score as "monitored and healthy")
     masked: List[int] = dataclasses.field(default_factory=list)
+    #: (hosts, T) f64 — each row's own staged timestamps (``ts`` is one
+    #: of them, the reference row's)
+    ts_rows: Optional[np.ndarray] = None
 
 
 
@@ -115,18 +126,24 @@ class FleetAggregator:
                              else max(10.0 * period, 0.5))
         self.min_samples = int(min_samples)
         H, C, T = len(self.agents), len(self.channels), self.window_n
-        # preallocated staging: every assembly reuses these buffers, so the
-        # steady-state cost is one bounded memcpy per host and zero allocs
-        self._slab = np.zeros((H, C, T), np.float32)
-        self._ts_rows = np.zeros((H, T), np.float64)
+        # preallocated mirrored frame: every assembly reuses these buffers
+        # and zero allocs; columns c and c + T always hold the same tick,
+        # so the window [s, s + T) is contiguous wherever s falls
+        self._slab = np.zeros((H, C, 2 * T), np.float32)
+        self._ts_rows = np.zeros((H, 2 * T), np.float64)
         self._scratch = np.empty((C, T), np.float32)
         self._ts_scratch = np.empty(T, np.float64)
-        self._valid = np.ones((H, C, T), bool)
+        self._valid = np.ones((H, C, 2 * T), bool)
+        #: invalid cells in each row's staged window (``~valid`` count)
+        self._invalid = np.zeros(H, np.int64)
+        #: tick (``round(t * rate)``) of the last staged ``t_common``; the
+        #: window is the columns ``[s, s + T)``, ``s = (tick - T + 1) mod T``
+        self._frame_tick = 0
         # delta-staging bookkeeping: a row whose last stage was a full
         # clean T-tick window (no trim, no backfill, no masking since)
         # records the seqlock sequence + newest staged tick; the next
         # assembly then reuses the row untouched (sequence unchanged) or
-        # left-shifts it and reads only the delta ticks out of the ring
+        # reads only the delta ticks out of the ring into the frame
         self._staged_seq = np.full(H, -1, np.int64)
         self._staged_last = np.full(H, -np.inf)
         self._staged_full = np.zeros(H, bool)
@@ -218,65 +235,94 @@ class FleetAggregator:
     # ------------------------------------------------------------- assembly
     def _stage_delta(self, h: int, agent: TelemetryAgent, skip: int,
                      count: int, seq: int, t_common: float, period: float,
-                     ) -> tuple:
+                     adv: int, cols: slice) -> tuple:
         """O(delta) staging attempt for one live host row.
 
         Preconditions for even trying: the row's previous stage was a
-        full clean T-tick window (``_staged_full``), this round wants the
-        un-skipped steady-state alignment (``skip == 0``), and the ring
-        holds a full window.  Then either the seqlock sequence is
-        unchanged — nothing was pushed, the staged row *is* this round's
-        window, zero ring reads — or the new right edge sits a whole
-        number of ticks ahead: the row (values, timestamps, validity) is
-        left-shifted and only the ``delta`` new columns are read out of
-        the ring.  Both outcomes are bitwise-identical to the full
-        restage they replace (ring history is append-only, so the
-        overlapping columns could not have changed).  Any gap, torn
-        read, or off-grid timestamp voids the attempt — the caller falls
-        back to the full restage.  Returns ``(staged, retries, nbytes)``,
-        ``nbytes`` the bytes written into the staging buffers.
+        full clean T-tick window (``_staged_full``, so it sits at the
+        previous round's frame offset), this round wants the un-skipped
+        steady-state alignment (``skip == 0``), and the ring holds a full
+        window.  Then either the seqlock sequence is unchanged and the
+        frame did not move (``adv == 0``) — nothing was pushed, the
+        staged row *is* this round's window, zero ring reads — or the new
+        right edge sits exactly the frame's advance ``adv`` ticks ahead:
+        only those new ticks are read out of the ring, straight into the
+        frame columns ``cols`` (the last ``adv`` columns of the new
+        window), whose validity and mirror copy
+        :meth:`_stage_new_columns` then writes for the whole fleet.  Both
+        outcomes are bitwise-identical to the full restage they replace
+        (ring history is append-only, so the overlapping columns could
+        not have changed).  Any gap, torn read, off-grid timestamp, or a
+        shift other than the frame's voids the attempt — the caller falls
+        back to the full restage.  Returns ``(staged, moved, retries,
+        nbytes)``: ``moved`` marks a delta read, ``nbytes`` the bytes
+        written into the staging buffers.
         """
         T = self.window_n
         if not self._staged_full[h] or skip != 0 or count < T:
-            return False, 0, 0
-        if seq >= 0 and seq == self._staged_seq[h] \
+            return False, False, 0, 0
+        if seq >= 0 and seq == self._staged_seq[h] and adv == 0 \
                 and abs(self._staged_last[h] - t_common) <= 0.5 * period:
             self.stats.unchanged_skips += 1
-            return True, 0, 0
+            return True, False, 0, 0
         gap = t_common - self._staged_last[h]
         di = int(round(gap / period))
-        if not (0 < di < T and abs(gap - di * period) <= 0.25 * period):
-            return False, 0, 0
-        row, tsr, vrow = self._slab[h], self._ts_rows[h], self._valid[h]
-        # overlapping left-shift: numpy buffers overlapping assignments,
-        # so this is the memmove it looks like
-        nbytes = (_write(row[:, :T - di], row[:, di:])
-                  + _write(tsr[:T - di], tsr[di:])
-                  + _write(vrow[:, :T - di], vrow[:, di:]))
-        ts_n, d_n, r = agent.ring.read_window(di, out_ts=tsr[T - di:],
-                                              out=row[:, T - di:])
-        nbytes += ts_n.nbytes + d_n.nbytes
+        if not (0 < di < T and di == adv
+                and abs(gap - di * period) <= 0.25 * period):
+            return False, False, 0, 0
+        ts_n, d_n, r = agent.ring.read_window(
+            di, out_ts=self._ts_rows[h, cols], out=self._slab[h, :, cols])
+        nbytes = ts_n.nbytes + d_n.nbytes
         if (ts_n.size != di
                 or abs(float(ts_n[0]) - (self._staged_last[h] + period))
                 > 0.25 * period
                 or abs(float(ts_n[-1]) - t_common) > 0.5 * period):
             # writer raced past the watermark or ticks were dropped: the
-            # shifted row no longer lines up — void it and restage fully
+            # new columns no longer line up — void the row, restage fully
             self._staged_full[h] = False
-            return False, r, nbytes
-        nbytes += np.isfinite(row[:, T - di:], out=vrow[:, T - di:]).nbytes
+            return False, False, r, nbytes
         self._staged_seq[h] = seq
-        self._staged_last[h] = float(tsr[-1])
+        self._staged_last[h] = float(ts_n[-1])
         self.stats.delta_reads += 1
-        return True, r, nbytes
+        return True, True, r, nbytes
+
+    def _mirror(self, rows, a: int, b: int) -> int:
+        """Copy frame columns ``[a, b)`` of ``rows`` (slab, timestamps,
+        validity) onto their twins, column ``c``'s being ``c + T`` below
+        ``T`` and ``c - T`` from it; returns the bytes written."""
+        T = self.window_n
+        nbytes = 0
+        for lo, hi, d in ((a, min(b, T), T), (max(a, T), b, -T)):
+            if lo < hi:
+                for x in (self._slab[rows], self._ts_rows[rows],
+                          self._valid[rows]):
+                    nbytes += _write(x[..., lo + d:hi + d], x[..., lo:hi])
+        return nbytes
+
+    def _stage_new_columns(self, cols: slice, moved: np.ndarray) -> int:
+        """The fleet-wide half of this round's delta reads, over the new
+        frame columns ``cols`` of every row: the validity mask, the
+        invalid-cell counts of the ``moved`` rows (up by the new ticks',
+        down by the leaving ticks', which the mirrored validity still
+        holds there), and the mirror copy.  Every other row already has
+        ``valid == isfinite(slab)`` and mirrored twins, so rewriting its
+        columns changes nothing.  Returns the bytes written."""
+        v = self._valid[:, :, cols]
+        if not v.all():
+            self._invalid[moved] -= (~v).sum(axis=(1, 2))[moved]
+        nbytes = np.isfinite(self._slab[:, :, cols], out=v).nbytes
+        if not v.all():
+            self._invalid[moved] += (~v).sum(axis=(1, 2))[moved]
+        return nbytes + self._mirror(slice(None), cols.start, cols.stop)
 
     def assemble(self) -> FleetSnapshot:
-        """Stage every host's trailing window into the (hosts, C, T) slab.
+        """Stage every host's trailing window into the mirrored frame.
 
         Safe against concurrent background writers: each host row is a
         seqlock-validated consistent snapshot.  Returns the snapshot whose
-        ``slab`` IS the internal staging buffer — consume it before the
-        next ``assemble`` call.
+        ``slab``, ``ts`` and ``valid_mask`` are (hosts, C, T) views of
+        the internal frame — consume them before the next ``assemble``
+        call.
         """
         H, T = len(self.agents), self.window_n
         with span("aggregator.assemble"):
@@ -294,7 +340,8 @@ class FleetAggregator:
                     seqs[h], counts[h], lasts[h] = a.ring.watermark()
             have = counts >= max(self.min_samples, 1)
             if not have.any():
-                snap = FleetSnapshot(ts=np.zeros(0), slab=self._slab[:0],
+                snap = FleetSnapshot(ts=np.zeros(0),
+                                     slab=self._slab[:0, :, :T],
                                      valid=np.zeros(H, np.int64),
                                      skipped=list(range(H)), retries=0)
                 self.last_snapshot = snap
@@ -302,40 +349,54 @@ class FleetAggregator:
             t_latest = float(lasts[have].max())
             alive = have & (lasts >= t_latest - self.dead_after_s)
             t_common = float(lasts[alive].min())
+            tick = int(round(t_common * self.rate_hz))
+            s = (tick - T + 1) % T
 
-            # phase 2: one bounded copy per live host, right-aligned at
-            # t_common
+            # phase 2: every live host's window, right-aligned at
+            # t_common, into the frame columns [s, s + T)
             st = self.stats
             n0 = (st.delta_reads, st.full_restages, st.unchanged_skips)
             with span("assemble.copy") as sp:
                 valid, skipped, ref_host, retries, nbytes = self._stage_rows(
-                    alive, have, counts, lasts, seqs, t_common, period)
+                    alive, have, counts, lasts, seqs, t_common, period, s,
+                    tick - self._frame_tick)
                 delta, full, unchanged = (
                     st.delta_reads - n0[0], st.full_restages - n0[1],
                     st.unchanged_skips - n0[2])
                 sp.set_metadata(delta_reads=delta, full_restages=full,
                                 unchanged=unchanged, bytes=nbytes)
+            self._frame_tick = tick
             st.staged_bytes += nbytes
             st.assemblies += 1
             st.torn_retries += retries
             st.torn_giveups += (
                 sum(a.ring.torn_giveups for a in self.agents) - giveups0)
-            snap = FleetSnapshot(ts=self._ts_rows[ref_host], slab=self._slab,
+            snap = FleetSnapshot(ts=self._ts_rows[ref_host, s:s + T],
+                                 slab=self._slab[:, :, s:s + T],
                                  valid=valid, skipped=skipped,
-                                 retries=retries, valid_mask=self._valid)
+                                 retries=retries,
+                                 valid_mask=self._valid[:, :, s:s + T],
+                                 ts_rows=self._ts_rows[:, s:s + T])
             self.last_snapshot = snap
             return snap
 
     def _stage_rows(self, alive: np.ndarray, have: np.ndarray,
                     counts: np.ndarray, lasts: np.ndarray, seqs: np.ndarray,
-                    t_common: float, period: float) -> tuple:
-        """Phase 2 of :meth:`assemble`: one bounded copy per live host,
-        right-aligned at ``t_common``.  Returns ``(valid, skipped,
-        ref_host, retries, nbytes)``, ``nbytes`` the bytes written into
-        the staging buffers."""
+                    t_common: float, period: float, s: int,
+                    adv: int) -> tuple:
+        """Phase 2 of :meth:`assemble`: every live host's window,
+        right-aligned at ``t_common``, into the frame columns ``[s, s +
+        T)``; ``adv`` is the frame's advance in ticks since the last
+        assembly.  Returns ``(valid, skipped, ref_host, retries,
+        nbytes)``, ``nbytes`` the bytes written into the staging
+        buffers."""
         H, T = len(self.agents), self.window_n
         retries = nbytes = 0
         valid = np.zeros(H, np.int64)
+        moved = np.zeros(H, bool)
+        # a delta read's new ticks: the last ``adv`` columns of the window
+        new_cols = slice(s + T - adv, s + T) if 0 < adv < T else None
+        win = slice(s, s + T)
         skipped: List[int] = []
         ref_host = -1
         for h, a in enumerate(self.agents):
@@ -347,6 +408,7 @@ class FleetAggregator:
                 self._valid[h] = True
                 nbytes += (self._slab[h].nbytes + self._ts_rows[h].nbytes
                            + self._valid[h].nbytes)
+                self._invalid[h] = 0
                 self._staged_full[h] = False
                 skipped.append(h)
                 self.stats.dead_hosts += int(have[h])
@@ -354,12 +416,12 @@ class FleetAggregator:
             skip = max(0, int(round((lasts[h] - t_common) / period)))
             # O(delta) staging first: a row whose previous stage was a
             # full clean window is reused untouched (seqlock sequence
-            # unchanged) or left-shifted + topped up with only the new
-            # ticks — byte-identical to the full restage it replaces,
-            # falling back to it on any raggedness, race, or gap
-            staged, r0, b0 = self._stage_delta(h, a, skip, int(counts[h]),
-                                               int(seqs[h]), t_common,
-                                               period)
+            # unchanged) or topped up with only the new ticks at the
+            # frame's edge — byte-identical to the full restage it
+            # replaces, falling back to it on any raggedness, race, or gap
+            staged, moved[h], r0, b0 = self._stage_delta(
+                h, a, skip, int(counts[h]), int(seqs[h]), t_common, period,
+                adv, new_cols)
             retries += r0
             nbytes += b0
             if staged:
@@ -367,12 +429,13 @@ class FleetAggregator:
                 if ref_host < 0 or T > valid[ref_host]:
                     ref_host = h
                 continue
-            # full-window hosts (the steady state) stage straight into
-            # their slab row — ONE bounded copy out of the ring; the
+            # full-window hosts stage straight into their frame window —
+            # ONE bounded copy out of the ring, then its mirror; the
             # scratch detour only happens for ragged/trimmed rows
+            row, ts_row = self._slab[h, :, win], self._ts_rows[h, win]
             direct = counts[h] - skip >= T
-            out_ts = self._ts_rows[h] if direct else self._ts_scratch
-            out_d = self._slab[h] if direct else self._scratch
+            out_ts = ts_row if direct else self._ts_scratch
+            out_d = row if direct else self._scratch
             ts_h, d_h, r = a.ring.read_window(T, out_ts=out_ts, out=out_d,
                                               skip_newest=skip)
             retries += r
@@ -385,44 +448,51 @@ class FleetAggregator:
             ts_h, d_h = ts_h[:k], d_h[:, :k]
             if k < self.min_samples:
                 self._slab[h] = 0.0
+                self._ts_rows[h] = 0.0
                 self._valid[h] = True
-                nbytes += self._slab[h].nbytes + self._valid[h].nbytes
+                nbytes += (self._slab[h].nbytes + self._ts_rows[h].nbytes
+                           + self._valid[h].nbytes)
+                self._invalid[h] = 0
                 self._staged_full[h] = False
                 skipped.append(h)
                 continue
-            row = self._slab[h]
             if not (direct and k == T):
                 if direct:
-                    # short/trimmed read landed left-aligned in the slab
-                    # row itself: move it through scratch to right-align
+                    # short/trimmed read landed left-aligned in the frame
+                    # window itself: move it through scratch to right-align
                     nbytes += (_write(self._scratch[:, :k], d_h)
                                + _write(self._ts_scratch[:k], ts_h))
                     d_h = self._scratch[:, :k]
                     ts_h = self._ts_scratch[:k]
                 nbytes += (_write(row[:, T - k:], d_h)
-                           + _write(self._ts_rows[h, T - k:], ts_h))
+                           + _write(ts_row[T - k:], ts_h))
             if k < T:
                 # late joiner: backfill the missing head with its oldest
                 # sample — a flat stretch that reads as a quiet baseline
                 nbytes += (_write(row[:, :T - k], d_h[:, :1])
-                           + _write(self._ts_rows[h, :T - k],
+                           + _write(ts_row[:T - k],
                                     ts_h[0] - period
                                     * np.arange(T - k, 0, -1)))
                 self.stats.ragged_hosts += 1
             valid[h] = k
             # per-cell validity: the agent marks failed/backoff-skipped
             # collectors' channels NaN, so finiteness IS the delivery mask
-            nbytes += np.isfinite(row, out=self._valid[h]).nbytes
+            vrow = self._valid[h, :, win]
+            nbytes += np.isfinite(row, out=vrow).nbytes
+            self._invalid[h] = vrow.size - np.count_nonzero(vrow)
+            nbytes += self._mirror(h, s, s + T)
             # only a full clean direct window seeds the next round's
             # delta path — trimmed/backfilled rows must restage
             full = bool(direct and k == T)
             self._staged_full[h] = full
             if full:
                 self._staged_seq[h] = int(seqs[h])
-                self._staged_last[h] = float(self._ts_rows[h, -1])
+                self._staged_last[h] = float(ts_row[-1])
             self.stats.full_restages += 1
             if ref_host < 0 or k > valid[ref_host]:
                 ref_host = h
+        if moved.any():
+            nbytes += self._stage_new_columns(new_cols, moved)
         return valid, skipped, ref_host, retries, nbytes
 
     # ------------------------------------------------------------- sharding
@@ -461,7 +531,12 @@ class FleetAggregator:
         restarting agent can neither narrow every established host's
         baseline nor collapse the span into ``diagnose_fleet``'s
         short-baseline quiet verdict (which would wipe a real straggler's
-        strike history fleet-wide while the newcomer refills)."""
+        strike history fleet-wide while the newcomer refills).
+
+        The per-cell validity mask goes to the monitor only while some
+        staged window holds an invalid cell (the stager counts them);
+        otherwise ``valid=None``, which ``diagnose_fleet`` treats exactly
+        like an all-true mask, without scanning one."""
         with span("aggregator.diagnose", hosts=len(self.agents)) as sp:
             # agent-restart wiring: a host whose probe was restarted/replaced
             # since the last round gets its monitor-side strike/quarantine
@@ -477,9 +552,12 @@ class FleetAggregator:
             if k < max(int(min_valid_s * self.rate_hz), 1):
                 return None
             for h in np.flatnonzero((snap.valid > 0) & (snap.valid < k)):
-                snap.slab[h] = 0.0  # cannot fill the span: quiet this round
-                if snap.valid_mask is not None:
-                    snap.valid_mask[h] = True   # zeros are deliberate quiet
+                # cannot fill the span: quiet this round, over the whole
+                # frame row so its mirror stays whole; zeros are
+                # deliberate quiet, so the row is all valid
+                self._slab[h] = 0.0
+                self._valid[h] = True
+                self._invalid[h] = 0
                 snap.masked.append(int(h))
                 # the staged row was just overwritten in place — it can no
                 # longer seed a delta read; force a full restage next round
@@ -487,7 +565,9 @@ class FleetAggregator:
             self.stats.masked_hosts += len(snap.masked)
             sp.set_metadata(masked=len(snap.masked))
             T = self.window_n
-            vm = snap.valid_mask
+            # the invalid-cell counts say whether the mask has a False
+            # cell: without one the monitor's clean path needs no mask
+            vm = snap.valid_mask if self._invalid.any() else None
             if k < T:
                 return monitor.diagnose_fleet(
                     snap.ts[T - k:], snap.slab[:, :, T - k:], self.channels,

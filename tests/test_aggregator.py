@@ -207,7 +207,7 @@ def test_live_background_agents_stage_aligned_and_consistent():
             snap = agg.assemble()
             live = [h for h in range(3) if h not in snap.skipped]
             assert live, "all hosts skipped under live sampling"
-            ends = [agg._ts_rows[h, -1] for h in live]
+            ends = [snap.ts_rows[h, -1] for h in live]
             # a tight bound is impossible under wall-clock sampling (a
             # GIL stall right before the common edge legitimately lags
             # one host by the stall length) — the exact-alignment
@@ -274,9 +274,11 @@ def test_delta_restage_bitwise_equals_full_restage():
 
 def test_staged_bytes_counts_each_write():
     """``staged_bytes`` sums the bytes each staging write moves: a full
-    restage and a delta read (left-shift plus new ticks) each rewrite one
-    whole row of slab, timestamps and validity; an unchanged row moves
-    nothing; a late joiner's right-align and backfill move more."""
+    restage writes one whole row of slab, timestamps and validity and
+    then its mirror; a delta read writes only its new ticks, their
+    validity and the mirror of both, ``2 * (C * 5 + 8)`` bytes a tick;
+    an unchanged row moves nothing; a late joiner's right-align and
+    backfill move more."""
     _, agents = _fleet(3, bad_host=0, history_s=30.0)
     agg = FleetAggregator(agents, window_s=20.0)
     C, T = len(agg.channels), agg.window_n
@@ -284,21 +286,22 @@ def test_staged_bytes_counts_each_write():
     agg.run_virtual(0.0, 25.0)
     agg.assemble()
     assert agg.stats.full_restages == 3
-    assert agg.stats.staged_bytes == 3 * row
+    assert agg.stats.staged_bytes == 3 * 2 * row
     agg.run_virtual(25.0, 25.5)
     agg.assemble()
     assert agg.stats.delta_reads == 3
-    assert agg.stats.staged_bytes == 6 * row
+    delta = 3 * 2 * (C * 5 + 8) * 50
+    assert agg.stats.staged_bytes == 3 * 2 * row + delta
     agg.assemble()                      # nothing pushed: rows reused
     assert agg.stats.unchanged_skips == 3
-    assert agg.stats.staged_bytes == 6 * row
+    assert agg.stats.staged_bytes == 3 * 2 * row + delta
 
     _, young = _fleet(2, bad_host=0, history_s=30.0)
     agg = FleetAggregator(young, window_s=20.0)
     agg.run_virtual(0.0, 10.0)          # half a window: backfilled rows
     agg.assemble()
     assert agg.stats.ragged_hosts == 2
-    assert agg.stats.staged_bytes > 2 * row
+    assert agg.stats.staged_bytes > 2 * 2 * row
 
 
 def test_restart_agent_voids_staged_row():
@@ -318,3 +321,150 @@ def test_restart_agent_voids_staged_row():
     snap = agg.assemble()
     assert agg.stats.full_restages > fr
     assert snap.slab.shape[0] == 3
+
+
+# ------------------------------------------------------ mirrored frame
+
+def _assert_frame_mirrored(agg):
+    """Every frame column holds the same tick as its twin ``c + T``."""
+    T = agg.window_n
+    for x in (agg._slab, agg._ts_rows, agg._valid):
+        np.testing.assert_array_equal(x[..., :T], x[..., T:])
+
+
+def _assert_same_snapshot(sa, sb):
+    np.testing.assert_array_equal(sa.slab, sb.slab)
+    np.testing.assert_array_equal(sa.ts, sb.ts)
+    np.testing.assert_array_equal(sa.ts_rows, sb.ts_rows)
+    np.testing.assert_array_equal(sa.valid_mask, sb.valid_mask)
+    assert list(sa.valid) == list(sb.valid)
+    assert sa.skipped == sb.skipped and sa.masked == sb.masked
+
+
+def _assert_same_diagnosis(fa, fb):
+    assert (fa is None) == (fb is None)
+    if fa is not None:
+        assert fa.flagged_hosts == fb.flagged_hosts
+        assert fa.straggler_host == fb.straggler_host
+        assert fa.quarantined == fb.quarantined
+        np.testing.assert_array_equal(fa.per_host_scores,
+                                      fb.per_host_scores)
+
+
+def test_frame_parity_across_wraps():
+    """1-, 7-, 50- and 0-tick advances on a 2 s window until the frame
+    offset has wrapped past T several times, with a dead row that comes
+    back, a late joiner (ragged, then masked by ``diagnose``) and a
+    restarted agent: after every round the frame aggregator's snapshot
+    views and diagnosis equal those of an aggregator forced through full
+    restages over the same rings, and every column equals its twin."""
+    _, agents = _fleet(5, bad_host=1, history_s=4.0)
+    a = FleetAggregator(agents, window_s=2.0, dead_after_s=0.2)
+    b = FleetAggregator(agents, window_s=2.0, dead_after_s=0.2)
+    mon_a, mon_b = (FleetMonitor(use_kernels=False) for _ in range(2))
+    T, dead, late = a.window_n, 3, 4
+    last = np.full(len(agents), 3.0)
+    for h, ag in enumerate(agents):
+        if h != late:
+            ag.run_virtual(0.0, 3.0)
+    t, wraps = 300, 0
+    for r, step in enumerate([1, 7, 50, 0] * 12):
+        t += step
+        wraps += (t % T) < ((t - step) % T)
+        if r == 30:
+            a.restart_agent(1)
+            b.restart_agent(1)
+        for h, ag in enumerate(agents):
+            if (h == dead and 5 <= r < 20) or (h == late and r < 10):
+                continue
+            if h == late and r == 10:
+                last[h] = (t - 60) / 100.0      # joins with 0.6 s of data
+            ag.run_virtual(last[h], t / 100.0)
+            last[h] = t / 100.0
+        _force_full(b)
+        fa, fb = a.diagnose(mon_a), b.diagnose(mon_b)
+        _assert_same_snapshot(a.last_snapshot, b.last_snapshot)
+        _assert_same_diagnosis(fa, fb)
+        _assert_frame_mirrored(a)
+        _assert_frame_mirrored(b)
+        np.testing.assert_array_equal(
+            a._invalid, (~a.last_snapshot.valid_mask).sum(axis=(1, 2)))
+    assert wraps >= 2
+    assert a.stats.delta_reads > 0 and a.stats.unchanged_skips > 0
+    assert a.stats.dead_hosts > 0 and a.stats.ragged_hosts > 0
+    assert a.stats.masked_hosts > 0 and a.stats.host_resets == 1
+    assert b.stats.delta_reads == 0
+
+
+def test_invalid_cells_counted_mask_only_while_in_window():
+    """One collector failure writes NaN ticks into one host's ring: while
+    they sit inside the staged window the monitor receives the validity
+    mask, once they have left it receives ``valid=None``; every round's
+    diagnosis equals a monitor fed the mask explicitly and a full-restage
+    aggregator over the same rings."""
+    from repro.sim.chaos import ChaosCollector, ChaosEvent, ChaosPolicy
+    trials = [make_trial(800 + h, "nic", intensity=0.0, t_on=40.0,
+                         confuser_prob=0.0) for h in range(3)]
+    sims = [SimCollector(t.channels, t.ts, t.data) for t in trials]
+    sims[0] = ChaosCollector(sims[0], ChaosPolicy(
+        (ChaosEvent("exception", 3.5, 0.005),)))
+    agents = [TelemetryAgent([c], rate_hz=100.0, history_s=4.0)
+              for c in sims]
+    a = FleetAggregator(agents, window_s=2.0)
+    b = FleetAggregator(agents, window_s=2.0)
+    mon_a, mon_b, mon_ref = (FleetMonitor(use_kernels=False)
+                             for _ in range(3))
+    passed = []
+    real = mon_a.diagnose_fleet
+
+    def spy(ts, slab, channels, valid=None, **kw):
+        passed.append(valid is not None)
+        return real(ts, slab, channels, valid=valid, **kw)
+    mon_a.diagnose_fleet = spy
+    a.run_virtual(0.0, 3.0)
+    seen = []
+    for r in range(24):
+        t = 3.0 + 0.25 * r
+        a.run_virtual(t, t + 0.25)
+        _force_full(b)
+        fa, fb = a.diagnose(mon_a), b.diagnose(mon_b)
+        snap = a.last_snapshot
+        holes = bool(np.isnan(snap.slab).any())
+        assert passed[-1] == holes
+        assert a._invalid[0] == (~snap.valid_mask[0]).sum()
+        assert not a._invalid[1:].any()
+        seen.append(holes)
+        ref = mon_ref.diagnose_fleet(snap.ts, snap.slab, a.channels,
+                                     valid=snap.valid_mask)
+        _assert_same_diagnosis(fa, ref)
+        _assert_same_diagnosis(fa, fb)
+        _assert_same_snapshot(snap, b.last_snapshot)
+    # clean, then holes entering and leaving through delta reads, clean
+    assert seen[0] is False and True in seen and seen[-1] is False
+    assert a.stats.delta_reads >= 20 * 3
+
+
+def test_row_shift_other_than_frame_advance_restages_fully():
+    """Timestamps a little either side of the half tick (a wall clock's
+    jitter): the rows' own shift, 50 ticks, passes the delta read's
+    quarter-period tolerance while the frame advances 51 columns.  Such
+    rows must take the full restage; topping them up at the frame's edge
+    would leave a stale column in the window."""
+    _, agents = _fleet(2, bad_host=0, history_s=4.0)
+    a = FleetAggregator(agents, window_s=2.0)
+    b = FleetAggregator(agents, window_s=2.0)
+    C = len(a.channels)
+    rng = np.random.default_rng(5)
+
+    def push(j0, n, frac):
+        ts = (np.arange(j0, j0 + n) + frac) / 100.0
+        for ag in agents:
+            ag.ring.push_block(ts, rng.normal(size=(C, n)).astype(np.float32))
+    push(0, 300, 0.45)
+    a.assemble()
+    push(300, 50, 0.55)
+    _force_full(b)
+    sa, sb = a.assemble(), b.assemble()
+    _assert_same_snapshot(sa, sb)
+    _assert_frame_mirrored(a)
+    assert a.stats.delta_reads == 0 and a.stats.full_restages == 4
