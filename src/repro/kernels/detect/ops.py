@@ -26,20 +26,54 @@ from repro.core.spans import span
 from repro.kernels.sweep import ops as sweep_ops
 from repro.kernels.sweep.ops import persistence_count  # re-export (tests/API)
 
-__all__ = ["detect_hosts", "detect_hosts_slab", "persistence_count"]
+__all__ = ["detect_hosts", "detect_hosts_slab", "detect_hosts_slab_launch",
+           "persistence_count"]
 
 
-def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
-                 patch_base: np.ndarray, wn: int, bn: int,
-                 threshold: float, persistence: float,
-                 use_kernel: bool, exact: bool,
-                 device=None, moments=None,
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Single-tick sweep over the (H, bn + wn) trailing slab.
+class DetectPending:
+    """A fleet detect whose sweep is in flight: :meth:`collect` pulls it
+    and re-decides the marginal rows through the f64 oracle."""
+
+    __slots__ = ("_sweep", "_patch_win", "_patch_base", "_threshold",
+                 "_persistence", "_exact")
+
+    def __init__(self, sweep, patch_win, patch_base, threshold: float,
+                 persistence: float, exact: bool) -> None:
+        self._sweep = sweep
+        self._patch_win, self._patch_base = patch_win, patch_base
+        self._threshold, self._persistence = threshold, persistence
+        self._exact = exact
+
+    def collect(self, in_flight: int = 1,
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(fire, score, onset)`` per host; ``in_flight`` as in
+        :meth:`repro.kernels.sweep.ops.SweepPending.collect`."""
+        fire, score, onset, marg = (
+            x[:, 0] for x in self._sweep.collect(in_flight))
+        if self._exact and marg.any():
+            # guard band hit: re-decide those hosts through the f64 oracle
+            # so the fast path cannot split from detect_rows at the
+            # threshold
+            rows = np.flatnonzero(marg)
+            with span("detect.redecide", rows=rows.size):
+                f2, s2, o2 = spike_mod.detect_rows(
+                    np.asarray(self._patch_win[rows], np.float64),
+                    np.asarray(self._patch_base[rows], np.float64),
+                    self._threshold, self._persistence)
+                fire[rows], score[rows], onset[rows] = f2, s2, o2
+        return fire.astype(bool), score, onset.astype(np.intp)
+
+
+def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
+                        patch_base: np.ndarray, wn: int, bn: int,
+                        threshold: float, persistence: float,
+                        use_kernel: bool, exact: bool,
+                        device=None, moments=None) -> DetectPending:
+    """Single-tick sweep over the (H, bn + wn) trailing slab, launched.
 
     ``patch_win``/``patch_base`` are the caller's original (H, Nw)/(H, Nb)
     arrays, any dtype — only epsilon-marginal rows are ever upcast from
-    them for the exact ``detect_rows`` re-decision.
+    them for the exact ``detect_rows`` re-decision in the collect.
 
     ``moments`` (mu, sd) — each (H,) f64, sd already sigma-floored —
     skips the O(H * bn) direct moment pass (the incremental streaming
@@ -71,23 +105,12 @@ def _detect_tail(tail32: np.ndarray, patch_win: np.ndarray,
     else:
         disp, bn_d = tail32, bn
         ticks = np.array([T], np.int64)
-    fire, score, onset, marg = sweep_ops.sweep_rows(
+    sweep = sweep_ops.sweep_launch(
         disp, wn, bn_d, ticks, threshold, persistence,
         moments=(mu[:, None], sd[:, None]), argmax_fallback=True,
         use_kernel=use_kernel, device=device)
-    fire, score, onset, marg = (fire[:, 0], score[:, 0], onset[:, 0],
-                                marg[:, 0])
-    if exact and marg.any():
-        # guard band hit: re-decide those hosts through the f64 oracle so
-        # the fast path cannot split from detect_rows at the threshold
-        rows = np.flatnonzero(marg)
-        with span("detect.redecide", rows=rows.size):
-            f2, s2, o2 = spike_mod.detect_rows(
-                np.asarray(patch_win[rows], np.float64),
-                np.asarray(patch_base[rows], np.float64),
-                threshold, persistence)
-            fire[rows], score[rows], onset[rows] = f2, s2, o2
-    return fire, score, onset
+    return DetectPending(sweep, patch_win, patch_base, threshold,
+                         persistence, exact)
 
 
 def detect_hosts(windows, baselines, threshold: float = 3.0,
@@ -113,10 +136,9 @@ def detect_hosts(windows, baselines, threshold: float = 3.0,
     wn, bn = windows.shape[1], baselines.shape[1]
     tail32 = np.concatenate([np.asarray(baselines, np.float32),
                              np.asarray(windows, np.float32)], axis=1)
-    fire, score, onset = _detect_tail(
+    return _detect_tail_launch(
         tail32, windows, baselines, wn, bn, float(threshold),
-        float(persistence), bool(use_kernel), bool(exact))
-    return fire.astype(bool), score, onset.astype(np.intp)
+        float(persistence), bool(use_kernel), bool(exact)).collect()
 
 
 def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
@@ -126,7 +148,23 @@ def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
                       force_oracle: bool = False, device=None,
                       moments=None,
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """:func:`detect_hosts` over a trailing latency slab.
+    """:func:`detect_hosts_slab_launch` and its ``collect()`` back to
+    back: ``(fire, score, onset)`` per host."""
+    return detect_hosts_slab_launch(
+        tail, wn, bn, threshold, persistence, use_kernel, exact, valid,
+        force_oracle, device, moments).collect()
+
+
+def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
+                             persistence: float = 0.0,
+                             use_kernel: bool = True, exact: bool = True,
+                             valid: Optional[np.ndarray] = None,
+                             force_oracle: bool = False, device=None,
+                             moments=None):
+    """:func:`detect_hosts` over a trailing latency slab, launched: the
+    fast path returns a :class:`DetectPending` whose ``collect()`` gives
+    ``(fire, score, onset)`` per host; the oracle path returns its
+    result already resolved.
 
     ``tail`` is the (H, bn + wn) slab — baseline columns then window
     columns, exactly the layout of a trailing ring snapshot — staged as
@@ -178,15 +216,15 @@ def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
         fire, score, onset = spike_mod.detect_rows_masked(
             t64[:, bn:], t64[:, :bn], v[:, bn:], v[:, :bn],
             float(threshold), float(persistence))
-        return fire.astype(bool), score, onset.astype(np.intp)
+        return sweep_ops.Resolved(
+            (fire.astype(bool), score, onset.astype(np.intp)))
     with span("detect.stage") as sp:
         tail32 = np.ascontiguousarray(tail, np.float32)
         sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
     # the exact re-decision must see the caller's values, not the f32
     # staging — only a genuinely-f32 tail may reuse the staged copy
     patch = tail32 if tail.dtype == np.float32 else tail
-    fire, score, onset = _detect_tail(
+    return _detect_tail_launch(
         tail32, patch[:, bn:], patch[:, :bn], int(wn), int(bn),
         float(threshold), float(persistence), bool(use_kernel),
         bool(exact), device=device, moments=moments)
-    return fire.astype(bool), score, onset.astype(np.intp)

@@ -265,6 +265,61 @@ def sweep_rows_exact(lat, wn: int, bn: int, ticks: np.ndarray,
     return fire, score, onset
 
 
+class Resolved:
+    """A launch whose result is already on the host (nothing was
+    dispatched): :meth:`collect` hands it back."""
+
+    __slots__ = ("_value",)
+
+    def __init__(self, value) -> None:
+        self._value = value
+
+    def collect(self, in_flight: int = 1):
+        return self._value
+
+
+class SweepPending:
+    """A sweep dispatched to its device and not yet pulled: the four
+    result arrays on the device, the chip they live on, and what the
+    host-side validity gate needs."""
+
+    __slots__ = ("_out", "chip", "_gate")
+
+    def __init__(self, out, chip: int, gate) -> None:
+        self._out, self.chip, self._gate = out, chip, gate
+
+    def collect(self, in_flight: int = 1,
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """Pull ``(fire, score, onset, marginal)`` (blocking on the
+        dispatch) and apply the validity gate.  ``in_flight`` is how many
+        launched sweeps were still unpulled when this pull began,
+        recorded on the ``sweep.pull`` span."""
+        with span("detect.sweep", chip=self.chip), \
+                span("sweep.pull", in_flight=in_flight, chip=self.chip):
+            fire, score, onset, marg = self._out
+            fire = np.asarray(fire).astype(bool)
+            score = np.array(score, np.float64)
+            onset = np.asarray(onset).astype(np.intp)
+            marg = np.asarray(marg).astype(bool)
+        self._out = None
+        if self._gate is not None:
+            # host-side validity gate: a baseline you cannot estimate (or a
+            # window with zero valid cells) may never fire, whatever the
+            # staged sentinel z came out to
+            vmask, bcnt, ticks, wn = self._gate
+            R = vmask.shape[0]
+            cv = np.concatenate([np.zeros((R, 1)),
+                                 np.cumsum(vmask, axis=1)], axis=1)
+            wcnt = cv[:, ticks] - cv[:, ticks - wn]
+            ok = wcnt > 0
+            if bcnt is not None:
+                ok &= bcnt >= spike_mod.MIN_VALID_BASELINE_N
+            fire &= ok
+            score = np.where(ok, score, 0.0)
+            onset = np.where(ok, onset, -1)
+        return fire, score, onset, marg
+
+
 def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                threshold: float = 3.0, persistence: float = 0.0,
                valid_n: Optional[np.ndarray] = None,
@@ -275,7 +330,8 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                valid: Optional[np.ndarray] = None,
                device=None,
                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Batched :func:`repro.core.spike.detect_sweep` over a latency slab.
+    """Batched :func:`repro.core.spike.detect_sweep` over a latency slab:
+    :func:`sweep_launch` and its ``collect()`` back to back.
 
     ``lat`` (rows, T) — any dtype, staged to f32 for the dispatch; every
     row is evaluated at the shared ``ticks`` (each in ``[wn + bn, T]``)
@@ -318,6 +374,28 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
     — moments are exact f64 host-side and marginal ticks re-decide
     through the f64 oracle regardless of where the f32 sweep ran.
     """
+    return sweep_launch(lat, wn, bn, ticks, threshold, persistence,
+                        valid_n=valid_n, moments=moments,
+                        argmax_fallback=argmax_fallback, eps=eps,
+                        use_kernel=use_kernel, block_t=block_t,
+                        valid=valid, device=device).collect()
+
+
+def sweep_launch(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
+                 threshold: float = 3.0, persistence: float = 0.0,
+                 valid_n: Optional[np.ndarray] = None,
+                 moments: Optional[Tuple[np.ndarray, np.ndarray]] = None,
+                 argmax_fallback: bool = False,
+                 eps: float = SWEEP_GUARD_EPS, use_kernel: bool = False,
+                 block_t: Optional[int] = None,
+                 valid: Optional[np.ndarray] = None,
+                 device=None):
+    """The first half of :func:`sweep_rows` (same arguments): f32
+    staging, the host->device put, the asynchronous dispatch and the
+    start of the results' copies back to the host.  Returns
+    a :class:`SweepPending` whose ``collect()`` pulls the results, so the
+    host can prepare and launch other sweeps — on other devices — while
+    this one runs."""
     lat = np.asarray(lat)
     if lat.ndim != 2:
         raise ValueError(f"lat must be (rows, T), got {lat.shape}")
@@ -327,8 +405,8 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
     nt = ticks.size
     if nt == 0:
         e = np.empty((R, 0))
-        return (e.astype(bool), e.astype(np.float64),
-                e.astype(np.intp), e.astype(bool))
+        return Resolved((e.astype(bool), e.astype(np.float64),
+                         e.astype(np.intp), e.astype(bool)))
     if ticks.min() < wn + bn or ticks.max() > T:
         raise ValueError(f"ticks must lie in [{wn + bn}, {T}]")
     if valid_n is None:
@@ -360,10 +438,10 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
     lat32 = np.ascontiguousarray(lat, np.float32)
     if vmask is not None:
         lat32 = np.where(vmask, lat32, np.float32(spike_mod.MASK_NEG))
-    # one span from the host->device put to the last pull; the put, the
-    # (asynchronous) dispatch and the four pulls are its children
+    # the launch half of the ``detect.sweep`` span: the put and the
+    # (asynchronous) dispatch; the collect opens it again around the pull
     h2d = lat32.nbytes + 4 * (np.size(mu) + np.size(sd) + nt + R)
-    with span("detect.sweep", rows=R, h2d_bytes=h2d), \
+    with span("detect.sweep", rows=R, h2d_bytes=h2d) as sp, \
             (contextlib.nullcontext() if device is None
              else jax.default_device(device)):
         with span("sweep.put"):
@@ -371,28 +449,18 @@ def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                     jnp.asarray(np.asarray(mu, np.float32)),
                     jnp.asarray(np.asarray(sd, np.float32)),
                     jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32))
+        chip = next(iter(args[0].devices())).id
+        sp.set_metadata(chip=chip)
         with span("sweep.dispatch"):
-            fire, score, onset, marg = _sweep_jit(
+            out = _sweep_jit(
                 *args, wn, float(threshold), int(min_hot), float(eps),
                 bool(argmax_fallback), bool(use_kernel),
                 bool(use_kernel) and interpret_mode(device),
                 tuning.sweep_block_t(block_t))
-        with span("sweep.pull"):
-            fire = np.asarray(fire).astype(bool)
-            score = np.array(score, np.float64)
-            onset = np.asarray(onset).astype(np.intp)
-            marg = np.asarray(marg).astype(bool)
-    if vmask is not None:
-        # host-side validity gate: a baseline you cannot estimate (or a
-        # window with zero valid cells) may never fire, whatever the
-        # staged sentinel z came out to
-        cv = np.concatenate([np.zeros((R, 1)), np.cumsum(vmask, axis=1)],
-                            axis=1)
-        wcnt = cv[:, ticks] - cv[:, ticks - wn]
-        ok = wcnt > 0
-        if bcnt is not None:
-            ok &= bcnt >= spike_mod.MIN_VALID_BASELINE_N
-        fire &= ok
-        score = np.where(ok, score, 0.0)
-        onset = np.where(ok, onset, -1)
-    return fire, score, onset, marg
+            # the four results start back to the host as soon as the
+            # kernel ends, side by side, whether or not the host is
+            # still busy launching other sweeps
+            for x in out:
+                x.copy_to_host_async()
+    return SweepPending(out, chip, None if vmask is None
+                        else (vmask, bcnt, ticks, wn))

@@ -58,6 +58,7 @@ from repro.core.taxonomy import CauseClass, Diagnosis, SpikeEvent
 from repro.kernels.detect import ops as detect_ops
 from repro.kernels.fused import ops as fused_ops
 from repro.kernels.spike import ops as spike_ops
+from repro.kernels.sweep.ops import Resolved
 from repro.kernels.xcorr import ops as xcorr_ops
 
 
@@ -492,9 +493,12 @@ class FleetMonitor:
                       base: int = 0,
                       quar: Optional[np.ndarray] = None,
                       tick_end: Optional[int] = None,
+                      launched=None, in_flight: int = 1,
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                  np.ndarray]:
-        """Layer-2 detection + telemetry quarantine over the latency tail.
+        """Layer-2 detection + telemetry quarantine over the latency tail
+        of ``host_data``: :meth:`_detect_launch`, then the half after the
+        sweep — collect, zero the quarantined hosts, flag.
 
         Returns ``(scores, cand, onset_rel, qhosts)``: per-host spike
         scores (quarantined hosts zeroed), the unordered flagged host
@@ -502,6 +506,36 @@ class FleetMonitor:
         hosts quarantined this round — all indexed relative to
         ``host_data`` (the sharded caller offsets them by its shard
         base).
+
+        ``launched`` is what :meth:`_detect_launch` returned for this
+        slab, when the caller launched it earlier (the sharded monitor
+        launches the next shards while this one's sweep runs); only the
+        second half then runs, and ``in_flight`` — launched, unfinished
+        detects when its pull begins — is recorded on the pull's span."""
+        if launched is None:
+            launched = self._detect_launch(
+                host_data, vfull, li, T, wn, bn, force_oracle=force_oracle,
+                device=device, base=base, quar=quar, tick_end=tick_end)
+        pending, qhosts = launched
+        fire, scores, onset_all = pending.collect(in_flight)
+        if qhosts.size:
+            fire[qhosts] = False
+            scores[qhosts] = 0.0
+        cand = np.flatnonzero(fire)
+        return scores, cand, onset_all[cand], qhosts
+
+    def _detect_launch(self, host_data: np.ndarray,
+                       vfull: Optional[np.ndarray], li: int,
+                       T: int, wn: int, bn: int,
+                       force_oracle: bool = False, device=None,
+                       base: int = 0,
+                       quar: Optional[np.ndarray] = None,
+                       tick_end: Optional[int] = None):
+        """The half of :meth:`_detect_round` before the sweep's results
+        come back: the quarantine update, the incremental moments and the
+        detect launch.  Returns ``(pending, qhosts)``, the ``launched``
+        argument of :meth:`_detect_round`; ``pending.collect()`` gives
+        per-host ``(fire, scores, onset)``.
 
         The shard parameters keep a per-shard invocation byte-identical
         to the corresponding rows of one full-slab call: ``base`` keys
@@ -562,31 +596,28 @@ class FleetMonitor:
                             rebuilt_rows=self._inc.last_round_rebuilt_rows)
                 else:
                     self._inc.invalidate(np.arange(base, base + hosts))
-            fire, scores, onset_all = detect_ops.detect_hosts_slab(
+            pending = detect_ops.detect_hosts_slab_launch(
                 lat[:, T - wn - bn:T], wn, bn,
                 self.cfg.threshold, self.cfg.persistence,
                 use_kernel=self.use_kernels, valid=lvt,
                 force_oracle=force_oracle, device=device, moments=moments)
-            if qhosts.size:
-                fire[qhosts] = False
-                scores[qhosts] = 0.0
-            cand = np.flatnonzero(fire)
-            onset_rel = onset_all[cand]
-        else:
-            scores = self.host_spike_scores(lat[:, T - wn:],
-                                            lat[:, T - wn - bn:T - wn])
-            if qhosts.size:
-                scores = np.array(scores)   # kernel output may be readonly
-                scores[qhosts] = 0.0
-            cand = np.flatnonzero(scores > self.cfg.threshold)
-            onset_rel = np.empty(0, dtype=np.intp)
-            if cand.size:
-                latc = np.asarray(lat[cand], dtype=np.float64)
-                keep, _, onset_rel = detect_rows(
-                    latc[:, T - wn:], latc[:, T - wn - bn:T - wn],
-                    self.cfg.threshold, self.cfg.persistence)
-                cand, onset_rel = cand[keep], onset_rel[keep]
-        return scores, cand, onset_rel, qhosts
+            return pending, qhosts
+        scores = self.host_spike_scores(lat[:, T - wn:],
+                                        lat[:, T - wn - bn:T - wn])
+        if qhosts.size:
+            scores = np.array(scores)   # kernel output may be readonly
+            scores[qhosts] = 0.0
+        cand = np.flatnonzero(scores > self.cfg.threshold)
+        fire = np.zeros(hosts, bool)
+        onset_all = np.full(hosts, -1, np.intp)
+        if cand.size:
+            latc = np.asarray(lat[cand], dtype=np.float64)
+            keep, _, onset = detect_rows(
+                latc[:, T - wn:], latc[:, T - wn - bn:T - wn],
+                self.cfg.threshold, self.cfg.persistence)
+            fire[cand[keep]] = True
+            onset_all[cand[keep]] = onset[keep]
+        return Resolved((fire, scores, onset_all)), qhosts
 
     def _rca_selection(self, flagged: np.ndarray, onset_rel: np.ndarray,
                        ) -> Tuple[np.ndarray, np.ndarray, List[int]]:

@@ -49,9 +49,10 @@ definition of "byte-exact".
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -400,6 +401,14 @@ class ShardedFleetMonitor(FleetMonitor):
                          any_invalid: Optional[bool]) -> FleetDiagnosis:
         """Shared sharded-round core (see class docstring for the tree).
 
+        Shards are launched in order — provider, validity, detect launch
+        — and finished oldest first — sweep pull, local RCA selection,
+        evidence gather — with at most one launched, unfinished shard per
+        distinct device in :attr:`devices`: on four chips four sweeps run
+        side by side while the host prepares the next shard, and on one
+        chip each launch is finished before the next (the serial order).
+        Placement and overlap never change a verdict.
+
         ``any_invalid`` None means "unknown until shards are visited"
         (provider mode, re-visit clean shards if corruption turns up);
         a bool means the caller inspected the full mask upfront."""
@@ -415,8 +424,13 @@ class ShardedFleetMonitor(FleetMonitor):
         geom = None
         dims: Optional[Tuple[int, int, int]] = None  # (C, T) + wn, bn
         tick_end: Optional[int] = None  # one grid anchor for every shard
+        # launched, unfinished shards, oldest first: at most one per chip
+        n_chips = len(set(self.devices))
+        pending: Deque[Tuple[int, np.ndarray, Optional[np.ndarray],
+                             object]] = collections.deque()
 
         def visit(s: int, force_oracle: bool) -> None:
+            """Launch half: provider, validity, detect launch."""
             nonlocal geom, dims, tick_end
             with span("shard.visit", shard=s, oracle=force_oracle):
                 a, b = plan.bounds[s]
@@ -454,15 +468,28 @@ class ShardedFleetMonitor(FleetMonitor):
                 # invalidates rather than advances them, so a shard visited
                 # twice in one round cannot double-advance the moment state
                 with stage_span(stage, "detect", "monitor.detect", rows=b - a):
-                    scores, cand, onset_rel, qloc = self._detect_round(
+                    launched = self._detect_launch(
                         slab, vfull, li, T, wn, bn,
                         force_oracle=force_oracle, device=self.devices[s],
                         base=a, quar=quar_saved[s], tick_end=tick_end)
                 if quar_saved[s] is None:
                     qmask = np.zeros(b - a, bool)
-                    qmask[qloc] = True
+                    qmask[launched[1]] = True
                     quar_saved[s] = qmask
                 ran_oracle[s] = force_oracle or saw_invalid[s]
+                pending.append((s, slab, vfull, launched))
+
+        def finish() -> None:
+            """Finish half of the oldest launched shard: collect, local
+            RCA selection, evidence gather."""
+            n = len(pending)
+            s, slab, vfull, launched = pending.popleft()
+            a, _ = plan.bounds[s]
+            with span("shard.finish", shard=s, chip=self.devices[s].id):
+                with stage_span(stage, "detect", "monitor.detect"):
+                    scores, cand, onset_rel, qloc = self._detect_round(
+                        slab, vfull, li, *dims, launched=launched,
+                        in_flight=n)
                 # local RCA selection mirrors the fleet's (same total order,
                 # same degraded/top-K policy) so every evidence block the
                 # fleet level will need is shipped — see _rca_selection
@@ -481,23 +508,32 @@ class ShardedFleetMonitor(FleetMonitor):
                     qhosts=qloc + a, evidence=evidence)
                 shard_scores[s] = scores
 
+        def launch(s: int, force_oracle: bool) -> None:
+            visit(s, force_oracle)
+            while len(pending) >= n_chips:
+                finish()
+
         force_all = bool(any_invalid)
         try:
-            visit(0, force_oracle=force_all)
+            launch(0, force_oracle=force_all)
         except _ShortBaseline:
             # same short-snapshot refusal as the single-slab path, decided
             # before any shard state advances
             self.last_traffic = ShardTraffic()
             return self._quiet_round(plan.hosts, extra_cost_s)
         for s in range(1, plan.n_shards):
-            visit(s, force_oracle=force_all)
+            launch(s, force_oracle=force_all)
+        while pending:
+            finish()
         if any_invalid is None and any(saw_invalid):
             # corruption surfaced after some shards took the fast path:
             # re-visit exactly those through the oracle so the round
             # matches what one full-slab masked call would have computed
             for s in range(plan.n_shards):
                 if not ran_oracle[s]:
-                    visit(s, force_oracle=True)
+                    launch(s, force_oracle=True)
+            while pending:
+                finish()
 
         # rack-level reduce: merge member candidate lists, prune evidence
         # to the rack's own RCA selection

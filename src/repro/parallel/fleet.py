@@ -54,7 +54,8 @@ def shard_devices(n_shards: int,
     Returns a list of length ``n_shards``: shard ``i`` dispatches on
     ``devices[i % len(devices)]``.  Deterministic (no load balancing) so
     a round's placement — and therefore its performance profile — is
-    reproducible run to run.
+    reproducible run to run.  The sharded round keeps one shard in
+    flight per distinct device of this list.
     """
     devs = list(devices) if devices is not None else monitor_devices()
     if not devs:

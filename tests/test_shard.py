@@ -2,6 +2,12 @@
 (flagged order, scores, causes, quarantine, degraded/deferred fields),
 shard edge cases (ragged shards, dead shards, late joiners), provider
 re-visit semantics, traffic bounds, and shard-aware checkpointing."""
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -414,3 +420,140 @@ def test_incremental_sharded_provider_revisit_invalidates():
     shard.diagnose_sharded(ts, provider_clean, channels)
     assert shard._inc.rounds == after + shard.plan.n_shards
     assert shard._inc.parity == 1.0
+
+
+# ------------------------------------------------ placement over chips
+
+_PLACEMENT = textwrap.dedent("""
+    import json, os, sys, types
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path[:0] = {paths!r}
+    import jax
+    import numpy as np
+    from benchmarks.fleetbench import _make_fleet
+    from repro.kernels.sweep import ops as sweep_ops
+    from repro.monitor import (
+        FleetMonitor, ShardPlan, ShardedFleetMonitor, verdict_fingerprint)
+
+    HOSTS, SHARD, W, STEP = 256, 32, 3000, 50
+    ts, data, channels = _make_fleet(HOSTS, bad_host=5, seed=11, bad_every=6)
+    li = channels.index("coll_allreduce_ms")
+    ends = [4350 + STEP * k for k in range(6)]
+    CORRUPT, OVER = 5, 3        # late corruption; over budget -> degraded
+    events = []                 # ("P", shard) / ("L", chips) / ("F", n)
+    real = sweep_ops.sweep_launch
+
+    def spy(*a, **k):
+        p = real(*a, **k)
+        events.append(("L", sorted({{d.id for x in p._out
+                                    for d in x.devices()}})))
+
+        def collect(in_flight=1):
+            events.append(("F", in_flight))
+            return p.collect(in_flight)
+        return types.SimpleNamespace(collect=collect)
+    sweep_ops.sweep_launch = spy
+
+    def valid_for(k):
+        if k != CORRUPT:
+            return None
+        v = np.ones((HOSTS, len(channels), W), bool)
+        v[250, li, -100:] = False          # last shard, below quarantine
+        return v
+
+    def run(mon):
+        out, log = [], []
+        for k, e in enumerate(ends):
+            t, d, v = ts[e - W:e], data[:, :, e - W:e], valid_for(k)
+            cost = 100.0 if k == OVER else 0.0
+            if mon is None:
+                fd = single.diagnose_fleet(t, d, channels, valid=v,
+                                           extra_cost_s=cost)
+            else:
+                del events[:]
+
+                def provider(s):
+                    events.append(("P", s))
+                    a, b = mon.plan.bounds[s]
+                    return d[a:b], None if v is None else v[a:b]
+                fd = mon.diagnose_sharded(t, provider, channels,
+                                          extra_cost_s=cost)
+                log.append(list(events))
+            out.append(json.dumps(verdict_fingerprint(fd), sort_keys=True))
+            out[-1] = [out[-1], bool(fd.degraded), fd.flagged_hosts]
+        return out, log
+
+    kw = dict(use_kernels=False, rca_top_k=4, budget_s=30.0, shed_after=1)
+    plan = ShardPlan.for_fleet(HOSTS, shard_hosts=SHARD, rack_shards=4)
+    single = FleetMonitor(**kw)
+    res = {{"single": run(None)[0]}}
+    for n in (1, 4):
+        mon = ShardedFleetMonitor(plan, devices=jax.devices()[:n], **kw)
+        fps, log = run(mon)
+        res[str(n)] = {{"fps": fps, "log": log,
+                        "chips": [d.id for d in mon.devices]}}
+    print("RESULT " + json.dumps(res))
+""")
+
+
+@pytest.fixture(scope="module")
+def placement(tmp_path_factory):
+    """Six streaming storm rounds of 8 shards x 32 hosts (round 3 over
+    budget so round 4 is degraded, round 5 with corruption surfacing on
+    the last shard), on one device, on four and through the single-slab
+    monitor, in a subprocess with four host devices.  Per sharded round:
+    the sequence of provider calls (P), sweep launches with the devices
+    their arrays live on (L) and sweep pulls with ``in_flight`` (F)."""
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    script = tmp_path_factory.mktemp("placement") / "probe.py"
+    script.write_text(_PLACEMENT.format(
+        paths=[os.path.join(root, "src"), root]))
+    out = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("RESULT ")]
+    return json.loads(line[-1][len("RESULT "):])
+
+
+def test_placement_verdicts_identical_on_one_and_four_devices(placement):
+    single = placement["single"]
+    assert any(d for _, d, _ in single) and all(f for _, _, f in single)
+    assert placement["1"]["fps"] == single
+    assert placement["4"]["fps"] == single
+
+
+def test_placement_provider_order_and_oracle_revisit(placement):
+    for n in ("1", "4"):
+        for k, log in enumerate(placement[n]["log"]):
+            calls = [v for e, v in log if e == "P"]
+            # round 5: shard 7 sees the corruption and runs the oracle,
+            # shards 0-6 are re-visited through it once all have finished
+            assert calls == list(range(8)) + (list(range(7)) if k == 5
+                                              else []), (n, k)
+
+
+def test_placement_sweeps_on_their_chips_four_in_flight(placement):
+    four = placement["4"]
+    assert four["chips"] == [s % 4 for s in range(8)]
+    for log in four["log"]:
+        shards = [v for e, v in log if e == "P"]
+        launched = [v for e, v in log if e == "L"]
+        assert launched == [[s % 4] for s in shards[:len(launched)]]
+        # a pull starts once four shards are in flight; the last three
+        # drain; round 5's shard 7 ran the oracle, with no sweep
+        pulls = [v for e, v in log if e == "F"]
+        assert pulls == [4, 4, 4, 4, 4, 3, 2, 1][:len(launched)]
+        assert len(launched) in (7, 8)
+
+
+def test_placement_one_device_finishes_each_launch_before_the_next(
+        placement):
+    for log in placement["1"]["log"]:
+        pulls = [v for e, v in log if e == "F"]
+        assert pulls and set(pulls) == {1}
+        # after the provider call: its launch, then straight its pull
+        seq = [e for e, _ in log]
+        for i, e in enumerate(seq):
+            if e == "L":
+                assert seq[i + 1] == "F"

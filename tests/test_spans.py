@@ -3,6 +3,7 @@
 spans' metadata, no span inside a per-host loop, ``stage_seconds`` keys,
 and the self-time arithmetic of the benchmark's span reader
 (``bench/metrics/_spans.py``)."""
+import types
 from collections import Counter
 
 import jax
@@ -31,7 +32,8 @@ PARENTS = {
     "monitor.validity": {"monitor.round"},
     "shard.visit": {"monitor.round"},
     "shard.provider": {"shard.visit"},
-    "monitor.detect": {"monitor.round", "shard.visit"},
+    "shard.finish": {"monitor.round"},
+    "monitor.detect": {"monitor.round", "shard.visit", "shard.finish"},
     "detect.quarantine": {"monitor.detect"},
     "detect.moments": {"monitor.detect"},
     "detect.stage": {"monitor.detect"},
@@ -40,7 +42,7 @@ PARENTS = {
     "sweep.dispatch": {"detect.sweep"},
     "sweep.pull": {"detect.sweep"},
     "detect.redecide": {"monitor.detect"},
-    "monitor.gather": {"shard.visit", "monitor.finish"},
+    "monitor.gather": {"shard.finish", "monitor.finish"},
     "shard.reduce": {"monitor.round"},
     "monitor.finish": {"monitor.round"},
     "finish.lifecycle": {"monitor.finish"},
@@ -51,8 +53,8 @@ PARENTS = {
 }
 #: the live path's windows hold no invalid cell, so the aggregator passes
 #: no validity mask and the monitor has none to scan
-LIVE = set(PARENTS) - {"shard.visit", "shard.provider", "shard.reduce",
-                       "monitor.validity"}
+LIVE = set(PARENTS) - {"shard.visit", "shard.provider", "shard.finish",
+                       "shard.reduce", "monitor.validity"}
 SHARDED = set(PARENTS) - {"aggregator.diagnose", "aggregator.assemble",
                           "assemble.probe", "assemble.copy",
                           "monitor.validity"}
@@ -76,13 +78,18 @@ def trials():
 def forced_redecide(monkeypatch):
     """Widen the sweep's guard band so that rounds re-decide rows through
     the f64 oracle; records the marginal rows each sweep returned."""
-    real, marginal = sweep_ops.sweep_rows, []
+    real, marginal = sweep_ops.sweep_launch, []
 
     def spy(*a, **k):
-        out = real(*a, **dict(k, eps=0.5))
-        marginal.append(int(out[3].sum()))
-        return out
-    monkeypatch.setattr(sweep_ops, "sweep_rows", spy)
+        pending = real(*a, **dict(k, eps=0.5))
+        collect = pending.collect
+
+        def recorded(*ca, **ck):
+            out = collect(*ca, **ck)
+            marginal.append(int(out[3].sum()))
+            return out
+        return types.SimpleNamespace(collect=recorded)
+    monkeypatch.setattr(sweep_ops, "sweep_launch", spy)
     return marginal
 
 
@@ -167,6 +174,12 @@ def test_span_tree_and_counts(tmp_path, trials, forced_redecide, path):
     # the oracle re-decides exactly the rows the sweep flagged marginal
     assert st["detect.redecide"].meta["rows"] == sum(forced_redecide) > 0
     assert st["detect.sweep"].meta["rows"] == 16 * 3
+    # one device: each pull finds only its own sweep in flight, on chip 0
+    pull = st["sweep.pull"]
+    assert pull.meta["in_flight"] == pull.n > 0
+    assert pull.meta["chip"] == st["detect.sweep"].meta["chip"] == 0
+    if path == "sharded":
+        assert st["shard.finish"].n == st["shard.visit"].n == 2 * 3
     if path == "live":
         copied = st["assemble.copy"].meta
         assert copied["bytes"] == (rounds.agg.stats.staged_bytes - staged0)
@@ -201,7 +214,9 @@ def test_spans_per_round_do_not_grow_with_hosts(tmp_path, trials, path):
         w = _traced(tmp_path / str(hosts), make(trials, hosts), 3)
         counts.append(_per_round(w))
     assert counts[0] == counts[1]
-    assert all(c["monitor.detect"] == (1 if path == "live" else 2)
+    # live: one detect around both halves; sharded: a launch and a
+    # finish half for each of the two shards
+    assert all(c["monitor.detect"] == (1 if path == "live" else 4)
                for c in counts[0])
 
 
