@@ -68,7 +68,8 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
                         patch_base: np.ndarray, wn: int, bn: int,
                         threshold: float, persistence: float,
                         use_kernel: bool, exact: bool,
-                        device=None, moments=None) -> DetectPending:
+                        device=None, moments=None, window=None,
+                        tick_end: Optional[int] = None) -> DetectPending:
     """Single-tick sweep over the (H, bn + wn) trailing slab, launched.
 
     ``patch_win``/``patch_base`` are the caller's original (H, Nw)/(H, Nb)
@@ -80,6 +81,10 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
     state supplies these at O(delta)); marginal rows are still re-decided
     through the f64 oracle from the raw patch, so epsilon-close moments
     cannot move a decision.
+
+    ``window`` (with ``moments``; see :func:`detect_hosts_slab_launch`)
+    takes ``tail32`` unstaged: the device window stages only what it
+    puts.
     """
     H, T = tail32.shape
     if moments is not None:
@@ -98,9 +103,11 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
         # copy and the kernel's slab stay O(wn) instead of O(wn + bn)
         # (onsets are window-relative either way; verified equivalent
         # for both kernel and reference dispatch)
-        with span("detect.stage") as sp:
-            disp, bn_d = np.ascontiguousarray(tail32[:, bn:]), 0
-            sp.set_metadata(bytes=disp.nbytes)
+        disp, bn_d = tail32[:, bn:], 0
+        if window is None:
+            with span("detect.stage") as sp:
+                disp = np.ascontiguousarray(disp)
+                sp.set_metadata(bytes=disp.nbytes)
         ticks = np.array([wn], np.int64)
     else:
         disp, bn_d = tail32, bn
@@ -108,7 +115,8 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
     sweep = sweep_ops.sweep_launch(
         disp, wn, bn_d, ticks, threshold, persistence,
         moments=(mu[:, None], sd[:, None]), argmax_fallback=True,
-        use_kernel=use_kernel, device=device)
+        use_kernel=use_kernel, device=device, window=window,
+        tick_end=tick_end)
     return DetectPending(sweep, patch_win, patch_base, threshold,
                          persistence, exact)
 
@@ -160,7 +168,8 @@ def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
                              use_kernel: bool = True, exact: bool = True,
                              valid: Optional[np.ndarray] = None,
                              force_oracle: bool = False, device=None,
-                             moments=None):
+                             moments=None, window=None,
+                             tick_end: Optional[int] = None):
     """:func:`detect_hosts` over a trailing latency slab, launched: the
     fast path returns a :class:`DetectPending` whose ``collect()`` gives
     ``(fire, score, onset)`` per host; the oracle path returns its
@@ -198,6 +207,15 @@ def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
     :class:`repro.core.rolling.IncrementalMoments`); ignored on the
     masked/forced oracle path, which always derives exact masked moments
     itself.
+
+    ``window`` (a :class:`repro.kernels.sweep.ops.DeviceWindow`, with
+    ``moments`` and the exclusive absolute ``tick_end`` of the tail's
+    last column) keeps the tail's last ``wn`` columns on ``device``
+    between calls: the clean fast path then stages and puts only the
+    columns that slid in since the window's last sweep, and the tail
+    itself is never copied — the re-decision upcasts its marginal rows
+    from the caller's views.  The window trusts that a tick once seen
+    does not change, as the incremental moments do.
     """
     tail = np.asarray(tail)
     if tail.ndim != 2 or tail.shape[-1] != wn + bn:
@@ -218,13 +236,19 @@ def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
             float(threshold), float(persistence))
         return sweep_ops.Resolved(
             (fire.astype(bool), score, onset.astype(np.intp)))
-    with span("detect.stage") as sp:
-        tail32 = np.ascontiguousarray(tail, np.float32)
-        sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
-    # the exact re-decision must see the caller's values, not the f32
-    # staging — only a genuinely-f32 tail may reuse the staged copy
-    patch = tail32 if tail.dtype == np.float32 else tail
+    if window is None or moments is None:
+        window = None
+        with span("detect.stage") as sp:
+            tail32 = np.ascontiguousarray(tail, np.float32)
+            sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
+        # the exact re-decision must see the caller's values, not the f32
+        # staging — only a genuinely-f32 tail may reuse the staged copy
+        patch = tail32 if tail.dtype == np.float32 else tail
+    else:
+        # the device window stages only the columns it puts
+        tail32 = patch = tail
     return _detect_tail_launch(
         tail32, patch[:, bn:], patch[:, :bn], int(wn), int(bn),
         float(threshold), float(persistence), bool(use_kernel),
-        bool(exact), device=device, moments=moments)
+        bool(exact), device=device, moments=moments, window=window,
+        tick_end=tick_end)

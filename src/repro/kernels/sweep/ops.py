@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,9 +69,11 @@ def persistence_count(n: int, persistence: float) -> int:
     return c
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "wn", "threshold", "min_hot", "eps", "argmax_fallback", "use_kernel",
-    "interpret", "block_t"))
+_STATIC = ("wn", "threshold", "min_hot", "eps", "argmax_fallback",
+           "use_kernel", "interpret", "block_t")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _sweep_jit(x, mu, sd, ticks, valid_n, wn, threshold, min_hot, eps,
                argmax_fallback, use_kernel, interpret, block_t):
     if use_kernel:
@@ -80,6 +82,42 @@ def _sweep_jit(x, mu, sd, ticks, valid_n, wn, threshold, min_hot, eps,
                                  block_t=block_t, interpret=interpret)
     return sweep_rows_ref(x, mu, sd, ticks, valid_n, wn, threshold,
                           min_hot, eps, argmax_fallback, block_t)
+
+
+def _window_ticks(x, wn):
+    """A device window's sweep: one tick at ``wn``, every row valid."""
+    return (jnp.full((1,), wn, jnp.int32),
+            jnp.full((x.shape[0],), wn, jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, donate_argnums=0)
+def _advance_sweep_jit(old, packed, wn, threshold, min_hot, eps,
+                       argmax_fallback, use_kernel, interpret, block_t):
+    """Slide a held window (``old``, donated) by the new columns and sweep
+    it, in one dispatch: ``(window, results)``.  ``packed`` is one put
+    of (rows, 2 + d) f32: mu, sd, then the d new columns."""
+    x = jnp.concatenate([old[:, packed.shape[1] - 2:], packed[:, 2:]],
+                        axis=1)
+    return x, _sweep_jit(x, packed[:, :1], packed[:, 1:2],
+                         *_window_ticks(x, wn), wn, threshold, min_hot, eps,
+                         argmax_fallback, use_kernel, interpret, block_t)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _proof_sweep_jit(old, x, d, mu, sd, wn, threshold, min_hot, eps,
+                     argmax_fallback, use_kernel, interpret, block_t):
+    """Sweep the window ``x`` and prove, bit for bit, that the held
+    window ``old`` slid by ``d`` columns holds the same values on the
+    columns the two share: ``(same, results)``.  ``old`` is ``x`` and
+    ``d`` 0 where nothing is to be proved."""
+    bits = functools.partial(jax.lax.bitcast_convert_type,
+                             new_dtype=jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    same = jnp.all((bits(jnp.roll(old, -d, axis=1)) == bits(x))
+                   | (col >= x.shape[1] - d))
+    return same, _sweep_jit(x, mu, sd, *_window_ticks(x, wn), wn,
+                            threshold, min_hot, eps, argmax_fallback,
+                            use_kernel, interpret, block_t)
 
 
 def rolling_moments(lat64: np.ndarray, ticks: np.ndarray, wn: int, bn: int,
@@ -280,13 +318,15 @@ class Resolved:
 
 class SweepPending:
     """A sweep dispatched to its device and not yet pulled: the four
-    result arrays on the device, the chip they live on, and what the
-    host-side validity gate needs."""
+    result arrays on the device, the chip they live on, what the
+    host-side validity gate needs, and a device window's coherence
+    proof with the :class:`DeviceWindows` that counts it."""
 
-    __slots__ = ("_out", "chip", "_gate")
+    __slots__ = ("_out", "chip", "_gate", "_proof")
 
-    def __init__(self, out, chip: int, gate) -> None:
+    def __init__(self, out, chip: int, gate, proof=None) -> None:
         self._out, self.chip, self._gate = out, chip, gate
+        self._proof = proof
 
     def collect(self, in_flight: int = 1,
                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -301,7 +341,11 @@ class SweepPending:
             score = np.array(score, np.float64)
             onset = np.asarray(onset).astype(np.intp)
             marg = np.asarray(marg).astype(bool)
-        self._out = None
+            if self._proof is not None:
+                same, owner = self._proof
+                owner.proofs += 1
+                owner.parity_failures += not bool(same)
+        self._out = self._proof = None
         if self._gate is not None:
             # host-side validity gate: a baseline you cannot estimate (or a
             # window with zero valid cells) may never fire, whatever the
@@ -318,6 +362,162 @@ class SweepPending:
             score = np.where(ok, score, 0.0)
             onset = np.where(ok, onset, -1)
         return fire, score, onset, marg
+
+
+#: ``put`` on the launch half of ``detect.sweep``: the host put the
+#: whole window, or only the columns that slid in (a number, as all span
+#: metadata are)
+PUT_FULL, PUT_DELTA = 0, 1
+
+
+class DeviceWindow:
+    """One slab's (rows, wn) f32 latency window as last swept, held on
+    its device between rounds, with the exclusive absolute tick its last
+    column ends before.  :func:`sweep_launch` advances it by the round's
+    new columns only; its :class:`DeviceWindows` keeps it."""
+
+    __slots__ = ("owner", "x", "tick_end", "device", "advances")
+
+    def __init__(self, owner: "DeviceWindows") -> None:
+        self.owner = owner
+        self.x = self.tick_end = self.device = None
+        self.advances = 0
+
+    def plan(self, rows: int, wn: int, tick_end: int,
+             device) -> Tuple[int, int, bool]:
+        """``(put, d, proof)`` for a sweep of the (rows, wn) window that
+        ends before ``tick_end`` on ``device``: ``d`` columns slid since
+        the last sweep, and whether this full put re-proves the carried
+        window.  A first sweep, other dims or device, or a slide outside
+        ``(0, wn)`` starts the window afresh from a full put — a slide of
+        0 too: the same ticks may come again with other values (a
+        snapshot diagnosed twice), which only a full put can see."""
+        d = 0
+        if self.x is not None and self.x.shape == (rows, wn) \
+                and self.device == device:
+            d = int(tick_end) - self.tick_end
+        if not 0 < d < wn:
+            self.x, self.advances = None, 0
+            return PUT_FULL, 0, False
+        self.advances += 1
+        every = self.owner.reanchor_every
+        if every > 0 and self.advances % every == 0:
+            return PUT_FULL, d, True
+        return PUT_DELTA, d, False
+
+
+class DeviceWindows:
+    """A monitor's device windows, one per slab of rows ``[base, base +
+    rows)``, and the counts of their puts and proofs.
+
+    A window is trusted as the incremental moments are
+    (:mod:`repro.core.rolling`): a slab's ticks, once seen, do not
+    change.  Every ``reanchor_every``-th advance of a window
+    (``REPRO_REANCHOR_ROUNDS``) takes a full put and proves on the device
+    that the carried window agrees with it bit for bit; a mismatch counts
+    in :attr:`parity_failures`.  Whatever drops the moments of rows drops
+    the windows holding them (:meth:`drop`, :meth:`clear`; see
+    ``FleetMonitor.invalidate_rows``)."""
+
+    def __init__(self) -> None:
+        self.reanchor_every = tuning.reanchor_rounds()
+        self._held: Dict[Tuple[int, int], DeviceWindow] = {}
+        #: launches by ``put`` code (PUT_FULL, PUT_DELTA)
+        self.puts = [0, 0]
+        self.proofs = 0
+        self.parity_failures = 0
+
+    def get(self, base: int, rows: int) -> DeviceWindow:
+        """The window of rows ``[base, base + rows)``; a new one drops
+        every other window that overlaps those rows."""
+        key = (int(base), int(rows))
+        w = self._held.get(key)
+        if w is None:
+            self.drop(np.arange(base, base + rows))
+            w = self._held[key] = DeviceWindow(self)
+        return w
+
+    def drop(self, rows: np.ndarray) -> None:
+        """Forget every window that holds one of ``rows``."""
+        for key in [k for k in self._held
+                    if np.any((rows >= k[0]) & (rows < sum(k)))]:
+            del self._held[key]
+
+    def clear(self) -> None:
+        """Forget every window."""
+        self._held.clear()
+
+    def stats(self) -> Dict[str, int]:
+        """Counters snapshot, merged into the monitor's
+        ``incremental_stats()``."""
+        return {"window_delta_puts": self.puts[PUT_DELTA],
+                "window_full_puts": self.puts[PUT_FULL],
+                "window_proofs": self.proofs,
+                "window_parity_failures": self.parity_failures}
+
+
+def _launch(rows: int, h2d: int, put: int, device, put_args,
+            dispatch) -> Tuple[tuple, int]:
+    """The launch half of the ``detect.sweep`` span, for both launches:
+    ``put_args()`` puts the arrays (``sweep.put``), ``dispatch(args)``
+    starts the sweep (``sweep.dispatch``), on ``device``, and its
+    results start back to the host.  Returns ``(results, chip)``; the
+    collect opens the span again around the pull."""
+    with span("detect.sweep", rows=rows, h2d_bytes=h2d, put=put) as sp, \
+            (contextlib.nullcontext() if device is None
+             else jax.default_device(device)):
+        with span("sweep.put"):
+            args = put_args()
+        chip = next(iter(args[0].devices())).id
+        sp.set_metadata(chip=chip)
+        with span("sweep.dispatch"):
+            out = dispatch(args)
+            # the results start back to the host as soon as the kernel
+            # ends, side by side, whether or not the host is still busy
+            # launching other sweeps
+            for x in out:
+                x.copy_to_host_async()
+    return out, chip
+
+
+def _window_launch(window: DeviceWindow, lat: np.ndarray, tick_end: int,
+                   mu, sd, device, static: Dict[str, object]) -> SweepPending:
+    """:func:`sweep_launch` of a device window: stage and put only the
+    columns that slid in, with mu and sd, as one array (or the whole
+    window), then slide and sweep on the device in one dispatch."""
+    R, wn = lat.shape
+    put, d, proof = window.plan(R, wn, tick_end, device)
+    with span("detect.stage") as sp:
+        if put == PUT_DELTA:
+            new = np.empty((R, 2 + d), np.float32)
+            new[:, 0], new[:, 1] = mu.reshape(R), sd.reshape(R)
+            new[:, 2:] = lat[:, wn - d:]
+            h2d = new.nbytes
+        else:
+            new = np.ascontiguousarray(lat, np.float32)
+            h2d = new.nbytes + 8 * R
+        sp.set_metadata(bytes=new.nbytes)
+    window.owner.puts[put] += 1
+
+    def put_args():
+        if put == PUT_DELTA:
+            return (jnp.asarray(new),)
+        return (jnp.asarray(new), jnp.asarray(np.asarray(mu, np.float32)),
+                jnp.asarray(np.asarray(sd, np.float32)))
+
+    def dispatch(args):
+        if put == PUT_DELTA:
+            window.x, out = _advance_sweep_jit(window.x, *args, **static)
+            return out
+        x = args[0]
+        same, out = _proof_sweep_jit(window.x if proof else x, x,
+                                     np.int32(d), *args[1:], **static)
+        window.x = x
+        return out + ((same,) if proof else ())
+    out, chip = _launch(R, h2d, put, device, put_args, dispatch)
+    window.tick_end, window.device = int(tick_end), device
+    return SweepPending(out[:4], chip, None,
+                        (out[4], window.owner) if proof else None)
 
 
 def sweep_rows(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
@@ -389,13 +589,20 @@ def sweep_launch(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                  eps: float = SWEEP_GUARD_EPS, use_kernel: bool = False,
                  block_t: Optional[int] = None,
                  valid: Optional[np.ndarray] = None,
-                 device=None):
+                 device=None, window: Optional[DeviceWindow] = None,
+                 tick_end: Optional[int] = None):
     """The first half of :func:`sweep_rows` (same arguments): f32
     staging, the host->device put, the asynchronous dispatch and the
     start of the results' copies back to the host.  Returns
     a :class:`SweepPending` whose ``collect()`` pulls the results, so the
     host can prepare and launch other sweeps — on other devices — while
-    this one runs."""
+    this one runs.
+
+    ``window`` sweeps ``lat`` — the (rows, wn) window ending before
+    absolute tick ``tick_end``, any dtype, bn 0 and one tick at wn, with
+    ``moments`` — through a :class:`DeviceWindow` held on ``device``:
+    only the columns that slid in since its last sweep are staged and
+    put (``put`` on the ``detect.sweep`` span)."""
     lat = np.asarray(lat)
     if lat.ndim != 2:
         raise ValueError(f"lat must be (rows, T), got {lat.shape}")
@@ -435,32 +642,26 @@ def sweep_launch(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
             moments = (mm, ss)
     mu, sd = moments
     min_hot = persistence_count(wn, persistence)
+    static = dict(wn=wn, threshold=float(threshold), min_hot=int(min_hot),
+                  eps=float(eps), argmax_fallback=bool(argmax_fallback),
+                  use_kernel=bool(use_kernel),
+                  interpret=bool(use_kernel) and interpret_mode(device),
+                  block_t=tuning.sweep_block_t(block_t))
+    if window is not None:
+        if bn or T != wn or vmask is not None or valid_n is not None:
+            raise ValueError("a device window sweeps one (rows, wn) "
+                             "window at tick wn, unmasked")
+        return _window_launch(window, lat, tick_end, mu, sd, device, static)
     lat32 = np.ascontiguousarray(lat, np.float32)
     if vmask is not None:
         lat32 = np.where(vmask, lat32, np.float32(spike_mod.MASK_NEG))
-    # the launch half of the ``detect.sweep`` span: the put and the
-    # (asynchronous) dispatch; the collect opens it again around the pull
     h2d = lat32.nbytes + 4 * (np.size(mu) + np.size(sd) + nt + R)
-    with span("detect.sweep", rows=R, h2d_bytes=h2d) as sp, \
-            (contextlib.nullcontext() if device is None
-             else jax.default_device(device)):
-        with span("sweep.put"):
-            args = (jnp.asarray(lat32),
-                    jnp.asarray(np.asarray(mu, np.float32)),
-                    jnp.asarray(np.asarray(sd, np.float32)),
-                    jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32))
-        chip = next(iter(args[0].devices())).id
-        sp.set_metadata(chip=chip)
-        with span("sweep.dispatch"):
-            out = _sweep_jit(
-                *args, wn, float(threshold), int(min_hot), float(eps),
-                bool(argmax_fallback), bool(use_kernel),
-                bool(use_kernel) and interpret_mode(device),
-                tuning.sweep_block_t(block_t))
-            # the four results start back to the host as soon as the
-            # kernel ends, side by side, whether or not the host is
-            # still busy launching other sweeps
-            for x in out:
-                x.copy_to_host_async()
+    out, chip = _launch(
+        R, h2d, PUT_FULL, device,
+        lambda: (jnp.asarray(lat32),
+                 jnp.asarray(np.asarray(mu, np.float32)),
+                 jnp.asarray(np.asarray(sd, np.float32)),
+                 jnp.asarray(ticks, jnp.int32), jnp.asarray(vn, jnp.int32)),
+        lambda args: _sweep_jit(*args, **static))
     return SweepPending(out, chip, None if vmask is None
                         else (vmask, bcnt, ticks, wn))

@@ -147,6 +147,10 @@ class FleetAggregator:
         self._staged_seq = np.full(H, -1, np.int64)
         self._staged_last = np.full(H, -np.inf)
         self._staged_full = np.zeros(H, bool)
+        #: ``_staged_full`` as the monitor last diagnosed it: a row that
+        #: was not a full clean window then or now was rewritten in place
+        #: since, and ``diagnose`` drops the monitor's carried state of it
+        self._carried = np.zeros(H, bool)
         self.stats = AggregatorStats()
         self.last_snapshot: Optional[FleetSnapshot] = None
         self._stopped = False
@@ -536,7 +540,12 @@ class FleetAggregator:
         The per-cell validity mask goes to the monitor only while some
         staged window holds an invalid cell (the stager counts them);
         otherwise ``valid=None``, which ``diagnose_fleet`` treats exactly
-        like an all-true mask, without scanning one."""
+        like an all-true mask, without scanning one.  Rows rewritten in
+        place since the monitor's last round (zeroed, masked, backfilled,
+        or restaged over such a row) go to
+        :meth:`~repro.monitor.fleet.FleetMonitor.invalidate_rows` first:
+        the monitor's carried moments and device windows hold what those
+        rows said before."""
         with span("aggregator.diagnose", hosts=len(self.agents)) as sp:
             # agent-restart wiring: a host whose probe was restarted/replaced
             # since the last round gets its monitor-side strike/quarantine
@@ -564,6 +573,12 @@ class FleetAggregator:
                 self._staged_full[h] = False
             self.stats.masked_hosts += len(snap.masked)
             sp.set_metadata(masked=len(snap.masked))
+            # zeroed, masked and backfilled rows, and rows restaged over
+            # one, hold other values than the monitor saw for the same
+            # ticks: their carried moments and device windows must go
+            monitor.invalidate_rows(
+                np.flatnonzero(~(self._carried & self._staged_full)))
+            self._carried[:] = self._staged_full
             T = self.window_n
             # the invalid-cell counts say whether the mask has a False
             # cell: without one the monitor's clean path needs no mask

@@ -58,7 +58,7 @@ from repro.core.taxonomy import CauseClass, Diagnosis, SpikeEvent
 from repro.kernels.detect import ops as detect_ops
 from repro.kernels.fused import ops as fused_ops
 from repro.kernels.spike import ops as spike_ops
-from repro.kernels.sweep.ops import Resolved
+from repro.kernels.sweep.ops import DeviceWindows, Resolved
 from repro.kernels.xcorr import ops as xcorr_ops
 
 
@@ -170,6 +170,10 @@ class FleetMonitor:
         # bench's cold baseline.
         self._inc = (rolling.IncrementalMoments(cap_ticks=self.cfg.baseline_n)
                      if (fast_detect and incremental) else None)
+        # the rounds that use those moments also keep each slab's detect
+        # window on its device and put only the ticks that slid in; what
+        # drops moment rows drops the windows holding them
+        self._windows = None if self._inc is None else DeviceWindows()
         self._strikes: Dict[int, int] = {}
         # telemetry quarantine (hysteresis): a host whose latency-channel
         # invalid fraction exceeds `enter_frac` for `enter_rounds`
@@ -291,10 +295,27 @@ class FleetMonitor:
         self._bad_streak.pop(h, None)
         self._clean_streak.pop(h, None)
         self._quar_backoff.pop(h, None)
-        if self._inc is not None:
-            # the replacement agent's ring shares no history with the old
-            # one — its cached moment blocks are another process's data
-            self._inc.invalidate([h])
+        # the replacement agent's ring shares no history with the old
+        # one — its cached moment blocks are another process's data
+        self.invalidate_rows([h])
+
+    def invalidate_rows(self, rows) -> None:
+        """Drop the carried detect state of ``rows`` (absolute host ids):
+        their incremental moment blocks and every device window holding
+        one of them, so their next clean round starts from a full put.
+
+        Both trust that a tick, once seen, keeps its value.  A caller
+        that rewrites rows of its slab in place — zeroing a dead host,
+        masking a young one, restaging over either — breaks that trust
+        for those rows, and only the caller can see it
+        (:meth:`repro.monitor.aggregator.FleetAggregator.diagnose` calls
+        this every round)."""
+        if self._inc is None:
+            return
+        rows = np.asarray(rows, np.intp).reshape(-1)
+        if rows.size:
+            self._inc.invalidate(rows)
+            self._windows.drop(rows)
 
     def _update_budget(self, round_cost_s: float) -> None:
         """Advance the deadline hysteresis one round."""
@@ -361,6 +382,7 @@ class FleetMonitor:
             # (checkpoint bytes stay flat); a restored monitor starts
             # cold and its first clean round re-anchors from scratch
             self._inc.invalidate_all()
+            self._windows.clear()
 
     # ------------------------------------------------------------- fleet RCA
     def diagnose_fleet(self, ts: np.ndarray, host_data: np.ndarray,
@@ -483,8 +505,11 @@ class FleetMonitor:
         """Counters of the incremental moment state (None when the
         direct moment pass is in use): rounds, re-anchors, the parity
         bit, and cache traffic — surfaced for ops dashboards and the
-        ``fleet/incremental_*`` bench rows."""
-        return None if self._inc is None else self._inc.stats()
+        ``fleet/incremental_*`` bench rows — and of the device windows:
+        puts by kind, proofs, ``window_parity_failures``."""
+        if self._inc is None:
+            return None
+        return {**self._inc.stats(), **self._windows.stats()}
 
     def _detect_round(self, host_data: np.ndarray,
                       vfull: Optional[np.ndarray], li: int,
@@ -556,7 +581,11 @@ class FleetMonitor:
         the visited rows' incremental state (their slab may carry
         masked/zeroed cells, so carried blocks are no longer trusted) —
         which also means an oracle re-visit of a shard never advances
-        the moment state twice."""
+        the moment state twice.  The clean rounds that take the moments
+        also sweep through the slab's device window (rows ``base ..
+        base + hosts``; :class:`~repro.kernels.sweep.ops.DeviceWindows`),
+        putting only the ticks that slid in; any other launch over those
+        rows drops the window, so the next one starts from a full put."""
         hosts = host_data.shape[0]
         lat = host_data[:, li, :]
         # telemetry quarantine: invalid fraction of the latency channel
@@ -584,7 +613,7 @@ class FleetMonitor:
             # candidate re-slice.  A masked round routes through this call
             # on BOTH detect paths — the mask branch IS the f64 oracle, so
             # fast and oracle stay trivially byte-identical under chaos.
-            moments = None
+            moments = window = None
             if self._inc is not None:
                 if lvt is None and not force_oracle and tick_end is not None:
                     with span("detect.moments", rows=hosts) as sp:
@@ -594,13 +623,15 @@ class FleetMonitor:
                         sp.set_metadata(
                             blocks_computed=self._inc.last_round_computed,
                             rebuilt_rows=self._inc.last_round_rebuilt_rows)
+                    window = self._windows.get(base, hosts)
                 else:
-                    self._inc.invalidate(np.arange(base, base + hosts))
+                    self.invalidate_rows(np.arange(base, base + hosts))
             pending = detect_ops.detect_hosts_slab_launch(
                 lat[:, T - wn - bn:T], wn, bn,
                 self.cfg.threshold, self.cfg.persistence,
                 use_kernel=self.use_kernels, valid=lvt,
-                force_oracle=force_oracle, device=device, moments=moments)
+                force_oracle=force_oracle, device=device, moments=moments,
+                window=window, tick_end=tick_end)
             return pending, qhosts
         scores = self.host_spike_scores(lat[:, T - wn:],
                                         lat[:, T - wn - bn:T - wn])

@@ -179,3 +179,31 @@ def test_kernel_names_match_the_roofline_readers(one_chip, rca_geometry):
     assert names == [[roofline.SWEEP_OP], [roofline.FUSED_OP]]
     assert roofline.is_sweep_op(names[0][0])
     assert roofline.is_fused_op(names[1][0])
+
+
+@pytest.mark.parametrize("jit", ["advance", "proof"])
+def test_window_sweeps_compile_with_the_sweep_kernel(one_chip, jit):
+    """The device-window dispatches of one shard at the 0.5 s cadence:
+    the slide (one put of mu, sd and 50 new ticks into the donated
+    window) and the full put with its coherence proof.  Both keep the kernel's custom call named
+    as ``sweep_roofline`` finds it, and the slide writes the window in
+    place of the one it was given."""
+    cfg = EngineConfig()
+    wn, R, d = cfg.window_n, tuning.shard_hosts(), 50
+    static = dict(
+        wn=wn, threshold=cfg.threshold,
+        min_hot=sweep_ops.persistence_count(wn, cfg.persistence),
+        eps=sweep_ops.SWEEP_GUARD_EPS, argmax_fallback=True,
+        use_kernel=True, interpret=False,
+        block_t=tuning.sweep_block_t(None))
+    if jit == "advance":
+        fn = sweep_ops._advance_sweep_jit
+        args = _shapes(one_chip, ((R, wn), F32), ((R, 2 + d), F32))
+    else:
+        fn = sweep_ops._proof_sweep_jit
+        args = _shapes(one_chip, ((R, wn), F32), ((R, wn), F32),
+                       ((), I32), ((R, 1), F32), ((R, 1), F32))
+    text = fn.lower(*args, **static).compile().as_text()
+    assert [trace_reduce.op_name(n) for n in _CUSTOM_CALL.findall(text)] \
+        == [roofline.SWEEP_OP]
+    assert ("input_output_alias={ {0}: (0" in text) == (jit == "advance")
