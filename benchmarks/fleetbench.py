@@ -8,9 +8,9 @@ Four sections, all emitted into BENCH_fleet.json by run.py:
   fleet/  — batched ``FleetMonitor.diagnose_fleet`` vs B sequential
             per-host ``engine.process`` replays, at B in {16, 64, 256,
             1024}: hosts/sec, speedup, per-stage wall time, plus the
-            streaming-detect kernel (one dispatch over the f32 slab) vs the
-            seed detect path (spike dispatch + f64 ``detect_rows`` replay)
-            with a byte-exact flagged/onset parity check.
+            streaming-detect stage (one dispatch over the f32 slab) with a
+            byte-exact flagged/onset parity check against f64
+            ``detect_rows``.
   fleet/live_* — the live path: ``FleetAggregator`` staging (seqlock
             read_window into a preallocated slab) vs per-host
             ``window(copy=True)`` snapshots + ``np.stack``, and the
@@ -176,9 +176,9 @@ def sweep_slab_rows(n_per_class: int = 4, reps: int = 3,
     def single_tick():
         # the CPU deployment path: masked-XLA ref (the Pallas kernel runs
         # in interpret mode on CPU — a correctness, not a timing, path)
-        return detect_ops.detect_hosts_slab(tail, wn, bn, cfg.threshold,
-                                            cfg.persistence,
-                                            use_kernel=False)
+        return detect_ops.detect_hosts_slab_launch(
+            tail, wn, bn, cfg.threshold, cfg.persistence,
+            use_kernel=False).collect()
 
     single_tick()                                          # jit warm-up
     tick_s = _median_wall(single_tick, reps)
@@ -202,9 +202,7 @@ def _make_fleet(n_hosts: int, bad_host: int, seed: int = 0,
     """(ts, (hosts, C, T) data, channels).  Quiet hosts cycle over
     ``n_unique`` distinct ambient trials (fleet-size-independent setup
     cost); one injected straggler.  ``bad_every`` > 0 additionally injects
-    every bad_every'th host (the incident-storm profile: the seed detect
-    path re-slices every candidate in f64, so its cost scales with the
-    flagged fraction)."""
+    every bad_every'th host (the incident-storm profile)."""
     quiet = [make_trial(seed + u, cls, intensity=0.0, t_on=40.0,
                         confuser_prob=0.0)
              for u in range(min(n_unique, n_hosts))]
@@ -222,35 +220,34 @@ def _make_fleet(n_hosts: int, bad_host: int, seed: int = 0,
     return quiet[0].ts[:t_hi], data, quiet[0].channels
 
 
-def _detect_compare_rows(B: int, ts, data, data32, channels, reps: int,
+def _detect_compare_rows(B: int, ts, data32, channels, reps: int,
                          tag: str = "") -> Tuple[list, float, dict, object]:
-    """Streaming-detect vs seed-detect stage rows for one fleet slab.
+    """Streaming-detect stage rows for one fleet slab, with the flagged
+    hosts and onsets checked against f64 ``detect_rows`` over the same
+    latency tail.
 
-    Returns (rows, batched wall, median stages, FleetDiagnosis of the
-    fast path)."""
+    Returns (rows, batched wall, median stages, FleetDiagnosis)."""
+    from repro.core.spike import detect_rows
     mon = FleetMonitor(use_kernels=False)
-    oracle = FleetMonitor(use_kernels=False, fast_detect=False)
     mon.diagnose_fleet(ts, data32, channels)            # jit warm-up
-    oracle.diagnose_fleet(ts, data, channels)
-
     batched_s, stages, fd = _median_stages(mon, ts, data32, channels, reps)
-    _, stages_o, fd_o = _median_stages(oracle, ts, data, channels, reps)
+    cfg, T = mon.cfg, data32.shape[-1]
+    wn = min(cfg.window_n, T // 2)
+    bn = min(cfg.baseline_n, T - wn)
+    li = list(channels).index(cfg.latency_metric)
+    tail = np.asarray(data32[:, li, T - wn - bn:], np.float64)
+    fire, _, onset = detect_rows(tail[:, bn:], tail[:, :bn], cfg.threshold,
+                                 cfg.persistence)
     parity = float(
-        fd.flagged_hosts == fd_o.flagged_hosts
-        and all(fd.diagnoses[h].event.t_onset
-                == fd_o.diagnoses[h].event.t_onset
+        sorted(fd.flagged_hosts) == np.flatnonzero(fire).tolist()
+        and all(fd.diagnoses[h].event.t_onset == ts[T - wn + onset[h]]
                 for h in fd.flagged_hosts))
-    det_f, det_o = stages["detect"], stages_o["detect"]
     rows = [
-        (f"fleet/detect_fast_s{tag}/B{B}", det_f,
+        (f"fleet/detect_fast_s{tag}/B{B}", stages["detect"],
          f"one streaming-detect dispatch, f32 slab; "
          f"{len(fd.flagged_hosts)} flagged"),
-        (f"fleet/detect_oracle_s{tag}/B{B}", det_o,
-         "seed: spike dispatch + f64 detect_rows replay"),
-        (f"fleet/detect_speedup{tag}/B{B}", det_o / det_f,
-         "oracle / streaming"),
         (f"fleet/detect_parity{tag}/B{B}", parity,
-         "1.0 = flagged hosts + onsets byte-exact"),
+         "1.0 = flagged hosts + onsets byte-exact vs f64 detect_rows"),
     ]
     return rows, batched_s, stages, fd
 
@@ -261,11 +258,10 @@ def fleet_rows(batch_sizes: Sequence[int] = (16, 64, 256, 1024),
     rows: List[Tuple[str, float, str]] = []
     for B in batch_sizes:
         ts, data, channels = _make_fleet(B, bad_host=B // 2)
-        # the columnar deployment hands the monitor the ring's f32 slab;
-        # the oracle monitor replays the seed path on the seed's f64 slab
+        # the columnar deployment hands the monitor the ring's f32 slab
         data32 = np.ascontiguousarray(data, np.float32)
         det_rows, batched_s, stages, fd = _detect_compare_rows(
-            B, ts, data, data32, channels, reps)
+            B, ts, data32, channels, reps)
         rows.append((f"fleet/batched_s/B{B}", batched_s,
                      f"{len(fd.flagged_hosts)} flagged, straggler="
                      f"{fd.straggler_host}"))
@@ -287,15 +283,14 @@ def fleet_rows(batch_sizes: Sequence[int] = (16, 64, 256, 1024),
                          "the like-for-like comparison)"))
         for stage, wall in stages.items():
             rows.append((f"fleet/stage_s/{stage}/B{B}", wall, ""))
-    # incident-storm profile at the largest B: ~1/4 of the fleet degraded.
-    # The seed path re-slices every candidate host in f64 and replays the
-    # scalar rule over it, so its detect cost grows with the flagged
-    # fraction; the streaming kernel's one-dispatch cost does not.
+    # incident-storm profile at the largest B: ~1/4 of the fleet degraded;
+    # the streaming detect's one-dispatch cost does not grow with the
+    # flagged fraction
     B = max(batch_sizes)
     ts, data, channels = _make_fleet(B, bad_host=B // 2, bad_every=4)
     data32 = np.ascontiguousarray(data, np.float32)
-    det_rows, _, _, _ = _detect_compare_rows(B, ts, data, data32, channels,
-                                             reps, tag="_storm")
+    det_rows, _, _, _ = _detect_compare_rows(B, ts, data32, channels, reps,
+                                             tag="_storm")
     rows += det_rows
     return rows
 

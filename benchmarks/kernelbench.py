@@ -19,8 +19,7 @@ from repro.kernels.backend import interpret_mode
 from repro.kernels.detect.ops import detect_hosts
 from repro.kernels.fused.fused import fused_rca_pallas
 from repro.kernels.fused.ops import fused_rca
-from repro.kernels.spike.ops import spike_scores
-from repro.kernels.welford.ops import welford
+from repro.kernels.fused.ref import spike_scores_ref
 from repro.kernels.xcorr.ops import lagged_xcorr
 
 
@@ -49,14 +48,10 @@ def kernel_microbench(B: int = 256, M: int = 16, N: int = 512,
     us_k = _time(lambda a, b: lagged_xcorr(a, b, K, use_kernel=True), L, Mx)
     rows.append((f"kernel/xcorr_pallas_interp/{B}x{M}x{N}", us_k,
                  "interpret-mode (CPU correctness path)"))
-    W = jnp.asarray(rng.standard_normal((B, M, N)), jnp.float32)
     Bs = jnp.asarray(rng.standard_normal((B, M, 4 * N)), jnp.float32)
-    rows.append((f"kernel/spike_ref_jnp/{B}x{M}", _time(
-        lambda a, b: spike_scores(a, b, use_kernel=False), W, Bs), ""))
-    rows.append((f"kernel/welford_ref_jnp/{B}x{M}", _time(
-        lambda a: welford(a, use_kernel=False), Bs), ""))
     # fused spike+xcorr (single pass over each tile) vs the two dispatches
-    us_sep = _time(lambda a, b, c: (spike_scores(b, c, use_kernel=False),
+    spike = jax.jit(spike_scores_ref)
+    us_sep = _time(lambda a, b, c: (spike(b, c),
                                     lagged_xcorr(a, b, K, use_kernel=False)),
                    L, Mx, Bs)
     us_fused = _time(lambda a, b, c: fused_rca(a, b, c, K, use_kernel=False),
@@ -69,7 +64,7 @@ def kernel_microbench(B: int = 256, M: int = 16, N: int = 512,
         lambda a, b, c: fused_rca(a, b, c, K, use_kernel=True), L, Mx, Bs),
         "interpret-mode (CPU correctness path)"))
     # streaming detect: score + persistence gate + onset, one dispatch over
-    # the (hosts, wn) slab (vs spike dispatch + f64 detect_rows replay)
+    # the (hosts, wn) slab
     H = detect_h
     Wd = jnp.asarray(rng.standard_normal((H, 500)) + 4, jnp.float32)
     Bd = jnp.asarray(rng.standard_normal((H, 2000)) + 4, jnp.float32)

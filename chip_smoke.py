@@ -21,7 +21,7 @@ One chip:
     3 rounds through ``diagnose_sharded``: flagged hosts and onsets must
     equal f64 ``detect_rows`` on the host, and top causes, mitigations
     and quarantine must equal the oracle monitor's
-    (``use_kernels=False, fast_detect=False``);
+    (``use_kernels=False``);
   * kernels — the round's sweep and fused dispatches must lower to
     ``tpu_custom_call`` (compiled Mosaic kernels, not the interpreter).
 
@@ -217,8 +217,7 @@ def argus_phase() -> None:
     want_flagged = set(np.flatnonzero(fire_ref).tolist())
 
     mon = ShardedFleetMonitor(plan, rca_top_k=topk)
-    oracle = ShardedFleetMonitor(plan, use_kernels=False, fast_detect=False,
-                                 rca_top_k=topk)
+    oracle = ShardedFleetMonitor(plan, use_kernels=False, rca_top_k=topk)
     walls = []
     conf_diff = 0.0
     nic_share = []
@@ -272,9 +271,9 @@ def argus_phase() -> None:
     fire_k, onset_k = [], []
     for s in range(plan.n_shards):
         slab, _ = provider(s)
-        f, _, o = detect_ops.detect_hosts_slab(
+        f, _, o = detect_ops.detect_hosts_slab_launch(
             slab[:, li, T - wn - bn:T], wn, bn, cfg.threshold,
-            cfg.persistence, device=mon.devices[s])
+            cfg.persistence, device=mon.devices[s]).collect()
         fire_k.append(f)
         onset_k.append(o)
     check(np.array_equal(np.concatenate(fire_k), fire_ref)

@@ -5,7 +5,8 @@
 where (mu_L, sigma_L) come from a baseline window W_b preceding the
 observation window W.  All functions are numpy (the per-host engine runs on
 the host CPU, exactly as the paper's agent does); the batched fleet-scale
-versions live in :mod:`repro.kernels.spike`.
+versions live in :mod:`repro.kernels.detect` (Layer 2) and
+:mod:`repro.kernels.fused` (Layer 3).
 """
 from __future__ import annotations
 
@@ -441,8 +442,8 @@ def spike_scores_matrix(windows: np.ndarray, baselines: np.ndarray) -> np.ndarra
     """Per-row spike scores for a (M, N) window matrix vs (M, Nb) baselines.
 
     Used by Layer 3 to score every host metric M_i alongside the latency
-    channel.  Vectorized numpy; the Pallas kernel in kernels/spike mirrors
-    this for (hosts x metrics) batches.
+    channel.  Vectorized numpy; the fused Layer-3 kernel (kernels/fused)
+    computes the same scores for (hosts x metrics) batches.
     """
     w = np.asarray(windows, dtype=np.float64)
     b = np.asarray(baselines, dtype=np.float64)

@@ -26,8 +26,8 @@ from repro.core.spans import span
 from repro.kernels.sweep import ops as sweep_ops
 from repro.kernels.sweep.ops import persistence_count  # re-export (tests/API)
 
-__all__ = ["detect_hosts", "detect_hosts_slab", "detect_hosts_slab_launch",
-           "persistence_count"]
+__all__ = ["detect_hosts", "detect_hosts_slab_launch",
+           "detect_window_launch", "persistence_count"]
 
 
 class DetectPending:
@@ -35,14 +35,13 @@ class DetectPending:
     and re-decides the marginal rows through the f64 oracle."""
 
     __slots__ = ("_sweep", "_patch_win", "_patch_base", "_threshold",
-                 "_persistence", "_exact")
+                 "_persistence")
 
     def __init__(self, sweep, patch_win, patch_base, threshold: float,
-                 persistence: float, exact: bool) -> None:
+                 persistence: float) -> None:
         self._sweep = sweep
         self._patch_win, self._patch_base = patch_win, patch_base
         self._threshold, self._persistence = threshold, persistence
-        self._exact = exact
 
     def collect(self, in_flight: int = 1,
                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -50,7 +49,7 @@ class DetectPending:
         :meth:`repro.kernels.sweep.ops.SweepPending.collect`."""
         fire, score, onset, marg = (
             x[:, 0] for x in self._sweep.collect(in_flight))
-        if self._exact and marg.any():
+        if marg.any():
             # guard band hit: re-decide those hosts through the f64 oracle
             # so the fast path cannot split from detect_rows at the
             # threshold
@@ -67,9 +66,8 @@ class DetectPending:
 def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
                         patch_base: np.ndarray, wn: int, bn: int,
                         threshold: float, persistence: float,
-                        use_kernel: bool, exact: bool,
-                        device=None, moments=None, window=None,
-                        tick_end: Optional[int] = None) -> DetectPending:
+                        use_kernel: bool, device=None,
+                        moments=None) -> DetectPending:
     """Single-tick sweep over the (H, bn + wn) trailing slab, launched.
 
     ``patch_win``/``patch_base`` are the caller's original (H, Nw)/(H, Nb)
@@ -81,14 +79,19 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
     state supplies these at O(delta)); marginal rows are still re-decided
     through the f64 oracle from the raw patch, so epsilon-close moments
     cannot move a decision.
-
-    ``window`` (with ``moments``; see :func:`detect_hosts_slab_launch`)
-    takes ``tail32`` unstaged: the device window stages only what it
-    puts.
     """
     H, T = tail32.shape
     if moments is not None:
         mu, sd = (np.asarray(m, np.float64).reshape(H) for m in moments)
+        # with moments supplied the sweep never touches the baseline
+        # columns — dispatch on the window slice only, so the staged
+        # copy and the kernel's slab stay O(wn) instead of O(wn + bn)
+        # (onsets are window-relative either way; verified equivalent
+        # for both kernel and reference dispatch)
+        with span("detect.stage") as sp:
+            disp = np.ascontiguousarray(tail32[:, bn:])
+            sp.set_metadata(bytes=disp.nbytes)
+        bn_d, ticks = 0, np.array([wn], np.int64)
     else:
         # detect_rows' f64 moments, bit for bit: accumulating the f32 rows
         # in f64 (dtype=) adds each exactly-representable element in the
@@ -97,33 +100,17 @@ def _detect_tail_launch(tail32: np.ndarray, patch_win: np.ndarray,
         sd = np.maximum(patch_base.std(axis=1, dtype=np.float64),
                         np.maximum(spike_mod.SIGMA_FLOOR_ABS,
                                    spike_mod.SIGMA_FLOOR_REL * np.abs(mu)))
-    if moments is not None:
-        # with moments supplied the sweep never touches the baseline
-        # columns — dispatch on the window slice only, so the staged
-        # copy and the kernel's slab stay O(wn) instead of O(wn + bn)
-        # (onsets are window-relative either way; verified equivalent
-        # for both kernel and reference dispatch)
-        disp, bn_d = tail32[:, bn:], 0
-        if window is None:
-            with span("detect.stage") as sp:
-                disp = np.ascontiguousarray(disp)
-                sp.set_metadata(bytes=disp.nbytes)
-        ticks = np.array([wn], np.int64)
-    else:
-        disp, bn_d = tail32, bn
-        ticks = np.array([T], np.int64)
+        disp, bn_d, ticks = tail32, bn, np.array([T], np.int64)
     sweep = sweep_ops.sweep_launch(
         disp, wn, bn_d, ticks, threshold, persistence,
         moments=(mu[:, None], sd[:, None]), argmax_fallback=True,
-        use_kernel=use_kernel, device=device, window=window,
-        tick_end=tick_end)
+        use_kernel=use_kernel, device=device)
     return DetectPending(sweep, patch_win, patch_base, threshold,
-                         persistence, exact)
+                         persistence)
 
 
 def detect_hosts(windows, baselines, threshold: float = 3.0,
                  persistence: float = 0.0, use_kernel: bool = True,
-                 exact: bool = True,
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Batched Layer-2 decision per host row, one dispatch.
 
@@ -131,9 +118,9 @@ def detect_hosts(windows, baselines, threshold: float = 3.0,
     numpy arrays of length H: fire is the full scalar :func:`spike.detect`
     rule (max-z above threshold AND >= ``persistence`` of the window hot),
     onset the first above-threshold sample with arg-max z fallback —
-    exactly :func:`repro.core.spike.detect_rows` (``exact=True`` makes the
-    agreement byte-exact via the marginality guard), without the
-    intermediate (H, Nw) z materialization in host memory.
+    exactly :func:`repro.core.spike.detect_rows` (byte-exact via the
+    marginality guard), without the intermediate (H, Nw) z
+    materialization in host memory.
     """
     windows = np.asarray(windows)
     baselines = np.asarray(baselines)
@@ -146,30 +133,15 @@ def detect_hosts(windows, baselines, threshold: float = 3.0,
                              np.asarray(windows, np.float32)], axis=1)
     return _detect_tail_launch(
         tail32, windows, baselines, wn, bn, float(threshold),
-        float(persistence), bool(use_kernel), bool(exact)).collect()
-
-
-def detect_hosts_slab(tail, wn: int, bn: int, threshold: float = 3.0,
-                      persistence: float = 0.0, use_kernel: bool = True,
-                      exact: bool = True,
-                      valid: Optional[np.ndarray] = None,
-                      force_oracle: bool = False, device=None,
-                      moments=None,
-                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """:func:`detect_hosts_slab_launch` and its ``collect()`` back to
-    back: ``(fire, score, onset)`` per host."""
-    return detect_hosts_slab_launch(
-        tail, wn, bn, threshold, persistence, use_kernel, exact, valid,
-        force_oracle, device, moments).collect()
+        float(persistence), bool(use_kernel)).collect()
 
 
 def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
                              persistence: float = 0.0,
-                             use_kernel: bool = True, exact: bool = True,
+                             use_kernel: bool = True,
                              valid: Optional[np.ndarray] = None,
                              force_oracle: bool = False, device=None,
-                             moments=None, window=None,
-                             tick_end: Optional[int] = None):
+                             moments=None):
     """:func:`detect_hosts` over a trailing latency slab, launched: the
     fast path returns a :class:`DetectPending` whose ``collect()`` gives
     ``(fire, score, onset)`` per host; the oracle path returns its
@@ -207,15 +179,6 @@ def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
     :class:`repro.core.rolling.IncrementalMoments`); ignored on the
     masked/forced oracle path, which always derives exact masked moments
     itself.
-
-    ``window`` (a :class:`repro.kernels.sweep.ops.DeviceWindow`, with
-    ``moments`` and the exclusive absolute ``tick_end`` of the tail's
-    last column) keeps the tail's last ``wn`` columns on ``device``
-    between calls: the clean fast path then stages and puts only the
-    columns that slid in since the window's last sweep, and the tail
-    itself is never copied — the re-decision upcasts its marginal rows
-    from the caller's views.  The window trusts that a tick once seen
-    does not change, as the incremental moments do.
     """
     tail = np.asarray(tail)
     if tail.ndim != 2 or tail.shape[-1] != wn + bn:
@@ -236,19 +199,39 @@ def detect_hosts_slab_launch(tail, wn: int, bn: int, threshold: float = 3.0,
             float(threshold), float(persistence))
         return sweep_ops.Resolved(
             (fire.astype(bool), score, onset.astype(np.intp)))
-    if window is None or moments is None:
-        window = None
-        with span("detect.stage") as sp:
-            tail32 = np.ascontiguousarray(tail, np.float32)
-            sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
-        # the exact re-decision must see the caller's values, not the f32
-        # staging — only a genuinely-f32 tail may reuse the staged copy
-        patch = tail32 if tail.dtype == np.float32 else tail
-    else:
-        # the device window stages only the columns it puts
-        tail32 = patch = tail
+    with span("detect.stage") as sp:
+        tail32 = np.ascontiguousarray(tail, np.float32)
+        sp.set_metadata(bytes=0 if tail32 is tail else tail32.nbytes)
+    # the exact re-decision must see the caller's values, not the f32
+    # staging — only a genuinely-f32 tail may reuse the staged copy
+    patch = tail32 if tail.dtype == np.float32 else tail
     return _detect_tail_launch(
         tail32, patch[:, bn:], patch[:, :bn], int(wn), int(bn),
         float(threshold), float(persistence), bool(use_kernel),
-        bool(exact), device=device, moments=moments, window=window,
-        tick_end=tick_end)
+        device=device, moments=moments)
+
+
+def detect_window_launch(window: sweep_ops.DeviceWindow, tail, wn: int,
+                         bn: int, tick_end: int, moments,
+                         threshold: float, persistence: float,
+                         use_kernel: bool, device) -> DetectPending:
+    """:func:`detect_hosts_slab_launch` of a clean trailing slab with
+    carried ``moments``, through a device ``window``
+    (:func:`repro.kernels.sweep.ops.window_launch`): the tail's last
+    ``wn`` columns, which end before absolute tick ``tick_end``, stay on
+    ``device`` between calls, so only the columns that slid in since the
+    window's last sweep are staged and put.  The tail itself is never
+    copied — the re-decision upcasts its marginal rows from the caller's
+    views.  The window trusts that a tick once seen does not change, as
+    the incremental moments do.
+    """
+    tail = np.asarray(tail)
+    if tail.ndim != 2 or tail.shape[-1] != wn + bn:
+        raise ValueError(f"tail {tail.shape} vs bn+wn={bn + wn}")
+    H = tail.shape[0]
+    mu, sd = (np.asarray(m, np.float64).reshape(H, 1) for m in moments)
+    sweep = sweep_ops.window_launch(
+        window, tail[:, bn:], tick_end, mu, sd, float(threshold),
+        float(persistence), True, bool(use_kernel), device)
+    return DetectPending(sweep, tail[:, bn:], tail[:, :bn],
+                         float(threshold), float(persistence))

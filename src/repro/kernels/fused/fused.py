@@ -1,10 +1,10 @@
 """Pallas TPU kernel: fused baseline stats + max-z spike score + lagged
 cross-correlation.
 
-The seed pipeline dispatched :mod:`repro.kernels.spike` and
-:mod:`repro.kernels.xcorr` separately, so every (host, metric) telemetry
-window crossed HBM twice.  Here one grid cell handles (1 row, block_m
-metrics) and computes, from a single VMEM-resident read of the tile:
+Scoring spikes and running :mod:`repro.kernels.xcorr` as two dispatches
+would move every (host, metric) telemetry window across HBM twice.  Here
+one grid cell handles (1 row, block_m metrics) and computes, from a
+single VMEM-resident read of the tile:
 
   * baseline mean/std (VPU row reductions, sigma floor as in core.spike),
   * the window max-z spike score S_i,

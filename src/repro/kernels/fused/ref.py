@@ -1,8 +1,9 @@
 """Pure-jnp oracle for the fused spike+xcorr kernel.
 
-Composes the two single-purpose oracles — proving the fusion changes data
-movement, not math.  ``fused_rca_masked_ref`` is the ragged-row variant
-(per-row valid lengths) behind the event-batched Layer-3 path.
+Composes a batched spike-score oracle with the xcorr oracle — proving the
+fusion changes data movement, not math.  ``fused_rca_masked_ref`` is the
+ragged-row variant (per-row valid lengths) behind the event-batched
+Layer-3 path.
 """
 from __future__ import annotations
 
@@ -12,10 +13,23 @@ import jax.numpy as jnp
 from repro.core.spike import (
     MASK_NEG as NEG, SIGMA_FLOOR_ABS, SIGMA_FLOOR_REL,
 )
-from repro.kernels.spike.ref import spike_scores_ref
 from repro.kernels.xcorr.ref import lagged_xcorr_ref
 
 _EPS = 1e-12
+
+
+def spike_scores_ref(windows: jax.Array, baselines: jax.Array) -> jax.Array:
+    """windows (B, M, N), baselines (B, M, Nb) -> scores (B, M) f32.
+
+    S = max_t (w(t) - mu_b) / max(sigma_b, floor)   (one-sided rise).
+    """
+    w = windows.astype(jnp.float32)
+    b = baselines.astype(jnp.float32)
+    mu = b.mean(axis=-1)
+    sd = b.std(axis=-1)
+    floor = jnp.maximum(SIGMA_FLOOR_ABS, SIGMA_FLOOR_REL * jnp.abs(mu))
+    sd = jnp.maximum(sd, floor)
+    return ((w - mu[..., None]) / sd[..., None]).max(axis=-1)
 
 
 def fused_rca_ref(latency: jax.Array, metrics: jax.Array,

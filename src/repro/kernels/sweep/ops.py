@@ -373,7 +373,7 @@ PUT_FULL, PUT_DELTA = 0, 1
 class DeviceWindow:
     """One slab's (rows, wn) f32 latency window as last swept, held on
     its device between rounds, with the exclusive absolute tick its last
-    column ends before.  :func:`sweep_launch` advances it by the round's
+    column ends before.  :func:`window_launch` advances it by the round's
     new columns only; its :class:`DeviceWindows` keeps it."""
 
     __slots__ = ("owner", "x", "tick_end", "device", "advances")
@@ -456,6 +456,18 @@ class DeviceWindows:
                 "window_parity_failures": self.parity_failures}
 
 
+def _static(wn: int, threshold: float, persistence: float, eps: float,
+            argmax_fallback: bool, use_kernel: bool, device,
+            block_t: Optional[int]) -> Dict[str, object]:
+    """The static arguments of the sweep dispatches."""
+    return dict(wn=wn, threshold=float(threshold),
+                min_hot=int(persistence_count(wn, persistence)),
+                eps=float(eps), argmax_fallback=bool(argmax_fallback),
+                use_kernel=bool(use_kernel),
+                interpret=bool(use_kernel) and interpret_mode(device),
+                block_t=tuning.sweep_block_t(block_t))
+
+
 def _launch(rows: int, h2d: int, put: int, device, put_args,
             dispatch) -> Tuple[tuple, int]:
     """The launch half of the ``detect.sweep`` span, for both launches:
@@ -480,12 +492,21 @@ def _launch(rows: int, h2d: int, put: int, device, put_args,
     return out, chip
 
 
-def _window_launch(window: DeviceWindow, lat: np.ndarray, tick_end: int,
-                   mu, sd, device, static: Dict[str, object]) -> SweepPending:
-    """:func:`sweep_launch` of a device window: stage and put only the
-    columns that slid in, with mu and sd, as one array (or the whole
-    window), then slide and sweep on the device in one dispatch."""
+def window_launch(window: DeviceWindow, lat: np.ndarray, tick_end: int,
+                  mu: np.ndarray, sd: np.ndarray, threshold: float,
+                  persistence: float, argmax_fallback: bool,
+                  use_kernel: bool, device,
+                  eps: float = SWEEP_GUARD_EPS) -> SweepPending:
+    """:func:`sweep_launch` of the (rows, wn) window ``lat`` — any dtype,
+    ending before absolute tick ``tick_end``, swept at one tick ``wn``
+    with the (rows, 1) moments ``mu``, ``sd`` — through ``window``, held
+    on ``device``: stage and put only the columns that slid in since its
+    last sweep, with mu and sd, as one array (or the whole window; ``put``
+    on the ``detect.sweep`` span), then slide and sweep on the device in
+    one dispatch."""
     R, wn = lat.shape
+    static = _static(wn, threshold, persistence, eps, argmax_fallback,
+                     use_kernel, device, None)
     put, d, proof = window.plan(R, wn, tick_end, device)
     with span("detect.stage") as sp:
         if put == PUT_DELTA:
@@ -589,20 +610,14 @@ def sweep_launch(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                  eps: float = SWEEP_GUARD_EPS, use_kernel: bool = False,
                  block_t: Optional[int] = None,
                  valid: Optional[np.ndarray] = None,
-                 device=None, window: Optional[DeviceWindow] = None,
-                 tick_end: Optional[int] = None):
+                 device=None):
     """The first half of :func:`sweep_rows` (same arguments): f32
     staging, the host->device put, the asynchronous dispatch and the
     start of the results' copies back to the host.  Returns
     a :class:`SweepPending` whose ``collect()`` pulls the results, so the
     host can prepare and launch other sweeps — on other devices — while
-    this one runs.
-
-    ``window`` sweeps ``lat`` — the (rows, wn) window ending before
-    absolute tick ``tick_end``, any dtype, bn 0 and one tick at wn, with
-    ``moments`` — through a :class:`DeviceWindow` held on ``device``:
-    only the columns that slid in since its last sweep are staged and
-    put (``put`` on the ``detect.sweep`` span)."""
+    this one runs.  A window held on its device between sweeps goes
+    through :func:`window_launch` instead."""
     lat = np.asarray(lat)
     if lat.ndim != 2:
         raise ValueError(f"lat must be (rows, T), got {lat.shape}")
@@ -641,17 +656,8 @@ def sweep_launch(lat: np.ndarray, wn: int, bn: int, ticks: np.ndarray,
                 None if valid_n is None else vn)
             moments = (mm, ss)
     mu, sd = moments
-    min_hot = persistence_count(wn, persistence)
-    static = dict(wn=wn, threshold=float(threshold), min_hot=int(min_hot),
-                  eps=float(eps), argmax_fallback=bool(argmax_fallback),
-                  use_kernel=bool(use_kernel),
-                  interpret=bool(use_kernel) and interpret_mode(device),
-                  block_t=tuning.sweep_block_t(block_t))
-    if window is not None:
-        if bn or T != wn or vmask is not None or valid_n is not None:
-            raise ValueError("a device window sweeps one (rows, wn) "
-                             "window at tick wn, unmasked")
-        return _window_launch(window, lat, tick_end, mu, sd, device, static)
+    static = _static(wn, threshold, persistence, eps, argmax_fallback,
+                     use_kernel, device, block_t)
     lat32 = np.ascontiguousarray(lat, np.float32)
     if vmask is not None:
         lat32 = np.where(vmask, lat32, np.float32(spike_mod.MASK_NEG))

@@ -46,7 +46,7 @@ def _env_int(name: str, default: int, minimum: int = 1) -> int:
 
 
 def block_m(override: int | None = None) -> int:
-    """Metric-block rows for the fused/xcorr/spike kernels."""
+    """Metric-block rows for the fused and xcorr kernels."""
     if override is not None:
         return int(override)
     return _env_int("REPRO_BLOCK_M", DEFAULT_BLOCK_M)

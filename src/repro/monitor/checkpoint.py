@@ -207,7 +207,7 @@ class MonitorSession:
                         valid: Optional[np.ndarray], new_lo: int) -> None:
         """Merge each host's newly-seen columns into its running moments.
 
-        Chunk-merge Welford (the welford kernel's combine rule) over the
+        Chunk-merge Welford (Chan et al.'s parallel combine rule) over the
         same chunk sequence is deterministic, so an uninterrupted run and
         a restore+replay — which see identical chunk boundaries — converge
         to bit-identical moments.  Invalid cells are excluded per channel.

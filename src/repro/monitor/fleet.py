@@ -15,19 +15,21 @@ vectorized over the host axis — the seed fell back to a per-host scalar
 the per-node scaling wall at fleet size.  Verdicts map to mitigation hints
 consumed by the training loop (fault tolerance wiring).
 
-The columnar fast path (default, ``fast_detect=True``) keeps the pipeline
-f32-contiguous from the telemetry ring to the verdict: Layer 2 is ONE
-streaming-detect dispatch (kernels.detect — since PR 5 a single-tick view
-of the suite-scale sweep core in kernels.sweep, so the fleet and the eval
-share one sweep implementation) and the Layer-3 evidence gather stays f32
-into the fused kernel.  ``fast_detect=False`` keeps the seed path — a
-spike-kernel dispatch, then an f64 re-slice + scalar-rule ``detect_rows``
-replay over the candidates, and an f64 evidence gather — as the parity
-oracle: flagged hosts and onsets match the fast path byte-exactly *by
-construction* (the sweep core's epsilon guard re-decides any host whose
-window holds a z within the guard band of the threshold through the f64
-oracle; the persistence gate compares an integer count), asserted by
-tests and recorded in BENCH_fleet.json.
+Layer 2 keeps the pipeline f32-contiguous from the telemetry ring to the
+verdict: ONE streaming-detect dispatch (kernels.detect — a single-tick
+view of the suite-scale sweep core in kernels.sweep, so the fleet and the
+eval share one sweep implementation) yields every host's score, gate and
+onset.  On a clean, on-grid round the baseline moments are carried
+(core.rolling) and the detect window stays on its device, so a round puts
+only the ticks that slid in (``detect_window_launch``); any other round
+sweeps the trailing slab from scratch (``detect_hosts_slab_launch``), and
+a round with invalid latency cells takes that call's masked f64 oracle.
+Flagged hosts and onsets are byte-exact against f64
+:func:`repro.core.spike.detect_rows` *by construction*: the sweep core's
+epsilon guard re-decides any host whose window holds a z within the guard
+band of the threshold through the f64 oracle, and the persistence gate
+compares an integer count.  The Layer-3 evidence gather stays f32 into
+the fused kernel.
 
 ``stage_seconds`` reports *disjoint* pipeline stages (detect / gather /
 kernel / rank / assemble); they cover most of a round but not all of it
@@ -53,13 +55,10 @@ from repro.core.engine import (
 from repro.core.reconcile import CO_GAP, symptom_table
 from repro.core.spans import span
 from repro.core.spans import stage as stage_span
-from repro.core.spike import detect_rows
 from repro.core.taxonomy import CauseClass, Diagnosis, SpikeEvent
 from repro.kernels.detect import ops as detect_ops
 from repro.kernels.fused import ops as fused_ops
-from repro.kernels.spike import ops as spike_ops
-from repro.kernels.sweep.ops import DeviceWindows, Resolved
-from repro.kernels.xcorr import ops as xcorr_ops
+from repro.kernels.sweep.ops import DeviceWindows
 
 
 class Mitigation(str, enum.Enum):
@@ -131,7 +130,6 @@ class FleetMonitor:
     def __init__(self, config: Optional[EngineConfig] = None,
                  use_kernels: bool = True,
                  persistent_threshold: int = 3,
-                 fast_detect: bool = True,
                  quarantine_enter_frac: float = 0.25,
                  quarantine_exit_frac: float = 0.05,
                  quarantine_enter_rounds: int = 2,
@@ -155,11 +153,8 @@ class FleetMonitor:
         #: This is also the fleet-level contract the sharded monitor's
         #: rack->fleet candidate tree bounds its cross-shard traffic with.
         self.rca_top_k = None if rca_top_k is None else int(rca_top_k)
-        #: columnar fast path: one streaming-detect dispatch + f32 gather;
-        #: False = seed spike-dispatch + f64 detect_rows replay (oracle)
-        self.fast_detect = fast_detect
         # incremental O(delta) streaming moments (core/rolling.py): the
-        # fast path's baseline moments come from persistent per-(host,
+        # detect's baseline moments come from persistent per-(host,
         # block) state instead of an O(rows * bn) direct pass each round.
         # Only engaged on clean on-grid rounds; masked/chaos rounds,
         # reset_host, and checkpoint restore cold-invalidate the affected
@@ -169,7 +164,7 @@ class FleetMonitor:
         # the direct per-round moment pass (the PR 9 behaviour) — the
         # bench's cold baseline.
         self._inc = (rolling.IncrementalMoments(cap_ticks=self.cfg.baseline_n)
-                     if (fast_detect and incremental) else None)
+                     if incremental else None)
         # the rounds that use those moments also keep each slab's detect
         # window on its device and put only the ticks that slid in; what
         # drops moment rows drops the windows holding them
@@ -205,27 +200,6 @@ class FleetMonitor:
         self._degraded = False
         self.shed_rounds = 0       # rounds executed in detect-only mode
         self.deferred_rca = 0      # flagged hosts whose RCA was deferred
-
-    # ------------------------------------------------------------- batched L2
-    def host_spike_scores(self, latency_windows: np.ndarray,
-                          latency_baselines: np.ndarray) -> np.ndarray:
-        """(hosts,) spike scores of each host's latency series.
-
-        latency_windows (hosts, N), baselines (hosts, Nb) — kernel path is
-        the batched spike kernel with M=1.
-        """
-        w = np.asarray(latency_windows, np.float32)[:, None, :]
-        b = np.asarray(latency_baselines, np.float32)[:, None, :]
-        s = spike_ops.spike_scores(w, b, use_kernel=self.use_kernels)
-        return np.asarray(s)[:, 0]
-
-    def batched_correlations(self, latency_windows: np.ndarray,
-                             metric_windows: np.ndarray) -> np.ndarray:
-        """rho (hosts, metrics, 2K+1) via the Pallas xcorr kernel."""
-        return np.asarray(xcorr_ops.lagged_xcorr(
-            np.asarray(latency_windows, np.float32),
-            np.asarray(metric_windows, np.float32),
-            max_lag=self.cfg.max_lag, use_kernel=self.use_kernels))
 
     # ----------------------------------------------------------- quarantine
     def _update_quarantine(self, bad_frac: np.ndarray,
@@ -421,10 +395,10 @@ class FleetMonitor:
         the overflow is deferred the same way.
 
         The round is assembled from overridable stages —
-        :meth:`_detect_round` (Layer 2 + quarantine over the latency
-        tail), an evidence-gather callback, and :meth:`_finish_round`
-        (flag ordering, strike/mitigation lifecycle, Layer-3 RCA, budget
-        hysteresis) — so the sharded monitor
+        :meth:`_detect_launch` and :meth:`_detect_round` (Layer 2 +
+        quarantine over the latency tail), an evidence-gather callback,
+        and :meth:`_finish_round` (flag ordering, strike/mitigation
+        lifecycle, Layer-3 RCA, budget hysteresis) — so the sharded monitor
         (:class:`repro.monitor.shard.ShardedFleetMonitor`) can run
         detection and evidence extraction per shard while reusing the
         exact fleet-level verdict logic, keeping the two byte-identical
@@ -449,8 +423,10 @@ class FleetMonitor:
             tick_end = self._tick_end(ts, T)
             stage: Dict[str, float] = {}
             with stage_span(stage, "detect", "monitor.detect", rows=hosts):
-                scores, cand, onset_rel, qhosts = self._detect_round(
+                quar, pending = self._detect_launch(
                     host_data, vfull, li, T, wn, bn, tick_end=tick_end)
+                scores, cand, onset_rel, qhosts = self._detect_round(
+                    quar, pending)
 
             def evidence_for(geom: "EvidenceGeometry",
                              rca_hosts: np.ndarray) -> np.ndarray:
@@ -511,44 +487,6 @@ class FleetMonitor:
             return None
         return {**self._inc.stats(), **self._windows.stats()}
 
-    def _detect_round(self, host_data: np.ndarray,
-                      vfull: Optional[np.ndarray], li: int,
-                      T: int, wn: int, bn: int,
-                      force_oracle: bool = False, device=None,
-                      base: int = 0,
-                      quar: Optional[np.ndarray] = None,
-                      tick_end: Optional[int] = None,
-                      launched=None, in_flight: int = 1,
-                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                 np.ndarray]:
-        """Layer-2 detection + telemetry quarantine over the latency tail
-        of ``host_data``: :meth:`_detect_launch`, then the half after the
-        sweep — collect, zero the quarantined hosts, flag.
-
-        Returns ``(scores, cand, onset_rel, qhosts)``: per-host spike
-        scores (quarantined hosts zeroed), the unordered flagged host
-        indices, their onsets relative to the detection window, and the
-        hosts quarantined this round — all indexed relative to
-        ``host_data`` (the sharded caller offsets them by its shard
-        base).
-
-        ``launched`` is what :meth:`_detect_launch` returned for this
-        slab, when the caller launched it earlier (the sharded monitor
-        launches the next shards while this one's sweep runs); only the
-        second half then runs, and ``in_flight`` — launched, unfinished
-        detects when its pull begins — is recorded on the pull's span."""
-        if launched is None:
-            launched = self._detect_launch(
-                host_data, vfull, li, T, wn, bn, force_oracle=force_oracle,
-                device=device, base=base, quar=quar, tick_end=tick_end)
-        pending, qhosts = launched
-        fire, scores, onset_all = pending.collect(in_flight)
-        if qhosts.size:
-            fire[qhosts] = False
-            scores[qhosts] = 0.0
-        cand = np.flatnonzero(fire)
-        return scores, cand, onset_all[cand], qhosts
-
     def _detect_launch(self, host_data: np.ndarray,
                        vfull: Optional[np.ndarray], li: int,
                        T: int, wn: int, bn: int,
@@ -556,11 +494,13 @@ class FleetMonitor:
                        base: int = 0,
                        quar: Optional[np.ndarray] = None,
                        tick_end: Optional[int] = None):
-        """The half of :meth:`_detect_round` before the sweep's results
-        come back: the quarantine update, the incremental moments and the
-        detect launch.  Returns ``(pending, qhosts)``, the ``launched``
-        argument of :meth:`_detect_round`; ``pending.collect()`` gives
-        per-host ``(fire, scores, onset)``.
+        """The half of Layer-2 detection before the sweep's results come
+        back: the quarantine update, the incremental moments and the
+        detect launch over the latency tail of ``host_data``.  Returns
+        ``(quar, pending)``, the arguments of :meth:`_detect_round`:
+        the (hosts,) bool mask of hosts quarantined this round, and the
+        launch, whose ``collect()`` gives per-host ``(fire, scores,
+        onset)``.
 
         The shard parameters keep a per-shard invocation byte-identical
         to the corresponding rows of one full-slab call: ``base`` keys
@@ -574,20 +514,21 @@ class FleetMonitor:
         hysteresis twice.
 
         ``tick_end`` (from :meth:`_tick_end`) anchors the incremental
-        moment cache to the absolute tick grid.  On a clean round the
+        moment cache to the absolute tick grid.  This is the one place
+        that picks the device window: on a clean, on-grid round the
         baseline moments come from :class:`~repro.core.rolling.
-        IncrementalMoments` at O(delta); a masked/forced-oracle round
-        routes through the masked f64 oracle instead *and invalidates*
-        the visited rows' incremental state (their slab may carry
-        masked/zeroed cells, so carried blocks are no longer trusted) —
-        which also means an oracle re-visit of a shard never advances
-        the moment state twice.  The clean rounds that take the moments
-        also sweep through the slab's device window (rows ``base ..
-        base + hosts``; :class:`~repro.kernels.sweep.ops.DeviceWindows`),
-        putting only the ticks that slid in; any other launch over those
-        rows drops the window, so the next one starts from a full put."""
+        IncrementalMoments` at O(delta) and the sweep runs through the
+        slab's device window (rows ``base .. base + hosts``;
+        :class:`~repro.kernels.sweep.ops.DeviceWindows`), putting only
+        the ticks that slid in.  Any other round sweeps the tail from
+        scratch — a masked/forced-oracle round through the masked f64
+        oracle — *and invalidates* the visited rows' incremental state
+        and windows (their slab may carry masked/zeroed cells, so carried
+        blocks are no longer trusted), so the next clean round starts
+        from a full put and an oracle re-visit of a shard never advances
+        the moment state twice."""
         hosts = host_data.shape[0]
-        lat = host_data[:, li, :]
+        tail = host_data[:, li, T - wn - bn:T]
         # telemetry quarantine: invalid fraction of the latency channel
         # over the detection tail drives the hysteresis state machine; the
         # update runs every full round (clean rounds advance re-admission)
@@ -601,54 +542,54 @@ class FleetMonitor:
         if quar is None:
             with span("detect.quarantine", hosts=hosts):
                 quar = self._update_quarantine(bad_frac, base=base)
-        qhosts = np.flatnonzero(quar)
         # persistence gate, the scalar spike.detect rule batched over hosts:
         # a host is a straggler only if `persistence` of its window sits
         # above mu + thr*sigma — bare max-z over 500 correlated ambient
         # samples trips routinely.  The gate also yields each survivor's
-        # onset estimate for Layer 3.
-        if self.fast_detect or lvt is not None or force_oracle:
-            # one streaming-detect dispatch over the trailing slab view:
-            # score + gate + onset per host, one host->device copy, no
-            # candidate re-slice.  A masked round routes through this call
-            # on BOTH detect paths — the mask branch IS the f64 oracle, so
-            # fast and oracle stay trivially byte-identical under chaos.
-            moments = window = None
-            if self._inc is not None:
-                if lvt is None and not force_oracle and tick_end is not None:
-                    with span("detect.moments", rows=hosts) as sp:
-                        moments = self._inc.moments(
-                            lat[:, T - wn - bn:T], tick_end, wn, bn,
-                            base=base)
-                        sp.set_metadata(
-                            blocks_computed=self._inc.last_round_computed,
-                            rebuilt_rows=self._inc.last_round_rebuilt_rows)
-                    window = self._windows.get(base, hosts)
-                else:
-                    self.invalidate_rows(np.arange(base, base + hosts))
-            pending = detect_ops.detect_hosts_slab_launch(
-                lat[:, T - wn - bn:T], wn, bn,
-                self.cfg.threshold, self.cfg.persistence,
-                use_kernel=self.use_kernels, valid=lvt,
-                force_oracle=force_oracle, device=device, moments=moments,
-                window=window, tick_end=tick_end)
-            return pending, qhosts
-        scores = self.host_spike_scores(lat[:, T - wn:],
-                                        lat[:, T - wn - bn:T - wn])
+        # onset estimate for Layer 3.  One streaming-detect dispatch over
+        # the trailing slab: score + gate + onset per host.
+        if (self._inc is not None and lvt is None and not force_oracle
+                and tick_end is not None):
+            with span("detect.moments", rows=hosts) as sp:
+                moments = self._inc.moments(tail, tick_end, wn, bn,
+                                            base=base)
+                sp.set_metadata(
+                    blocks_computed=self._inc.last_round_computed,
+                    rebuilt_rows=self._inc.last_round_rebuilt_rows)
+            pending = detect_ops.detect_window_launch(
+                self._windows.get(base, hosts), tail, wn, bn, tick_end,
+                moments, self.cfg.threshold, self.cfg.persistence,
+                self.use_kernels, device)
+            return quar, pending
+        self.invalidate_rows(np.arange(base, base + hosts))
+        pending = detect_ops.detect_hosts_slab_launch(
+            tail, wn, bn, self.cfg.threshold, self.cfg.persistence,
+            use_kernel=self.use_kernels, valid=lvt,
+            force_oracle=force_oracle, device=device)
+        return quar, pending
+
+    def _detect_round(self, quar: np.ndarray, pending, in_flight: int = 1,
+                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                 np.ndarray]:
+        """The half of Layer-2 detection after the sweep: collect the
+        ``pending`` launch of :meth:`_detect_launch`, zero the hosts its
+        ``quar`` mask quarantines, flag.  ``in_flight`` — launched,
+        unfinished detects when its pull begins — is recorded on the
+        pull's span.
+
+        Returns ``(scores, cand, onset_rel, qhosts)``: per-host spike
+        scores (quarantined hosts zeroed), the unordered flagged host
+        indices, their onsets relative to the detection window, and the
+        hosts quarantined this round — all indexed relative to the
+        launched slab (the sharded caller offsets them by its shard
+        base)."""
+        fire, scores, onset_all = pending.collect(in_flight)
+        qhosts = np.flatnonzero(quar)
         if qhosts.size:
-            scores = np.array(scores)   # kernel output may be readonly
+            fire[qhosts] = False
             scores[qhosts] = 0.0
-        cand = np.flatnonzero(scores > self.cfg.threshold)
-        fire = np.zeros(hosts, bool)
-        onset_all = np.full(hosts, -1, np.intp)
-        if cand.size:
-            latc = np.asarray(lat[cand], dtype=np.float64)
-            keep, _, onset = detect_rows(
-                latc[:, T - wn:], latc[:, T - wn - bn:T - wn],
-                self.cfg.threshold, self.cfg.persistence)
-            fire[cand[keep]] = True
-            onset_all[cand[keep]] = onset[keep]
-        return Resolved((fire, scores, onset_all)), qhosts
+        cand = np.flatnonzero(fire)
+        return scores, cand, onset_all[cand], qhosts
 
     def _rca_selection(self, flagged: np.ndarray, onset_rel: np.ndarray,
                        ) -> Tuple[np.ndarray, np.ndarray, List[int]]:
@@ -815,16 +756,14 @@ class FleetMonitor:
         candidates' evidence) across the shard boundary, never the raw
         (hosts, C, T) telemetry.
 
-        The columnar mode gathers straight to f32 (the fused kernel's
-        input dtype) — no f64 round-trip of the evidence slab; the oracle
-        path keeps the seed's f64 gather.  Invalid evidence cells
+        The gather goes straight to f32 (the fused kernel's input dtype)
+        — no f64 round-trip of the evidence slab.  Invalid evidence cells
         (crashed collector, frozen channel) must not skew orientation
         means or correlations: they are NaN'd out, then the last valid
         reading is carried forward — degraded evidence, never fabricated
         spikes."""
-        gather_dtype = np.float32 if self.fast_detect else np.float64
         sel = np.ix_(flagged, geom.rows, geom.cols)
-        X = host_data[sel].astype(gather_dtype)     # (H, 1+M, nb+rn)
+        X = host_data[sel].astype(np.float32)       # (H, 1+M, nb+rn)
         if valid is not None:
             X[~valid[sel]] = np.nan
         return sanitize_mod.forward_fill(X)
@@ -871,9 +810,9 @@ class FleetMonitor:
             # gathered slab: per cause, does some symptom channel show a
             # two-sided raw-z deviation at/above its floor (reconcile's
             # corroboration test, vectorized over hosts)?  Computed in f64
-            # on the raw (unoriented, forward-filled) evidence so the fast
-            # f32 gather and the f64 oracle agree on every verdict-cause
-            # list.
+            # on the raw (unoriented, forward-filled) evidence so the f32
+            # gather gives the cause lists an f64 gather of the same
+            # evidence would.
             sym_ok: Dict[CauseClass, np.ndarray] = {}
             if cfg.max_hypotheses > 1:
                 for cause, chans in symptom_table().items():

@@ -24,8 +24,8 @@ shard results through a two-level rack → fleet candidate tree:
 Byte-exactness is by construction, not by tolerance:
 
   * detection is per-host independent, and the shard dispatch is the
-    same ``detect_hosts_slab`` call the single-slab path makes — a
-    shard's rows see bit-identical inputs;
+    same detect launch the single-slab path makes — a shard's rows see
+    bit-identical inputs;
   * a corrupt cell ANYWHERE routes every shard through the masked f64
     oracle (``force_oracle``), exactly as one full-slab call with any
     invalid cell takes the oracle for every host — the fast/oracle split
@@ -468,28 +468,24 @@ class ShardedFleetMonitor(FleetMonitor):
                 # invalidates rather than advances them, so a shard visited
                 # twice in one round cannot double-advance the moment state
                 with stage_span(stage, "detect", "monitor.detect", rows=b - a):
-                    launched = self._detect_launch(
+                    quar, launched = self._detect_launch(
                         slab, vfull, li, T, wn, bn,
                         force_oracle=force_oracle, device=self.devices[s],
                         base=a, quar=quar_saved[s], tick_end=tick_end)
-                if quar_saved[s] is None:
-                    qmask = np.zeros(b - a, bool)
-                    qmask[launched[1]] = True
-                    quar_saved[s] = qmask
+                quar_saved[s] = quar
                 ran_oracle[s] = force_oracle or saw_invalid[s]
-                pending.append((s, slab, vfull, launched))
+                pending.append((s, slab, vfull, quar, launched))
 
         def finish() -> None:
             """Finish half of the oldest launched shard: collect, local
             RCA selection, evidence gather."""
             n = len(pending)
-            s, slab, vfull, launched = pending.popleft()
+            s, slab, vfull, quar, launched = pending.popleft()
             a, _ = plan.bounds[s]
             with span("shard.finish", shard=s, chip=self.devices[s].id):
                 with stage_span(stage, "detect", "monitor.detect"):
                     scores, cand, onset_rel, qloc = self._detect_round(
-                        slab, vfull, li, *dims, launched=launched,
-                        in_flight=n)
+                        quar, launched, in_flight=n)
                 # local RCA selection mirrors the fleet's (same total order,
                 # same degraded/top-K policy) so every evidence block the
                 # fleet level will need is shipped — see _rca_selection

@@ -230,8 +230,8 @@ def test_detect_hosts_slab_masked_matches_rows_oracle():
     valid = np.ones((H, bn + wn), bool)
     valid[2, bn:] = False
     valid[3, 100:110] = False                       # benign baseline nicks
-    f, s, o = detect_ops.detect_hosts_slab(tail, wn, bn, persistence=0.2,
-                                           valid=valid)
+    f, s, o = detect_ops.detect_hosts_slab_launch(
+        tail, wn, bn, persistence=0.2, valid=valid).collect()
     wf, ws, wo = spike.detect_rows_masked(
         tail[:, bn:].astype(np.float64), tail[:, :bn].astype(np.float64),
         valid[:, bn:], valid[:, :bn], 3.0, 0.2)
@@ -240,9 +240,10 @@ def test_detect_hosts_slab_masked_matches_rows_oracle():
     np.testing.assert_array_equal(o, wo)
     assert f[1] and not f[2]
     # all-true mask: dropped, byte-identical to valid=None
-    a = detect_ops.detect_hosts_slab(tail, wn, bn, persistence=0.2)
-    b = detect_ops.detect_hosts_slab(tail, wn, bn, persistence=0.2,
-                                     valid=np.ones_like(valid))
+    a = detect_ops.detect_hosts_slab_launch(tail, wn, bn,
+                                            persistence=0.2).collect()
+    b = detect_ops.detect_hosts_slab_launch(
+        tail, wn, bn, persistence=0.2, valid=np.ones_like(valid)).collect()
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
 

@@ -6,7 +6,7 @@ import pytest
 
 from repro.core.spike import detect_rows, spike_scores_matrix
 from repro.kernels.detect import detect_hosts, persistence_count
-from repro.monitor.fleet import FleetMonitor
+from repro.monitor import FleetMonitor, ShardedFleetMonitor, ShardPlan
 from repro.sim.scenario import make_trial
 
 
@@ -71,21 +71,40 @@ def _fleet_data(n_hosts, bad_host, cls, seed=0, clip_s=46.0):
     return trials[0].ts[:t_hi], data, trials[0].channels
 
 
-def test_fleet_fast_detect_matches_oracle_path():
-    """Byte-exact flagged/onset parity of the columnar monitor (streaming
-    detect + f32 gather) vs the seed path (spike dispatch + f64 replay)."""
-    ts, data, channels = _fleet_data(6, 2, "nic", seed=40)
-    fast = FleetMonitor(use_kernels=False).diagnose_fleet(ts, data, channels)
-    oracle = FleetMonitor(use_kernels=False, fast_detect=False
-                          ).diagnose_fleet(ts, data, channels)
-    assert fast.flagged_hosts == oracle.flagged_hosts
-    assert fast.straggler_host == oracle.straggler_host
-    for h in fast.flagged_hosts:
-        assert fast.diagnoses[h].event.t_onset \
-            == oracle.diagnoses[h].event.t_onset
-        assert fast.diagnoses[h].top_cause == oracle.diagnoses[h].top_cause
-    np.testing.assert_allclose(fast.per_host_scores, oracle.per_host_scores,
+@pytest.mark.parametrize("cls,layout", [
+    *((c, lay) for c in ("nic", "io", "cpu", "gpu")
+      for lay in ("slab", "sharded")),
+    ("nic", "off_grid")])
+def test_fleet_detect_matches_detect_rows(cls, layout):
+    """The monitor's flagged hosts, onsets and per-host scores against
+    f64 ``detect_rows`` over the same latency tail: on one slab and on a
+    2-shard plan (both through the device window), and off the tick grid
+    (the direct moment pass, no window)."""
+    ts, data, channels = _fleet_data(6, 2, cls, seed=40)
+    if layout == "off_grid":
+        ts = ts + 0.004
+    if layout == "sharded":
+        mon = ShardedFleetMonitor(ShardPlan.from_bounds([(0, 3), (3, 6)]),
+                                  use_kernels=False)
+    else:
+        mon = FleetMonitor(use_kernels=False)
+    fd = mon.diagnose_fleet(ts, data, channels)
+    cfg, T = mon.cfg, ts.size
+    wn = min(cfg.window_n, T // 2)
+    bn = min(cfg.baseline_n, T - wn)
+    li = channels.index(cfg.latency_metric)
+    tail = np.asarray(data[:, li, T - wn - bn:], np.float64)
+    fire, score, onset = detect_rows(tail[:, bn:], tail[:, :bn],
+                                     cfg.threshold, cfg.persistence)
+    assert fire.any()
+    assert sorted(fd.flagged_hosts) == np.flatnonzero(fire).tolist()
+    for h in fd.flagged_hosts:
+        assert fd.diagnoses[h].event.t_onset == ts[T - wn + onset[h]]
+    np.testing.assert_allclose(fd.per_host_scores, score,
                                rtol=1e-4, atol=1e-4)
+    st = mon.incremental_stats()
+    assert st["window_full_puts"] == (0 if layout == "off_grid" else
+                                      2 if layout == "sharded" else 1)
 
 
 def test_single_host_fleet():

@@ -1,8 +1,9 @@
 """Device-resident detect windows (``kernels.sweep.ops.DeviceWindows``):
 a monitor keeps each slab's (rows, wn) latency window on its device and
 puts only the ticks that slid in since the last round.  Every round's
-``(fire, score, onset)`` must equal a from-scratch ``detect_hosts_slab``
-over the same tail with the same moments, bit for bit; everything that
+``(fire, score, onset)`` must equal a from-scratch
+``detect_hosts_slab_launch`` over the same tail with the same moments, bit
+for bit; everything that
 drops moment rows drops the window (a full put follows); the periodic
 proof finds a carried window that disagrees; and a constant slide
 compiles nothing after the first two rounds."""
@@ -38,41 +39,37 @@ def fleet():
 
 @pytest.fixture
 def launches(monkeypatch):
-    """Every fleet detect launch of the round: its tail, moments, window
+    """Every windowed fleet detect launch of the round: its tail, moments
     and, once collected, its ``(fire, score, onset)``."""
-    real, calls = detect_ops.detect_hosts_slab_launch, []
+    real, calls = detect_ops.detect_window_launch, []
 
-    def spy(tail, wn, bn, *a, **k):
-        rec = dict(tail=np.array(tail), wn=wn, bn=bn, k=k)
+    def spy(window, tail, wn, bn, tick_end, moments, *a):
+        rec = dict(tail=np.array(tail), wn=wn, bn=bn, moments=moments)
         calls.append(rec)
-        pending = real(tail, wn, bn, *a, **k)
+        pending = real(window, tail, wn, bn, tick_end, moments, *a)
 
         def collect(*ca, **ck):
             rec["out"] = pending.collect(*ca, **ck)
             return rec["out"]
         return types.SimpleNamespace(collect=collect)
-    monkeypatch.setattr(detect_ops, "detect_hosts_slab_launch", spy)
+    monkeypatch.setattr(detect_ops, "detect_window_launch", spy)
     return calls
 
 
 def _assert_exact(mon, calls):
-    """Each windowed launch equals ``detect_hosts_slab`` over the same
-    tail with the same moments and no window, bit for bit."""
-    n = 0
+    """Each windowed launch equals ``detect_hosts_slab_launch`` over the
+    same tail with the same moments and no window, bit for bit."""
     for c in calls:
-        if c["k"].get("window") is None:
-            continue
         want = detect_hosts_slab_ref(c, mon)
         for got, ref in zip(c["out"], want):
             assert got.dtype == ref.dtype and np.array_equal(got, ref)
-        n += 1
-    return n
+    return len(calls)
 
 
 def detect_hosts_slab_ref(c, mon):
-    return detect_ops.detect_hosts_slab(
+    return detect_ops.detect_hosts_slab_launch(
         c["tail"], c["wn"], c["bn"], mon.cfg.threshold, mon.cfg.persistence,
-        use_kernel=mon.use_kernels, moments=c["k"]["moments"])
+        use_kernel=mon.use_kernels, moments=c["moments"]).collect()
 
 
 def _round(mon, fleet, e, W=2500, valid=None):
@@ -87,7 +84,7 @@ def _puts(mon):
 
 
 @pytest.mark.parametrize("use_kernels", [False, True])
-def test_single_slab_rounds_equal_detect_hosts_slab(fleet, launches,
+def test_single_slab_rounds_equal_detect_hosts_slab_launch(fleet, launches,
                                                     use_kernels):
     mon = FleetMonitor(use_kernels=use_kernels)
     for k in range(26):
@@ -114,18 +111,19 @@ _SHARDED = textwrap.dedent("""
     HOSTS, SHARD, W, STEP, ROUNDS = 64, 16, 2500, 50, 24
     ts, data, channels = _make_fleet(HOSTS, bad_host=5, seed=11,
                                      bad_every=6)
-    real, calls = detect_ops.detect_hosts_slab_launch, []
+    real, calls = detect_ops.detect_window_launch, []
 
-    def spy(tail, wn, bn, *a, **k):
-        rec = dict(tail=np.array(tail), wn=wn, bn=bn, k=k)
+    def spy(window, tail, wn, bn, tick_end, moments, *a):
+        rec = dict(window=window, tail=np.array(tail), wn=wn, bn=bn,
+                   moments=moments)
         calls.append(rec)
-        p = real(tail, wn, bn, *a, **k)
+        p = real(window, tail, wn, bn, tick_end, moments, *a)
 
         def collect(*ca, **ck):
             rec["out"] = p.collect(*ca, **ck)
             return rec["out"]
         return types.SimpleNamespace(collect=collect)
-    detect_ops.detect_hosts_slab_launch = spy
+    detect_ops.detect_window_launch = spy
 
     def run(mon):
         del calls[:]
@@ -143,15 +141,12 @@ _SHARDED = textwrap.dedent("""
         exact = windowed = 0
         chips = set()
         for c in calls:
-            win = c["k"].get("window")
-            if win is None:
-                continue
             windowed += 1
-            chips.add(next(iter(win.x.devices())).id)
-            ref = detect_ops.detect_hosts_slab(
+            chips.add(next(iter(c["window"].x.devices())).id)
+            ref = detect_ops.detect_hosts_slab_launch(
                 c["tail"], c["wn"], c["bn"], mon.cfg.threshold,
                 mon.cfg.persistence, use_kernel=mon.use_kernels,
-                moments=c["k"]["moments"])
+                moments=c["moments"]).collect()
             exact += all(g.dtype == w.dtype and np.array_equal(g, w)
                          for g, w in zip(c["out"], ref))
         st = mon.incremental_stats()
@@ -188,7 +183,7 @@ def sharded_runs(tmp_path_factory):
 
 
 @pytest.mark.parametrize("devices", ["1", "4"])
-def test_sharded_rounds_equal_detect_hosts_slab(sharded_runs, devices):
+def test_sharded_rounds_equal_detect_hosts_slab_launch(sharded_runs, devices):
     run = sharded_runs[devices]
     assert run["windowed"] == run["exact"] == 24 * 4
     assert run["chips"] == list(range(int(devices)))
@@ -357,8 +352,9 @@ def test_constant_slide_compiles_nothing_after_two_rounds(fleet,
     compiles = []
 
     def detect(e):
-        mon._detect_round(data[:, :, e - 2500:e], None, li, 2500, wn, bn,
-                          tick_end=mon._tick_end(ts[e - 2500:e], 2500))
+        mon._detect_round(*mon._detect_launch(
+            data[:, :, e - 2500:e], None, li, 2500, wn, bn,
+            tick_end=mon._tick_end(ts[e - 2500:e], 2500)))
 
     def on_duration(event, duration, **_):
         if event == "/jax/core/compile/backend_compile_duration":

@@ -127,14 +127,12 @@ def test_short_window_returns_quiet_verdict_not_spurious_stragglers(T):
     quiet verdict with the skip marker instead."""
     ts, data, channels, _ = _fleet_data(3, 1, "cpu", seed=250)
     ts, data = ts[:T], data[:, :, :T]
-    for fast in (True, False):
-        fd = FleetMonitor(use_kernels=False,
-                          fast_detect=fast).diagnose_fleet(ts, data, channels)
-        assert fd.flagged_hosts == []
-        assert fd.diagnosis is None
-        assert fd.mitigation == Mitigation.NONE
-        assert np.all(fd.per_host_scores == 0.0)
-        assert "short_baseline_skip" in fd.stage_seconds
+    fd = FleetMonitor(use_kernels=False).diagnose_fleet(ts, data, channels)
+    assert fd.flagged_hosts == []
+    assert fd.diagnosis is None
+    assert fd.mitigation == Mitigation.NONE
+    assert np.all(fd.per_host_scores == 0.0)
+    assert "short_baseline_skip" in fd.stage_seconds
 
 
 def test_short_window_round_clears_strike_history():

@@ -1,12 +1,11 @@
 """Fused spike+xcorr kernel: interpret-mode vs pure-jnp oracle, and
-vs the two single-purpose kernels it replaces."""
+vs the spike-score oracle and the xcorr kernel it replaces."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.fused.ops import fused_rca, fused_rca_max
-from repro.kernels.fused.ref import fused_rca_ref
-from repro.kernels.spike.ops import spike_scores
+from repro.kernels.fused.ref import fused_rca_ref, spike_scores_ref
 from repro.kernels.xcorr.ops import lagged_xcorr
 
 
@@ -37,7 +36,7 @@ def test_fused_equals_separate_kernels():
     Mx = rng.standard_normal((B, M, N)).astype(np.float32)
     Bs = (rng.standard_normal((B, M, Nb)) + 5).astype(np.float32)
     s, rho = fused_rca(jnp.asarray(L), jnp.asarray(Mx), jnp.asarray(Bs), K)
-    s_sep = spike_scores(jnp.asarray(Mx), jnp.asarray(Bs), use_kernel=True)
+    s_sep = spike_scores_ref(jnp.asarray(Mx), jnp.asarray(Bs))
     rho_sep = lagged_xcorr(jnp.asarray(L), jnp.asarray(Mx), K,
                            use_kernel=True)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_sep),

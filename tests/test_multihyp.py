@@ -160,12 +160,21 @@ def test_fleet_multi_cause_verdict_lists():
     assert fd1.causes[1] == [fd1.diagnoses[1].top_cause]
 
 
-def test_fleet_causes_parity_fast_vs_oracle():
-    """The co-cause corroboration runs in f64 on both detect paths, so
-    the fast f32 gather and the f64 oracle agree on every cause list."""
-    ts, slab, channels, _ = _fleet_slab()
-    fa = FleetMonitor(EngineConfig(), fast_detect=True,
-                      use_kernels=False).diagnose_fleet(ts, slab, channels)
-    fb = FleetMonitor(EngineConfig(), fast_detect=False,
-                      use_kernels=False).diagnose_fleet(ts, slab, channels)
-    assert fa.causes == fb.causes
+def test_fleet_causes_f32_gather_matches_f64():
+    """The co-cause corroboration runs in f64 on the gathered evidence,
+    so the monitor's f32 gather gives the cause lists an f64 copy of the
+    same evidence gives."""
+    ts, slab, channels, truth = _fleet_slab()
+    mon = FleetMonitor(EngineConfig(), use_kernels=False)
+    real, seen = mon._rca_from_evidence, []
+
+    def spy(ts_, X, *rest):
+        seen.append((ts_, X, rest))
+        return real(ts_, X, *rest)
+    mon._rca_from_evidence = spy
+    fd = mon.diagnose_fleet(ts, slab, channels)
+    (ts_, X, rest), = seen
+    assert X.dtype == np.float32
+    _, causes = real(ts_, np.asarray(X, np.float64), *rest[:-1], {})
+    assert fd.causes == causes
+    assert set(fd.causes[1]) == {e.kind for e in truth}

@@ -442,18 +442,20 @@ _PLACEMENT = textwrap.dedent("""
     ends = [4350 + STEP * k for k in range(6)]
     CORRUPT, OVER = 5, 3        # late corruption; over budget -> degraded
     events = []                 # ("P", shard) / ("L", chips) / ("F", n)
-    real = sweep_ops.sweep_launch
 
-    def spy(*a, **k):
-        p = real(*a, **k)
-        events.append(("L", sorted({{d.id for x in p._out
-                                    for d in x.devices()}})))
+    def spy(real):
+        def launch(*a, **k):
+            p = real(*a, **k)
+            events.append(("L", sorted({{d.id for x in p._out
+                                        for d in x.devices()}})))
 
-        def collect(in_flight=1):
-            events.append(("F", in_flight))
-            return p.collect(in_flight)
-        return types.SimpleNamespace(collect=collect)
-    sweep_ops.sweep_launch = spy
+            def collect(in_flight=1):
+                events.append(("F", in_flight))
+                return p.collect(in_flight)
+            return types.SimpleNamespace(collect=collect)
+        return launch
+    sweep_ops.sweep_launch = spy(sweep_ops.sweep_launch)
+    sweep_ops.window_launch = spy(sweep_ops.window_launch)
 
     def valid_for(k):
         if k != CORRUPT:

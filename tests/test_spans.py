@@ -78,18 +78,21 @@ def trials():
 def forced_redecide(monkeypatch):
     """Widen the sweep's guard band so that rounds re-decide rows through
     the f64 oracle; records the marginal rows each sweep returned."""
-    real, marginal = sweep_ops.sweep_launch, []
+    marginal = []
 
-    def spy(*a, **k):
-        pending = real(*a, **dict(k, eps=0.5))
-        collect = pending.collect
+    def spy(real):
+        def launch(*a, **k):
+            pending = real(*a, **dict(k, eps=0.5))
+            collect = pending.collect
 
-        def recorded(*ca, **ck):
-            out = collect(*ca, **ck)
-            marginal.append(int(out[3].sum()))
-            return out
-        return types.SimpleNamespace(collect=recorded)
-    monkeypatch.setattr(sweep_ops, "sweep_launch", spy)
+            def recorded(*ca, **ck):
+                out = collect(*ca, **ck)
+                marginal.append(int(out[3].sum()))
+                return out
+            return types.SimpleNamespace(collect=recorded)
+        return launch
+    for name in ("sweep_launch", "window_launch"):
+        monkeypatch.setattr(sweep_ops, name, spy(getattr(sweep_ops, name)))
     return marginal
 
 

@@ -21,10 +21,8 @@ from repro.core.engine import EngineConfig
 from repro.kernels import tuning
 from repro.kernels.fused import ops as fused_ops
 from repro.kernels.fused.fused import fused_rca_masked_pallas, fused_rca_pallas
-from repro.kernels.spike.spike import spike_scores_pallas
 from repro.kernels.sweep import ops as sweep_ops
 from repro.kernels.sweep.sweep import sweep_rows_pallas
-from repro.kernels.welford.welford import welford_pallas
 from repro.kernels.xcorr.xcorr import lagged_xcorr_pallas
 from repro.monitor.fleet import FleetMonitor
 from repro.sim.scenario import make_trial
@@ -124,26 +122,16 @@ def test_fused_ragged_compiles(one_chip, rca_geometry):
                          ((B,), I32)))
 
 
-@pytest.mark.parametrize("kernel", ["xcorr", "spike", "welford"])
+@pytest.mark.parametrize("kernel", ["xcorr"])
 def test_single_purpose_kernels_compile(one_chip, rca_geometry, kernel):
-    """The seed kernels behind ``fast_detect=False`` and core.xcorr."""
+    """The lagged-xcorr kernel behind ``kernels.xcorr.ops``, at the RCA
+    window's widths."""
     cfg = EngineConfig()
     B, M, rn, nb = rca_geometry
-    if kernel == "xcorr":
-        fn = functools.partial(lagged_xcorr_pallas, max_lag=cfg.max_lag,
-                               n_valid=rn, interpret=False)
-        args = (((B, _pad128(rn)), F32), ((B, M, _pad128(rn)), F32))
-    elif kernel == "spike":
-        H = tuning.shard_hosts()
-        fn = functools.partial(spike_scores_pallas, nw_valid=cfg.window_n,
-                               nb_valid=cfg.baseline_n, interpret=False)
-        args = (((H, 1, _pad128(cfg.window_n)), F32),
-                ((H, 1, _pad128(cfg.baseline_n)), F32))
-    else:
-        fn = functools.partial(welford_pallas, n_valid=cfg.baseline_n,
-                               interpret=False)
-        args = (((B, M, _pad128(cfg.baseline_n)), F32),)
-    _compile(fn, _shapes(one_chip, *args))
+    fn = functools.partial(lagged_xcorr_pallas, max_lag=cfg.max_lag,
+                           n_valid=rn, interpret=False)
+    _compile(fn, _shapes(one_chip, ((B, _pad128(rn)), F32),
+                         ((B, M, _pad128(rn)), F32)))
 
 
 _CUSTOM_CALL = re.compile(
