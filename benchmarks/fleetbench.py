@@ -338,9 +338,9 @@ def shard_rows(parity_hosts: int = 96,
                               materialized one at a time from a fixed
                               trial pool, and the rows record what
                               actually crossed the rack->fleet tree
-                              (candidate scalars + pruned evidence
-                              blocks) against the raw-slab
-                              counterfactual.
+                              (candidate scalars + the fetched evidence
+                              blocks of the RCA set) against the
+                              raw-slab counterfactual.
     """
     from repro.kernels import tuning
     from repro.monitor.shard import (
@@ -389,7 +389,10 @@ def shard_rows(parity_hosts: int = 96,
     fdp = shard2.diagnose_sharded(ts, provider, channels)
     ref = FleetMonitor(use_kernels=False).diagnose_fleet(
         ts, clean, channels, valid=pvalid)
-    revisited = len(calls) == plan.n_shards + 2
+    # visits, the re-visits of fast-path shards 0-1, then one evidence
+    # fetch per shard owning an RCA'd host, ascending
+    owners = sorted({plan.shard_of(h) for h in fdp.diagnoses})
+    revisited = calls == list(range(plan.n_shards)) + [0, 1] + owners
     parity = min(parity, float(
         verdict_fingerprint(fdp) == verdict_fingerprint(ref)
         and revisited and covered))
@@ -454,7 +457,7 @@ def shard_rows(parity_hosts: int = 96,
                      "sum of per-shard detect dispatches"))
         rows.append((f"fleet/shard_stage_reduce_s/{tag}",
                      fd.stage_seconds.get("reduce", 0.0),
-                     "rack->fleet candidate merge + evidence pruning"))
+                     "rack->fleet candidate merge (scalars only)"))
         rows.append((f"fleet/shard_xfer_bytes/{tag}",
                      float(tr.total_bytes),
                      f"{tr.n_candidates} candidate records + "

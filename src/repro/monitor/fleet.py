@@ -428,10 +428,10 @@ class FleetMonitor:
                 scores, cand, onset_rel, qhosts = self._detect_round(
                     quar, pending)
 
-            def evidence_for(geom: "EvidenceGeometry",
-                             rca_hosts: np.ndarray) -> np.ndarray:
+            def evidence_for(geom: "EvidenceGeometry", rca_hosts: np.ndarray,
+                             ) -> Tuple[np.ndarray, Dict[str, int]]:
                 return self._gather_evidence(host_data, rca_hosts, geom,
-                                             vfull)
+                                             vfull), {}
 
             with span("monitor.finish", flagged=cand.size):
                 return self._finish_round(ts, channels, li, T, wn, bn,
@@ -599,11 +599,7 @@ class FleetMonitor:
         host-id tie-break).  Applies the degraded-mode strike priority
         (detect-only rounds explain only hosts with strike history) and
         the ``rca_top_k`` storm cap; returns ``(rca_hosts, rca_onsets,
-        deferred)``.  Pure — no monitor state is touched — so the sharded
-        monitor can run the same selection per shard/rack to decide which
-        evidence blocks to ship, guaranteeing every host the fleet level
-        will RCA has its evidence on hand (the fleet's selection over a
-        superset picks a subset of each part's local selection)."""
+        deferred)``.  Pure: no monitor state is touched."""
         rca_hosts, rca_onsets = flagged, onset_rel
         deferred: List[int] = []
         if self._degraded:
@@ -636,9 +632,10 @@ class FleetMonitor:
         deterministic so sharded and single-slab rounds agree), applies
         the degraded-mode and ``rca_top_k`` RCA deferrals, runs batched
         Layer-3 RCA through ``evidence_for`` (a callback returning the
-        gathered evidence slab for exactly the RCA'd hosts, in order —
-        the single-slab path slices ``host_data``, the sharded path
-        reassembles blocks shipped from shards), advances the
+        gathered evidence slab for exactly the RCA'd hosts, in order,
+        and extra metadata for the ``monitor.gather`` span — the
+        single-slab path slices ``host_data``, the sharded path fetches
+        the blocks from the owning shards), advances the
         strike/mitigation lifecycle and the deadline-budget hysteresis,
         and returns the round's :class:`FleetDiagnosis`."""
         # deterministic flag order: score-descending with ascending host id
@@ -660,8 +657,8 @@ class FleetMonitor:
                 if geom is not None:
                     with stage_span(stage, "gather", "monitor.gather",
                                     hosts=rca_hosts.size) as sp:
-                        X = evidence_for(geom, rca_hosts)
-                        sp.set_metadata(bytes=X.nbytes)
+                        X, meta = evidence_for(geom, rca_hosts)
+                        sp.set_metadata(bytes=X.nbytes, **meta)
                     diagnoses, causes = self._rca_from_evidence(
                         ts, X, geom, rca_hosts, (T - wn) + rca_onsets,
                         scores, stage)
@@ -752,9 +749,9 @@ class FleetMonitor:
         Row 0 is the latency channel, rows 1.. the evidence channels, the
         column span ``geom.cols`` the shared baseline + RCA window.  This
         is the per-host-independent half of Layer 3 — the sharded monitor
-        runs it on each shard and ships only these blocks (its top-K
-        candidates' evidence) across the shard boundary, never the raw
-        (hosts, C, T) telemetry.
+        runs it on each shard that owns a host of the fleet's RCA set,
+        for those hosts only, and never moves the raw (hosts, C, T)
+        telemetry.
 
         The gather goes straight to f32 (the fused kernel's input dtype)
         — no f64 round-trip of the evidence slab.  Invalid evidence cells

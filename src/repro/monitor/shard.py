@@ -6,20 +6,21 @@ tens of gigabytes, and one detect dispatch on one device is the scaling
 wall the paper's multi-node extension (§5.1) runs into first.  This
 module splits the fleet into contiguous host shards (:class:`ShardPlan`),
 runs Layer-2 detection per shard through the one-dispatch sweep core
-(whose cost does not scale with the flagged fraction — PR 5), and merges
-shard results through a two-level rack → fleet candidate tree:
+(whose cost does not scale with the flagged fraction), and merges shard
+results through a two-level rack → fleet candidate tree that carries
+scalars only:
 
   shard   detect + quarantine on its own (H_s, C, T) slab, on its own
           mesh device (``parallel.fleet``); ships a
           :class:`ShardCandidates` — flagged host ids, scores, onsets,
-          plus *evidence blocks* for its locally-selected RCA candidates
-          — never the raw slab;
-  rack    merges its member shards' candidate lists and prunes the
-          evidence set to the rack-level RCA selection (same total
-          order);
+          quarantined ids — never the raw slab;
+  rack    merges its member shards' candidate lists;
   fleet   concatenates rack candidates and runs the unchanged
           fleet-level verdict logic (:meth:`FleetMonitor._finish_round`)
-          over them.
+          over them.  Once that logic has picked the round's RCA set, the
+          fleet fetches the evidence blocks of exactly those hosts from
+          their owning shards (one provider call per owning shard), so
+          a storm's flagged-but-deferred hosts cost no gather.
 
 Byte-exactness is by construction, not by tolerance:
 
@@ -31,16 +32,15 @@ Byte-exactness is by construction, not by tolerance:
     invalid cell takes the oracle for every host — the fast/oracle split
     can never follow shard boundaries;
   * candidate ordering is a total order (score descending, host id
-    ascending on ties, ``kind="stable"``), so the fleet-level selection
-    over the merged candidates picks exactly the hosts one full-slab
-    round would, and each is guaranteed to be in its shard's and rack's
-    local selection (a top-K of a superset is a subset of each part's
-    top-K);
-  * the cross-host-coupled half of Layer 3 (the orientation baseline
-    slice depends on the *minimum onset over all RCA'd hosts*) never
-    runs per shard — shards only gather their hosts' evidence blocks
-    (per-host independent), and the fused RCA kernel runs once at fleet
-    level on the assembled blocks.
+    ascending on ties, ``kind="stable"``), and the fleet-level selection
+    runs over every shard's flagged hosts, so it picks exactly the hosts
+    one full-slab round would;
+  * the evidence gather is per-host independent (an f32 cast and a
+    per-row forward fill), so a block fetched from the owning shard's
+    slab equals the single-slab gather's row; the cross-host-coupled
+    half of Layer 3 (the orientation baseline slice depends on the
+    *minimum onset over all RCA'd hosts*) runs once at fleet level on
+    the fetched blocks, in RCA order.
 
 ``verdict_fingerprint`` canonicalizes the deterministic fields of a
 :class:`~repro.monitor.fleet.FleetDiagnosis` (everything except wall-time
@@ -177,9 +177,9 @@ class ShardCandidates:
     """What one shard (or one rack) ships up the aggregation tree.
 
     Scalars for *every* flagged host — ids, scores, onsets are 24 bytes a
-    host, cheap enough to never prune — plus gathered evidence blocks for
-    the locally-selected RCA candidates only (the expensive part:
-    ``(1 + M) * (nb + rn)`` floats each).  Raw telemetry never crosses.
+    host, cheap enough to never prune.  Evidence blocks do not ride the
+    tree: the fleet fetches them for its RCA set once the set is known.
+    Raw telemetry never crosses.
     """
 
     #: absolute flagged host ids, ascending
@@ -190,20 +190,12 @@ class ShardCandidates:
     onset: np.ndarray
     #: absolute ids of hosts quarantined this round
     qhosts: np.ndarray
-    #: abs host id -> (1 + M, nb + rn) gathered evidence block, for the
-    #: local RCA selection only
-    evidence: Dict[int, np.ndarray]
 
     @property
     def scalar_bytes(self) -> int:
         """Wire size of the always-shipped scalar records."""
         return (self.idx.size * _CAND_RECORD_BYTES
                 + self.qhosts.size * 8)
-
-    @property
-    def evidence_bytes(self) -> int:
-        """Wire size of the shipped evidence blocks."""
-        return sum(int(b.nbytes) for b in self.evidence.values())
 
 
 @dataclasses.dataclass
@@ -217,11 +209,11 @@ class ShardTraffic:
 
     #: shard->rack bytes: scalar candidate records
     shard_scalar_bytes: int = 0
-    #: shard->rack bytes: evidence blocks
+    #: shard->rack bytes: the fetched evidence blocks of the RCA set
     shard_evidence_bytes: int = 0
     #: rack->fleet bytes: scalar candidate records (post rack merge)
     rack_scalar_bytes: int = 0
-    #: rack->fleet bytes: evidence blocks (post rack-level pruning)
+    #: rack->fleet bytes: the same fetched blocks, on their second hop
     rack_evidence_bytes: int = 0
     #: per-host scores shipped for the FleetDiagnosis readout (8 B/host)
     score_bytes: int = 0
@@ -229,7 +221,7 @@ class ShardTraffic:
     raw_bytes: int = 0
     #: flagged candidates that crossed shard->rack
     n_candidates: int = 0
-    #: evidence blocks that crossed rack->fleet
+    #: evidence blocks fetched: one per RCA'd host
     n_evidence: int = 0
 
     @property
@@ -314,7 +306,9 @@ class ShardedFleetMonitor(FleetMonitor):
     exists for, use :meth:`diagnose_sharded` instead: a *provider*
     callback materializes one shard's slab at a time, so the full fleet
     slab never exists in memory (64k hosts × 10 channels × 3100 ticks is
-    ~8 GB as one array; one 1024-host shard is ~127 MB).
+    ~8 GB as one array; one 1024-host shard is ~127 MB).  Shards ship
+    scalar candidates up the rack → fleet tree; the fleet then fetches
+    the evidence of its RCA set only, one owning shard's slab at a time.
 
     All verdict state — strikes, quarantine hysteresis, degraded mode —
     lives in the base class keyed by absolute host id, advanced shard by
@@ -390,8 +384,10 @@ class ShardedFleetMonitor(FleetMonitor):
         corruption, shards that already ran the fast path are re-visited
         through the masked f64 oracle (the single-slab masked round takes
         the oracle for *every* host), which calls the provider a second
-        time for those shards.  Clean rounds visit each shard exactly
-        once."""
+        time for those shards.  After the visits, the provider is called
+        once more for each shard that owns a host of the round's RCA set,
+        in ascending shard order, to fetch those hosts' evidence.  A clean
+        round visits each shard exactly once before the fetch."""
         with span("monitor.round", hosts=self.plan.hosts):
             return self._diagnose_shards(ts, provider, channels,
                                          extra_cost_s, any_invalid=None)
@@ -402,12 +398,14 @@ class ShardedFleetMonitor(FleetMonitor):
         """Shared sharded-round core (see class docstring for the tree).
 
         Shards are launched in order — provider, validity, detect launch
-        — and finished oldest first — sweep pull, local RCA selection,
-        evidence gather — with at most one launched, unfinished shard per
-        distinct device in :attr:`devices`: on four chips four sweeps run
-        side by side while the host prepares the next shard, and on one
-        chip each launch is finished before the next (the serial order).
-        Placement and overlap never change a verdict.
+        — and finished oldest first — sweep pull, flagging — with at most
+        one launched, unfinished shard per distinct device in
+        :attr:`devices`: on four chips four sweeps run side by side while
+        the host prepares the next shard, and on one chip each launch is
+        finished before the next (the serial order).  Placement and
+        overlap never change a verdict.  Once the fleet has picked its
+        RCA set, each owning shard is visited once more, in ascending
+        order, for its RCA'd hosts' evidence blocks.
 
         ``any_invalid`` None means "unknown until shards are visited"
         (provider mode, re-visit clean shards if corruption turns up);
@@ -421,25 +419,38 @@ class ShardedFleetMonitor(FleetMonitor):
         saw_invalid = [False] * plan.n_shards
         traffic = ShardTraffic()
         stage: Dict[str, float] = {"detect": 0.0}
-        geom = None
-        dims: Optional[Tuple[int, int, int]] = None  # (C, T) + wn, bn
+        dims: Optional[Tuple[int, int, int]] = None  # (T, wn, bn)
         tick_end: Optional[int] = None  # one grid anchor for every shard
         # launched, unfinished shards, oldest first: at most one per chip
         n_chips = len(set(self.devices))
-        pending: Deque[Tuple[int, np.ndarray, Optional[np.ndarray],
-                             object]] = collections.deque()
+        pending: Deque[Tuple[int, np.ndarray, object]] = collections.deque()
+
+        def load(s: int) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+            """``provider(s)`` checked against the plan: the shard's slab
+            and its mask, None when every cell is valid."""
+            a, b = plan.bounds[s]
+            with span("shard.provider", shard=s):
+                slab, val = provider(s)
+            slab = np.asarray(slab)
+            if slab.ndim != 3 or slab.shape[0] != b - a:
+                raise ValueError(f"shard {s} slab {slab.shape} vs bounds "
+                                 f"({a}, {b})")
+            if dims is not None and slab.shape[2] != dims[0]:
+                raise ValueError(f"shard {s} T={slab.shape[2]} vs {dims[0]}")
+            if val is None:
+                return slab, None
+            v = np.asarray(val, bool)
+            if v.shape != slab.shape:
+                raise ValueError(f"shard {s} valid {v.shape} vs slab "
+                                 f"{slab.shape}")
+            return slab, (None if v.all() else v)
 
         def visit(s: int, force_oracle: bool) -> None:
             """Launch half: provider, validity, detect launch."""
-            nonlocal geom, dims, tick_end
+            nonlocal dims, tick_end
             with span("shard.visit", shard=s, oracle=force_oracle):
                 a, b = plan.bounds[s]
-                with span("shard.provider", shard=s):
-                    slab, val = provider(s)
-                slab = np.asarray(slab)
-                if slab.ndim != 3 or slab.shape[0] != b - a:
-                    raise ValueError(f"shard {s} slab {slab.shape} vs bounds "
-                                     f"({a}, {b})")
+                slab, vfull = load(s)
                 if dims is None:
                     T = slab.shape[2]
                     wn = min(self.cfg.window_n, T // 2)
@@ -447,19 +458,8 @@ class ShardedFleetMonitor(FleetMonitor):
                     if bn < MIN_BASELINE_N:
                         raise _ShortBaseline
                     dims = (T, wn, bn)
-                    geom = self._evidence_geometry(channels, li, T, wn, bn)
                     tick_end = self._tick_end(ts, T)
                 T, wn, bn = dims
-                if slab.shape[2] != T:
-                    raise ValueError(f"shard {s} T={slab.shape[2]} vs {T}")
-                vfull = None
-                if val is not None:
-                    v = np.asarray(val, bool)
-                    if v.shape != slab.shape:
-                        raise ValueError(f"shard {s} valid {v.shape} vs slab "
-                                         f"{slab.shape}")
-                    if not v.all():
-                        vfull = v
                 saw_invalid[s] = (
                     vfull is not None
                     and not vfull[:, li, T - wn - bn:T].all())
@@ -474,34 +474,20 @@ class ShardedFleetMonitor(FleetMonitor):
                         base=a, quar=quar_saved[s], tick_end=tick_end)
                 quar_saved[s] = quar
                 ran_oracle[s] = force_oracle or saw_invalid[s]
-                pending.append((s, slab, vfull, quar, launched))
+                pending.append((s, quar, launched))
 
         def finish() -> None:
-            """Finish half of the oldest launched shard: collect, local
-            RCA selection, evidence gather."""
+            """Finish half of the oldest launched shard: collect, flag."""
             n = len(pending)
-            s, slab, vfull, quar, launched = pending.popleft()
+            s, quar, launched = pending.popleft()
             a, _ = plan.bounds[s]
             with span("shard.finish", shard=s, chip=self.devices[s].id):
                 with stage_span(stage, "detect", "monitor.detect"):
                     scores, cand, onset_rel, qloc = self._detect_round(
                         quar, launched, in_flight=n)
-                # local RCA selection mirrors the fleet's (same total order,
-                # same degraded/top-K policy) so every evidence block the
-                # fleet level will need is shipped — see _rca_selection
-                order = np.argsort(-scores[cand], kind="stable")
-                sel, _, _ = self._rca_selection(
-                    cand[order] + a, onset_rel[order])
-                evidence: Dict[int, np.ndarray] = {}
-                if geom is not None and sel.size:
-                    with stage_span(stage, "gather", "monitor.gather",
-                                    hosts=sel.size) as sp:
-                        X = self._gather_evidence(slab, sel - a, geom, vfull)
-                        sp.set_metadata(bytes=X.nbytes)
-                    evidence = {int(h): X[k] for k, h in enumerate(sel)}
                 per_shard[s] = ShardCandidates(
                     idx=cand + a, score=scores[cand], onset=onset_rel,
-                    qhosts=qloc + a, evidence=evidence)
+                    qhosts=qloc + a)
                 shard_scores[s] = scores
 
         def launch(s: int, force_oracle: bool) -> None:
@@ -531,32 +517,20 @@ class ShardedFleetMonitor(FleetMonitor):
             while pending:
                 finish()
 
-        # rack-level reduce: merge member candidate lists, prune evidence
-        # to the rack's own RCA selection
+        # rack-level reduce: merge member candidate lists (scalars only)
         with stage_span(stage, "reduce", "shard.reduce") as sp:
             rack_cands: List[ShardCandidates] = []
             for rack in plan.racks:
                 members = [per_shard[s] for s in rack]
                 for m in members:
                     traffic.shard_scalar_bytes += m.scalar_bytes
-                    traffic.shard_evidence_bytes += m.evidence_bytes
                     traffic.n_candidates += int(m.idx.size)
-                idx = np.concatenate([m.idx for m in members])
-                score = np.concatenate([m.score for m in members])
-                onset = np.concatenate([m.onset for m in members])
-                qh = np.concatenate([m.qhosts for m in members])
-                order = np.argsort(-score, kind="stable")
-                sel, _, _ = self._rca_selection(idx[order], onset[order])
-                merged_ev: Dict[int, np.ndarray] = {}
-                for m in members:
-                    merged_ev.update(m.evidence)
                 rc = ShardCandidates(
-                    idx=idx, score=score, onset=onset, qhosts=qh,
-                    evidence={int(h): merged_ev[int(h)] for h in sel
-                              if int(h) in merged_ev})
+                    idx=np.concatenate([m.idx for m in members]),
+                    score=np.concatenate([m.score for m in members]),
+                    onset=np.concatenate([m.onset for m in members]),
+                    qhosts=np.concatenate([m.qhosts for m in members]))
                 traffic.rack_scalar_bytes += rc.scalar_bytes
-                traffic.rack_evidence_bytes += rc.evidence_bytes
-                traffic.n_evidence += len(rc.evidence)
                 rack_cands.append(rc)
 
             # fleet level: concatenate rack candidates (shard order keeps
@@ -567,25 +541,32 @@ class ShardedFleetMonitor(FleetMonitor):
             cand = np.concatenate([rc.idx for rc in rack_cands])
             onset_rel = np.concatenate([rc.onset for rc in rack_cands])
             qhosts = np.concatenate([rc.qhosts for rc in rack_cands])
-            blocks: Dict[int, np.ndarray] = {}
-            for rc in rack_cands:
-                blocks.update(rc.evidence)
-            sp.set_metadata(candidates=traffic.n_candidates,
-                            evidence=traffic.n_evidence)
+            sp.set_metadata(candidates=traffic.n_candidates)
         traffic.score_bytes = int(scores.size) * 8
         # counterfactual: what shipping every raw f32 shard slab would cost
         T, wn, bn = dims
         traffic.raw_bytes = plan.hosts * len(channels) * T * 4
         self.last_traffic = traffic
 
-        def evidence_for(geom_, rca_hosts: np.ndarray) -> np.ndarray:
-            missing = [int(h) for h in rca_hosts if int(h) not in blocks]
-            if missing:
-                raise RuntimeError(
-                    f"evidence blocks missing for hosts {missing}: "
-                    "shard/rack selection failed to cover the fleet "
-                    "RCA set (top-K superset invariant violated)")
-            return np.stack([blocks[int(h)] for h in rca_hosts])
+        def evidence_for(geom, rca_hosts: np.ndarray,
+                         ) -> Tuple[np.ndarray, Dict[str, int]]:
+            """The fetch: evidence for exactly the fleet's RCA set, one
+            provider call per owning shard, in ascending shard order."""
+            owner = np.array([plan.shard_of(h) for h in rca_hosts])
+            X = np.empty((rca_hosts.size, geom.rows.size, geom.cols.size),
+                         np.float32)
+            shards = np.unique(owner)
+            for s in shards:
+                k = np.flatnonzero(owner == s)
+                slab, vfull = load(int(s))
+                X[k] = self._gather_evidence(
+                    slab, rca_hosts[k] - plan.bounds[s][0], geom, vfull)
+                del slab, vfull         # one shard slab alive at a time
+            # each block crosses shard -> rack -> fleet
+            traffic.shard_evidence_bytes = int(X.nbytes)
+            traffic.rack_evidence_bytes = int(X.nbytes)
+            traffic.n_evidence = int(rca_hosts.size)
+            return X, {"shards": int(shards.size)}
 
         with span("monitor.finish", flagged=cand.size):
             return self._finish_round(ts, channels, li, T, wn, bn, scores,

@@ -106,8 +106,8 @@ def test_multi_round_strike_escalation_parity():
 
 def test_incident_storm_topk_parity_and_deferral():
     """Storm (every 6th host injected) with a fleet-level RCA cap: the
-    rack tree must ship exactly the evidence the fleet selection needs,
-    and the overflow lands in deferred_hosts on both paths."""
+    fleet fetches exactly the evidence its selection needs, and the
+    overflow lands in deferred_hosts on both paths."""
     ts, data, channels = _make_fleet(48, bad_host=5, seed=11, bad_every=6)
     mono, shard = _pair(rca_top_k=3)
     a = mono.diagnose_fleet(ts, data, channels)
@@ -115,8 +115,93 @@ def test_incident_storm_topk_parity_and_deferral():
     assert len(a.flagged_hosts) > 3
     assert a.deferred_hosts and len(a.diagnoses) <= 3
     assert verdict_fingerprint(a) == verdict_fingerprint(b)
-    # the cap also bounds evidence traffic: at most top-K blocks per rack
-    assert shard.last_traffic.n_evidence <= 3 * shard.plan.n_racks
+    # the cap also bounds evidence traffic: one block per RCA'd host
+    assert shard.last_traffic.n_evidence == len(b.diagnoses)
+
+
+def _rca_spy(mon):
+    """Record the evidence slab and hosts of each Layer-3 dispatch."""
+    seen = []
+    real = mon._rca_from_evidence
+
+    def spy(ts, X, geom, flagged, *a, **k):
+        seen.append((X.copy(), np.array(flagged), geom))
+        return real(ts, X, geom, flagged, *a, **k)
+    mon._rca_from_evidence = spy
+    return seen
+
+
+def test_storm_topk_fetch_exact_over_streaming_rounds():
+    """Storm with ``rca_top_k`` below the flagged count, shard 1 fed
+    shard 0's rows (equal scores, ordered by host id across shards):
+    over streaming rounds the sharded round matches the single slab bit
+    for bit, fetches once per owning shard in ascending order after the
+    visits, one block per RCA'd host, each equal to the whole-slab
+    gather's row."""
+    ts, data, channels = _make_fleet(48, bad_host=5, seed=11, bad_every=6)
+    data = data.copy()
+    data[20:40] = data[0:20]
+    T = data.shape[2]
+    mono, shard = _pair(rca_top_k=5)
+    seen = _rca_spy(shard)
+    spread, tie_cut = False, False
+    for tk in (T - 240, T - 160, T - 80, T):
+        d, calls = data[:, :, :tk], []
+
+        def provider(s):
+            calls.append(s)
+            a, b = shard.plan.bounds[s]
+            return d[a:b], None
+
+        a = mono.diagnose_fleet(ts[:tk], d, channels)
+        b = shard.diagnose_sharded(ts[:tk], provider, channels)
+        assert verdict_fingerprint(a) == verdict_fingerprint(b), tk
+        assert len(b.flagged_hosts) > 5 and len(b.diagnoses) == 5
+        assert shard.last_traffic.n_evidence == len(b.diagnoses)
+        owners = sorted({shard.plan.shard_of(h) for h in b.diagnoses})
+        assert calls == [0, 1, 2] + owners, tk
+        spread |= len(owners) >= 3
+        rca = {float(b.per_host_scores[h]) for h in b.diagnoses}
+        tie_cut |= any(float(b.per_host_scores[h]) in rca
+                       for h in b.deferred_hosts)
+        X, hosts, geom = seen[-1]
+        assert sorted(hosts.tolist()) == sorted(b.diagnoses)
+        whole = shard._gather_evidence(d, hosts, geom)
+        assert X.dtype == np.float32 and X.tobytes() == whole.tobytes()
+    assert spread and tie_cut
+    assert _state_no_plan(shard) == mono.state_dict()
+
+
+def test_masked_round_fetch_uses_the_revisit_mask():
+    """Corruption surfaces on the last shard only, while shard 0 masks
+    evidence cells of its straggler: the fetch reads shard 0's mask
+    (evidence NaN'd and forward-filled) and the round equals the
+    single-slab masked round, blocks included."""
+    ts, data, channels = _make_fleet(48, bad_host=5, seed=31)
+    li = channels.index(LAT)
+    valid = np.ones(data.shape, bool)
+    valid[44, li, -200:] = False           # shard 2 only, below quarantine
+    valid[5, :, -300:-100] = False         # shard 0: the straggler's tail
+    valid[5, li] = True                    # ... latency stays valid
+    mono, shard = _pair()
+    seen_m, seen_s = _rca_spy(mono), _rca_spy(shard)
+    calls = []
+
+    def provider(s):
+        calls.append(s)
+        a, b = shard.plan.bounds[s]
+        return data[a:b], valid[a:b]
+
+    fd = shard.diagnose_sharded(ts, provider, channels)
+    ref = mono.diagnose_fleet(ts, data, channels, valid=valid)
+    assert verdict_fingerprint(fd) == verdict_fingerprint(ref)
+    assert sorted(fd.diagnoses) == [5]
+    assert calls == [0, 1, 2, 0, 1, 0]
+    (Xs, hs, geom), (Xm, hm, _) = seen_s[-1], seen_m[-1]
+    assert hs.tolist() == hm.tolist() == [5]
+    assert Xs.tobytes() == Xm.tobytes()
+    unmasked = shard._gather_evidence(data, hs, geom)
+    assert Xs.tobytes() != unmasked.tobytes()
 
 
 def test_quarantine_entry_parity_under_corruption():
@@ -199,6 +284,8 @@ def test_host_count_mismatch_rejected():
 # ----------------------------------------------------------- provider API
 
 def test_provider_clean_round_visits_each_shard_once():
+    """A clean round visits shards 0-2 once each, then fetches the RCA
+    set's evidence: bad host 5 lives on shard 0, one more call."""
     ts, data, channels = _make_fleet(48, bad_host=5, seed=29)
     mono, shard = _pair()
     plan, calls = shard.plan, []
@@ -208,8 +295,10 @@ def test_provider_clean_round_visits_each_shard_once():
         a, b = plan.bounds[s]
         return data[a:b], None
 
-    fp = verdict_fingerprint(shard.diagnose_sharded(ts, provider, channels))
-    assert calls == [0, 1, 2]
+    fd = shard.diagnose_sharded(ts, provider, channels)
+    assert sorted(fd.diagnoses) == [5]
+    assert calls == [0, 1, 2, 0]
+    fp = verdict_fingerprint(fd)
     assert fp == verdict_fingerprint(mono.diagnose_fleet(ts, data, channels))
 
 
@@ -231,7 +320,9 @@ def test_provider_revisits_fast_path_shards_on_late_corruption():
         return data[a:b], valid[a:b]
 
     fd = shard.diagnose_sharded(ts, provider, channels)
-    assert calls == [0, 1, 2, 0, 1]        # shard 2 already ran the oracle
+    # shard 2 already ran the oracle; then the fetch of host 5's evidence
+    assert sorted(fd.diagnoses) == [5]
+    assert calls == [0, 1, 2, 0, 1, 0]
     ref = mono.diagnose_fleet(ts, data, channels, valid=valid)
     assert verdict_fingerprint(fd) == verdict_fingerprint(ref)
     assert _state_no_plan(shard) == mono.state_dict()
@@ -529,10 +620,14 @@ def test_placement_provider_order_and_oracle_revisit(placement):
     for n in ("1", "4"):
         for k, log in enumerate(placement[n]["log"]):
             calls = [v for e, v in log if e == "P"]
+            rca = json.loads(placement[n]["fps"][k][0])["diagnoses"]
+            owners = sorted({int(h) // 32 for h in rca})
+            assert owners, (n, k)
             # round 5: shard 7 sees the corruption and runs the oracle,
-            # shards 0-6 are re-visited through it once all have finished
+            # shards 0-6 are re-visited through it once all have finished;
+            # then one fetch per shard owning an RCA'd host, ascending
             assert calls == list(range(8)) + (list(range(7)) if k == 5
-                                              else []), (n, k)
+                                              else []) + owners, (n, k)
 
 
 def test_placement_sweeps_on_their_chips_four_in_flight(placement):

@@ -3,6 +3,8 @@
 spans' metadata, no span inside a per-host loop, ``stage_seconds`` keys,
 and the self-time arithmetic of the benchmark's span reader
 (``bench/metrics/_spans.py``)."""
+import importlib.util
+import os
 import types
 from collections import Counter
 
@@ -31,7 +33,7 @@ PARENTS = {
     "monitor.round": {None, "aggregator.diagnose"},
     "monitor.validity": {"monitor.round"},
     "shard.visit": {"monitor.round"},
-    "shard.provider": {"shard.visit"},
+    "shard.provider": {"shard.visit", "monitor.gather"},
     "shard.finish": {"monitor.round"},
     "monitor.detect": {"monitor.round", "shard.visit", "shard.finish"},
     "detect.quarantine": {"monitor.detect"},
@@ -42,7 +44,7 @@ PARENTS = {
     "sweep.dispatch": {"detect.sweep"},
     "sweep.pull": {"detect.sweep"},
     "detect.redecide": {"monitor.detect"},
-    "monitor.gather": {"shard.finish", "monitor.finish"},
+    "monitor.gather": {"monitor.finish"},
     "shard.reduce": {"monitor.round"},
     "monitor.finish": {"monitor.round"},
     "finish.lifecycle": {"monitor.finish"},
@@ -181,8 +183,18 @@ def test_span_tree_and_counts(tmp_path, trials, forced_redecide, path):
     pull = st["sweep.pull"]
     assert pull.meta["in_flight"] == pull.n > 0
     assert pull.meta["chip"] == st["detect.sweep"].meta["chip"] == 0
+    # one gather a round, of exactly the RCA'd hosts
+    gather = st["monitor.gather"]
+    assert gather.n == 3
+    assert gather.meta["hosts"] == sum(len(fd.diagnoses) for fd in rounds.out)
     if path == "sharded":
         assert st["shard.finish"].n == st["shard.visit"].n == 2 * 3
+        # the fetch: one provider call per shard owning an RCA'd host
+        owners = sum(len({h // 8 for h in fd.diagnoses}) for fd in rounds.out)
+        assert gather.meta["shards"] == owners
+        assert st["shard.provider"].n == 2 * 3 + owners
+    else:
+        assert "shards" not in gather.meta
     if path == "live":
         copied = st["assemble.copy"].meta
         assert copied["bytes"] == (rounds.agg.stats.staged_bytes - staged0)
@@ -248,6 +260,60 @@ def test_self_time_and_untraced_arithmetic():
     # round 1: 100 - 40 (detect) - 30 (finish); round 2: 100 - 50 (detect)
     assert w.untraced_s(["monitor.round"]) == pytest.approx(80e-9)
     assert w.untraced_s([]) == pytest.approx(20e-9)
+
+
+def _reader(name):
+    """``read`` of ``bench/metrics/<name>.py``, loaded afresh."""
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "metrics",
+                        f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+#: two rounds: a shard-level gather and the fleet's fetch (with its
+#: provider call) in round 1, the fleet's fetch alone in round 2
+_GATHER_WINDOW = _spans.Window(
+    rounds=[(0.0, 100.0, 1), (200.0, 300.0, 1)],
+    spans=[("monitor.round", 5.0, 95.0, 1, {"hosts": 64.0}),
+           ("shard.finish", 10.0, 40.0, 1, {"shard": 3.0}),
+           ("monitor.gather", 20.0, 30.0, 1, {"hosts": 5.0, "bytes": 9.0}),
+           ("monitor.finish", 50.0, 90.0, 1, {"flagged": 9.0}),
+           ("monitor.gather", 55.0, 65.0, 1,
+            {"hosts": 3.0, "bytes": 9.0, "shards": 2.0}),
+           ("shard.provider", 56.0, 58.0, 1, {"shard": 1.0}),
+           ("monitor.round", 205.0, 295.0, 1, {"hosts": 64.0}),
+           ("monitor.finish", 250.0, 290.0, 1, {"flagged": 4.0}),
+           ("monitor.gather", 255.0, 262.0, 1,
+            {"hosts": 4.0, "bytes": 9.0, "shards": 1.0})])
+#: the same rounds with nothing flagged: no gather span at all
+_QUIET_WINDOW = _spans.Window(
+    rounds=_GATHER_WINDOW.rounds,
+    spans=[s for s in _GATHER_WINDOW.spans if s[0] == "monitor.round"])
+
+
+@pytest.mark.parametrize("window,want", [
+    (_GATHER_WINDOW, (10.0 + 10.0 + 7.0) * 1e-6 / 2), (_QUIET_WINDOW, None),
+    (None, None)])
+def test_gather_ms_reader_on_a_built_window(monkeypatch, window, want):
+    """``gather_ms.argus64k``: the summed ``monitor.gather`` time per
+    round, child spans included; None without a gather span."""
+    monkeypatch.setattr(_spans, "load", lambda run: window)
+    got = _reader("gather_ms.argus64k")(None)
+    assert got == (None if want is None else pytest.approx(want))
+
+
+@pytest.mark.parametrize("window,want", [
+    (_GATHER_WINDOW, (5.0 + 3.0 + 4.0) / 2), (_QUIET_WINDOW, None),
+    (None, None)])
+def test_evidence_blocks_reader_on_a_built_window(monkeypatch, window, want):
+    """``evidence_blocks.argus64k``: the gathers' ``hosts`` summed over
+    the window, per round; None without a gather span."""
+    monkeypatch.setattr(_spans, "load", lambda run: window)
+    got = _reader("evidence_blocks.argus64k")(None)
+    assert got == (None if want is None else pytest.approx(want))
 
 
 def test_self_time_on_a_recorded_trace(tmp_path, trials):
